@@ -31,6 +31,7 @@ class _DevicePoller:
         self.thread = threading.Thread(
             target=self._run, name=f"device_poller_{device_key}", daemon=True)
         self.completed_count = 0
+        self.failed_count = 0
         self.thread.start()
 
     def submit(self, arrays: Any, on_ready: Callable[[], None]) -> None:
@@ -47,8 +48,14 @@ class _DevicePoller:
                 arrays, on_ready = self.queue.popleft()
             try:
                 jax.block_until_ready(arrays)
-            except Exception:
-                pass        # errors surface to the waiter on its own access
+            except Exception as e:
+                # the callback still fires (a waiter must not hang), but
+                # a device program that failed is never silent: logged
+                # and counted, and the waiter's own access raises it
+                self.failed_count += 1
+                from ..butil import logging as log
+                log.error("device poller %s: block_until_ready failed: "
+                          "%s: %s", self.key, type(e).__name__, e)
             self.completed_count += 1
             try:
                 on_ready()
@@ -111,6 +118,11 @@ class DeviceEventDispatcher:
     def stats(self) -> Dict[str, int]:
         with self._plock:
             return {k: p.completed_count for k, p in self._pollers.items()}
+
+    def failures(self) -> int:
+        """Completions whose device work raised (all pollers)."""
+        with self._plock:
+            return sum(p.failed_count for p in self._pollers.values())
 
 
 def device_wait(arrays: Any, timeout: Optional[float] = None) -> int:

@@ -154,13 +154,33 @@ _ICI_BATCH_FN = ctypes.CFUNCTYPE(None, ctypes.POINTER(IciReqC),
                                  ctypes.c_uint64)
 
 
+_unavailable_logged = False
+
+
+def _unavailable(why: str) -> None:
+    """The pure-Python implementations take over — said once, at error:
+    a process that believes it runs the native datapath and does not is
+    the failure this line exists for."""
+    global _unavailable_logged
+    if _unavailable_logged:
+        return
+    _unavailable_logged = True
+    from . import logging as log
+    log.error("native core unavailable (%s); every native-gated path runs "
+              "its pure-Python implementation", why)
+
+
 def _build() -> bool:
     try:
         subprocess.run(["make", "-C", _NATIVE_DIR, "libbrpc_tpu_core.so"],
                        check=True, capture_output=True, timeout=300)
         return True
-    except Exception:
-        return False
+    except subprocess.CalledProcessError as e:
+        _unavailable("make -C native failed: "
+                     + e.stderr.decode(errors="replace")[-2000:])
+    except Exception as e:
+        _unavailable(f"make -C native: {type(e).__name__}: {e}")
+    return False
 
 
 def load() -> Optional[ctypes.CDLL]:
@@ -186,11 +206,14 @@ def load() -> Optional[ctypes.CDLL]:
                 shutil.copy(_SO, tmp.name)
                 lib = ctypes.CDLL(tmp.name)
                 if not hasattr(lib, _BRPC_TPU_NEWEST_SYMBOL_):
+                    _unavailable(f"rebuilt {_SO} still lacks "
+                                 f"{_BRPC_TPU_NEWEST_SYMBOL_}")
                     return None
             return _bind(lib)
-        except (OSError, AttributeError):
+        except (OSError, AttributeError) as e:
             # broken core library → none; callers fall back to the
             # pure-Python implementations
+            _unavailable(f"{type(e).__name__}: {e}")
             return None
 
 

@@ -710,7 +710,7 @@ class CollectiveFanoutPlane:
         import jax
         import numpy as np
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-        from ..butil.jax_compat import shard_map
+        from jax import shard_map
         from ..ici.mesh import IciMesh
         mesh = IciMesh.default()
         md = low.md
@@ -764,7 +764,7 @@ class CollectiveFanoutPlane:
         import numpy as np
         import jax.numpy as jnp
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-        from ..butil.jax_compat import shard_map
+        from jax import shard_map
         from ..ici.mesh import IciMesh
         mesh = IciMesh.default()
         md = low.md

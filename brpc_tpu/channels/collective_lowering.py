@@ -102,7 +102,7 @@ class CollectiveChannel:
 
     def _compile(self, md: _Method, operands, shard_flags) -> Callable:
         import jax
-        from ..butil.jax_compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         ax = self.mesh.axis_name
 

@@ -40,7 +40,7 @@ class Collectives:
     def _shard_map(self, fn, in_spec, out_spec):
         import jax
         from jax.sharding import PartitionSpec as P
-        from ..butil.jax_compat import shard_map
+        from jax import shard_map
         return jax.jit(shard_map(
             fn, mesh=self.mesh.mesh, in_specs=in_spec, out_specs=out_spec,
             check_vma=False))

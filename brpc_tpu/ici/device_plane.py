@@ -38,7 +38,10 @@ Failure semantics: a refused/failed post raises :class:`DevicePlaneError`
 BEFORE any descriptor exists, so the caller degrades to its previous
 path — ``device_put`` in-process, the PR-2 bulk/inline fallback machinery
 on the fabric — within the same frame (counted in
-``ici_device_plane_fallbacks``).  An IN-PROCESS posted send whose recv
+``ici_device_plane_fallbacks``).  A program the compiler REFUSES is a
+defect, not a degradation: :class:`DevicePlaneBuildError`, logged at error
+with the compiler's message and counted apart in
+``ici_device_plane_build_failures``.  An IN-PROCESS posted send whose recv
 never arrives is reaped after ``ici_device_plane_match_timeout_s`` and
 fails only that transfer.  Cross-process (fabric) transfers are owned by
 their socket's per-direction executors instead: a transfer still queued
@@ -110,11 +113,20 @@ _g_fallbacks = bvar.Adder("ici_device_plane_fallbacks")
 _g_cache_hits = bvar.Adder("ici_device_plane_program_cache_hits")
 _g_cache_misses = bvar.Adder("ici_device_plane_program_cache_misses")
 _g_match_timeouts = bvar.Adder("ici_device_plane_match_timeouts")
+_g_build_failures = bvar.Adder("ici_device_plane_build_failures")
 
 
 class DevicePlaneError(ConnectionError):
     """A post was refused or failed before any descriptor went out; the
     caller must route the payload over its fallback path."""
+
+
+class DevicePlaneBuildError(DevicePlaneError):
+    """The compiler refused the transfer program.  Unlike a runtime
+    refusal (chaos, plane health) this is a defect of the program, not of
+    the moment: logged at error with the compiler's message and counted
+    in ``build_failures`` where it happens, so the frame's degrade to
+    device_put is never the only trace of it."""
 
 
 # transfer states (WR lifecycle)
@@ -299,6 +311,7 @@ class DevicePlane:
         "bytes_sent": "_lock",
         "bytes_recv": "_lock",
         "fallbacks": "_lock",
+        "build_failures": "_lock",
         "cache_hits": "_lock",
         "cache_misses": "_lock",
         "match_timeouts": "_lock",
@@ -333,6 +346,7 @@ class DevicePlane:
         self.bytes_sent = 0
         self.bytes_recv = 0
         self.fallbacks = 0
+        self.build_failures = 0
         self.cache_hits = 0
         self.cache_misses = 0
         self.match_timeouts = 0
@@ -375,54 +389,61 @@ class DevicePlane:
 
     def _build(self, nbytes: int, src_dev: int, dst_dev: int, kernel: str):
         import jax
+        import jax.numpy as jnp
         import numpy as np
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-        from ..butil.jax_compat import shard_map
+        from jax import shard_map
         mesh = self.mesh()
         src, dst = mesh.device(src_dev), mesh.device(dst_dev)
         mesh2 = Mesh(np.array([src, dst]), ("p2p",))
         sharding = NamedSharding(mesh2, P("p2p"))
         if kernel == "pallas":
-            per_device = self._pallas_body(nbytes)
+            per_device = self._pallas_body(nbytes, (src, dst))
         else:
             def per_device(x_local):          # (1, nbytes) local row
                 return jax.lax.ppermute(x_local, "p2p", [(0, 1)])
+        # compiled HERE, not at first call: the compiler's verdict on the
+        # program belongs to the build (post_send), before any descriptor
         fn = jax.jit(shard_map(per_device, mesh=mesh2, in_specs=P("p2p"),
-                               out_specs=P("p2p"), check_vma=False))
+                               out_specs=P("p2p"), check_vma=False)).lower(
+            jax.ShapeDtypeStruct((2, nbytes), jnp.uint8,
+                                 sharding=sharding)).compile()
         return (fn, sharding, mesh2, src, dst)
 
     @staticmethod
-    def _pallas_body(nbytes: int):
-        """The hand-scheduled variant: one remote-DMA hop
+    def _pallas_body(nbytes: int, devices):
+        """The hand-scheduled variant: one remote-DMA hop, HBM → HBM
         (pltpu.make_async_remote_copy = ibv_post_send over ICI; see
-        pallas_ring.py for the ring-shaped sibling).  Symmetric shift —
-        both submesh members post toward the other (ICI links are
-        bidirectional, so the unused reverse hop is free on hardware);
-        only the dst row of the output is consumed.  Interpret mode
-        off-TPU so CI runs the exact kernel control flow."""
+        pallas_ring.py for the ring-shaped sibling).  The payload never
+        enters VMEM, so its size is bounded by HBM alone.  Symmetric
+        shift — both submesh members post toward the other after a
+        barrier handshake (ICI links are bidirectional, so the unused
+        reverse hop is free on hardware); only the dst row of the output
+        is consumed.  Compiled for a TPU submesh, the Pallas TPU
+        interpreter for any other (pallas_ring.interpret_for)."""
         import jax
         import jax.numpy as jnp
         from jax import lax
         import jax.experimental.pallas as pl
         import jax.experimental.pallas.tpu as pltpu
-        from ..butil.jax_compat import tpu_compiler_params
-        interpret = _platform() != "tpu"
+        from .pallas_ring import interpret_for, neighbour_barrier
+        interpret = interpret_for(devices, f"device-plane {nbytes}B")
 
-        def kern(local_ref, out_ref, comm_buf, send_sem, recv_sem):
-            my_id = lax.axis_index("p2p")
-            other = 1 - my_id
-            comm_buf[0] = local_ref[:]
+        def kern(local_ref, out_ref, send_sem, recv_sem):
+            other = 1 - lax.axis_index("p2p")
+            # the QP handshake: the peer is inside the kernel (its
+            # out_ref is live) before our DMA targets it
+            neighbour_barrier(other, other)
             rdma = pltpu.make_async_remote_copy(
-                src_ref=comm_buf.at[0],
-                dst_ref=comm_buf.at[1],
-                send_sem=send_sem.at[0],
-                recv_sem=recv_sem.at[1],
+                src_ref=local_ref,
+                dst_ref=out_ref,
+                send_sem=send_sem,
+                recv_sem=recv_sem,
                 device_id=other,
                 device_id_type=pltpu.DeviceIdType.LOGICAL,
             )
             rdma.start()
             rdma.wait()
-            out_ref[:] = comm_buf[1]
 
         def per_device(x_local):              # (1, nbytes)
             out = pl.pallas_call(
@@ -431,13 +452,13 @@ class DevicePlane:
                 in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
                 out_specs=pl.BlockSpec(memory_space=pl.ANY),
                 scratch_shapes=[
-                    pltpu.VMEM((2, nbytes), jnp.uint8),
-                    pltpu.SemaphoreType.DMA((2,)),
-                    pltpu.SemaphoreType.DMA((2,)),
+                    pltpu.SemaphoreType.DMA(()),
+                    pltpu.SemaphoreType.DMA(()),
                 ],
-                compiler_params=tpu_compiler_params(has_side_effects=True,
-                                                    collective_id=2),
+                compiler_params=pltpu.CompilerParams(has_side_effects=True,
+                                                     collective_id=2),
                 interpret=interpret,
+                name="brpc_device_plane_p2p",
             )(x_local[0])
             return out[None]
 
@@ -491,6 +512,23 @@ class DevicePlane:
             raise DevicePlaneError("device plane is point-to-point; "
                                    "same-device payloads are ref passes")
         nbytes = int(arr.shape[0])
+        # compile (or fetch) FIRST: a compilation error must surface before
+        # the descriptor is committed to any wire, and the seconds a cold
+        # compile takes are not the peer's to answer for — the match
+        # timeout runs from the post, which starts once the program exists
+        try:
+            self._program(nbytes, src_dev, dst_dev)
+        except Exception as e:
+            with self._lock:
+                self.build_failures += 1
+            _g_build_failures << 1
+            log.error("device plane ici://%d->%d: %dB transfer program "
+                      "(kernel=%s) was refused by the compiler: %s: %s",
+                      src_dev, dst_dev, nbytes,
+                      _flags.get_flag("ici_device_plane_kernel"),
+                      type(e).__name__, e)
+            raise DevicePlaneBuildError(
+                f"transfer program build failed: {e}") from e
         # trace context at post time: the server span being served, or
         # the ACTIVE client span (channel write path) — the context the
         # kind-4 descriptor carries to the receiver
@@ -499,15 +537,6 @@ class DevicePlane:
         t = DeviceTransfer(uuid if uuid is not None else self.next_uuid(),
                            src_dev, dst_dev, nbytes, src_arr=arr,
                            trace_id=tid, parent_span_id=psid)
-        # compile (or fetch) NOW: a compilation error must surface before
-        # the descriptor is committed to any wire
-        try:
-            self._program(nbytes, src_dev, dst_dev)
-        except Exception as e:
-            with self._lock:
-                self.fallbacks += 1
-            _g_fallbacks << 1
-            raise DevicePlaneError(f"transfer program build failed: {e}")
         if not remote:
             with self._lock:
                 self._pending[t.uuid] = t
@@ -536,8 +565,8 @@ class DevicePlane:
             # in-process degrade: device_put the pinned source (counted);
             # the compiled path failed but the bytes must still arrive
             import jax
-            log.warning("device plane %s: compiled transfer failed (%s) — "
-                        "device_put fallback", t.describe()["route"], e)
+            log.error("device plane %s: compiled transfer failed (%s) — "
+                      "device_put fallback", t.describe()["route"], e)
             with self._lock:
                 self.fallbacks += 1
             _g_fallbacks << 1
@@ -769,6 +798,7 @@ class DevicePlane:
                 "bytes_sent": self.bytes_sent,
                 "bytes_recv": self.bytes_recv,
                 "fallbacks": self.fallbacks,
+                "build_failures": self.build_failures,
                 "program_cache_hits": self.cache_hits,
                 "program_cache_misses": self.cache_misses,
                 "match_timeouts": self.match_timeouts,

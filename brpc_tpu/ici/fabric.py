@@ -348,6 +348,7 @@ class FabricNode:
         # same-host shm ring tier: probed at start (a denied /dev/shm
         # just leaves the capability un-advertised — clean degrade)
         self._shm_ok = False
+        self._shm_nonce = _os.urandom(4).hex()
         self._shm_lib = None
 
     # ---- lifecycle -----------------------------------------------------
@@ -457,7 +458,6 @@ class FabricNode:
         # keep the socket bulk tier, byte-for-byte the old behavior.
         if lib is not None and hasattr(lib, "brpc_tpu_shm_create") \
                 and _flags.get_flag("ici_fabric_shm"):
-            import os as _os
             probe = f"brpc_tpu_shm_probe.{_os.getpid()}"
             lib.brpc_tpu_shm_unlink(probe.encode())
             ph = lib.brpc_tpu_shm_create(probe.encode(), 64 * 1024)
@@ -815,7 +815,12 @@ class FabricNode:
         lives only for the handshake round trip."""
         if not self._shm_ok or self._shm_lib is None:
             return 0, None, None
-        name = f"brpc_tpu_shm.{self.process_id}.{self.next_uuid():x}"
+        # /dev/shm is ONE namespace for the whole host, and the jax
+        # process index repeats in every pod on it (and in every pytest
+        # worker): the OS pid tells pods apart, the per-node nonce tells
+        # a recycled pid from the dead process that leaked an entry
+        name = (f"brpc_tpu_shm.{_os.getpid()}.{self._shm_nonce}."
+                f"{self.next_uuid():x}")
         stripes = _resolve_shm_stripes()
         if stripes > 1 and hasattr(self._shm_lib, "brpc_tpu_shm_create2"):
             # striped v2 segment (multi-core hosts): the attacher reads
@@ -838,7 +843,14 @@ class FabricNode:
             return
         if h:
             self._shm_lib.brpc_tpu_shm_close(h)
-        if name:
+        self.unlink_shm_segment(name)
+
+    def unlink_shm_segment(self, name: Optional[str]) -> None:
+        """The CREATOR's unlink, run on every exit of a handshake — the
+        acked one too: the attacher unlinks after mapping, but an
+        attacher killed between the two would leave the entry behind
+        for the life of the host.  Idempotent (ENOENT is success)."""
+        if name and self._shm_lib is not None:
             self._shm_lib.brpc_tpu_shm_unlink(name.encode())
 
     def ping(self, target_dev: int, timeout: float = 1.0) -> bool:
@@ -933,6 +945,7 @@ class FabricNode:
         if shm_h:
             if echo.get("shm"):
                 sock._attach_shm(shm_lib, shm_h)
+                self.unlink_shm_segment(shm_name)
             else:
                 # server did not ack (older peer, refused, or attach
                 # failed): the segment must not leak
@@ -1640,6 +1653,7 @@ class FabricSocket(CreditWindow, OrderedDelivery, Socket):
                 self._shm_reestab_pending, None
         if ok and pending is not None:
             self._attach_shm(pending[0], pending[1])
+            self.node.unlink_shm_segment(pending[2])
         elif pending is not None:
             self.node.drop_shm_segment(pending[1], pending[2])
             ok = False

@@ -32,7 +32,7 @@ def ring_all_reduce(x, mesh: Optional[IciMesh] = None):
     chunk i's shards … i.e. a reduce-scatter + all-gather pipeline)."""
     import jax
     from jax.sharding import PartitionSpec as P
-    from ..butil.jax_compat import shard_map
+    from jax import shard_map
 
     mesh = mesh or IciMesh.default()
     n = mesh.size

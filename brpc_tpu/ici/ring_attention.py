@@ -55,7 +55,7 @@ def _build_ring_attention(mesh: IciMesh, block_shape, dtype, causal: bool):
     import jax
     import jax.numpy as jnp
     from jax import lax
-    from ..butil.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = mesh.size
@@ -137,7 +137,7 @@ def _build_ulysses(mesh: IciMesh, block_shape, dtype):
     import jax
     import jax.numpy as jnp
     from jax import lax
-    from ..butil.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = mesh.size

@@ -36,6 +36,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 from ..butil.endpoint import EndPoint
 from ..butil import flags as _flags
 from ..butil import debug_sync as _dbg
+from ..butil import logging as log
 from ..butil.iobuf import IOBuf, IOPortal, DEVICE
 from ..bthread.butex import Butex
 from ..bthread.device_waiter import DeviceEventDispatcher
@@ -143,6 +144,10 @@ class CreditWindow:
                 # a stalled window must not black-hole the socket: fail it
                 # so queued writes complete with an error and callers see
                 # EFAILEDSOCKET rather than waiting forever
+                log.error("ici socket to %s: send window stalled >%.0fs "
+                          "(peer not consuming); %d bytes unacked — "
+                          "socket set failed", self.remote_side, timeout,
+                          self.unacked_send_bytes())
                 self.set_failed(
                     errors.EFAILEDSOCKET,
                     f"ici send window stalled >{timeout:.0f}s "
@@ -179,8 +184,13 @@ class OrderedDelivery:
 
         arrays = [w for w in waits if not hasattr(w, "add_done_callback")]
         handles = [w for w in waits if hasattr(w, "add_done_callback")]
-        gates = len(handles) + (1 if arrays and not _all_ready(arrays)
-                                else 0)
+        # readiness is sampled ONCE: an array that turns ready between a
+        # count and a later re-check would be counted as a gate and never
+        # handed to the poller — the entry then never opens, the peer
+        # never consumes, and the writer's window stalls (seen on the
+        # chip, where readiness really is asynchronous)
+        poll_arrays = bool(arrays) and not _all_ready(arrays)
+        gates = len(handles) + (1 if poll_arrays else 0)
         if gates == 0:
             entry[0] = True
             self._drain_deliveries()
@@ -197,7 +207,7 @@ class OrderedDelivery:
             entry[0] = True
             self._drain_deliveries()
 
-        if arrays and not _all_ready(arrays):
+        if poll_arrays:
             DeviceEventDispatcher.instance().on_ready(arrays, one_gate)
         for h in handles:
             h.add_done_callback(one_gate)
@@ -279,8 +289,10 @@ class IciSocket(CreditWindow, OrderedDelivery, Socket):
         program (shard_map + ppermute / Pallas remote DMA) with only a
         descriptor riding the delivery path; the matching recv is
         enqueued by ``_deliver`` (the QP rendezvous).  A refused post
-        (chaos, unbuildable program) degrades to device_put in the same
-        frame."""
+        degrades to device_put in the same frame: a chaos/plane-health
+        refusal is counted in the plane's ``fallbacks``, a program the
+        compiler refused is logged at error and counted in
+        ``build_failures`` by the plane (DevicePlaneBuildError)."""
         import jax
         from . import device_plane as _dp
         target = self.mesh.device(self.remote_dev)
@@ -329,7 +341,8 @@ class IciSocket(CreditWindow, OrderedDelivery, Socket):
                                 _ici_device_bytes_moved += r.length
                             continue
                         except _dp.DevicePlaneError:
-                            pass         # counted by the plane; fall back
+                            pass     # counted (and, for a build error,
+                            #          logged) by the plane; device_put
                 moved = jax.device_put(arr, target)
                 self._pin_until_sent(r.block, moved)
                 chunks.append((moved, r.length))

@@ -548,20 +548,26 @@ class Channel:
         cntl._selected_endpoint = ep
         group = self._channel_signature()
         ssl_ctx = self.options.ssl_context
+        # the caller's residence holds on BOTH ici planes: the native
+        # binding takes it at bind, the Python plane here at connect
+        local_dev = self.options.ici_local_device
         if ctype == "pooled":
             sock = smap.get_pooled_socket(ep, self.messenger, group=group,
                                           ssl_context=ssl_ctx,
-                                          connect_timeout=cto)
+                                          connect_timeout=cto,
+                                          ici_local_device=local_dev)
             cntl._pooled_from = ep
         elif ctype == "short":
             sock = smap.get_short_socket(ep, self.messenger,
                                          ssl_context=ssl_ctx,
-                                         connect_timeout=cto)
+                                         connect_timeout=cto,
+                                         ici_local_device=local_dev)
             cntl._short_socket = sock
         else:
             sock = smap.get_socket(ep, self.messenger,
                                    ssl_context=ssl_ctx, group=group,
-                                   connect_timeout=cto)
+                                   connect_timeout=cto,
+                                   ici_local_device=local_dev)
         return sock
 
     def close(self) -> None:
@@ -610,10 +616,13 @@ class Channel:
         when the peer would parse it identically — protocol, TLS, and
         auth identity all partition the space.  The auth object itself is
         part of the key (the map then pins it, so identity can never be
-        recycled while its connections live)."""
+        recycled while its connections live).  An ici:// connection also
+        fixes where its responses land (``ici_local_device``), so callers
+        resident on different chips never share one."""
         return (self._protocol.name,
                 self.options.ssl_context is not None,
-                self.options.auth)
+                self.options.auth,
+                self.options.ici_local_device)
 
     def _on_call_end(self, cntl: Controller) -> None:
         # pooled sockets go back to the pool; short ones close

@@ -238,6 +238,13 @@ class Socket:
             try:
                 n = self._do_write(req.data)
             except Exception as e:
+                # the calls in flight only ever see the CODE: the reason
+                # a transport write threw (a relocation, a slice, a dead
+                # peer) must reach the log here or it reaches nobody
+                from ..butil import logging as log
+                log.error("write to %s failed, socket set failed: %s: %s",
+                          self.remote_side, type(e).__name__, e,
+                          exc_info=True)
                 self.set_failed(errors.EFAILEDSOCKET, str(e))
                 return True
             if n < 0:           # transport not writable now

@@ -53,21 +53,27 @@ class SocketMap:
 
     def get_socket(self, ep: EndPoint, messenger=None,
                    ssl_context=None, group: Any = "",
-                   connect_timeout: float = 5.0) -> Socket:
-        """The shared 'single' connection to ep (creates/replaces lazily)."""
+                   connect_timeout: float = 5.0,
+                   ici_local_device: Optional[int] = None) -> Socket:
+        """The shared 'single' connection to ep (creates/replaces lazily).
+        ``ici_local_device`` (ici:// only) is the caller's residence —
+        part of the caller's ``group``, so it is the same for every user
+        of one entry."""
         e = self._entry(ep, group)
         with e.lock:
             if e.socket is not None and not e.socket.failed \
                     and not e.socket.logoff:
                 return e.socket
-            s = self._checked_connect(ep, ssl_context, connect_timeout)
+            s = self._checked_connect(ep, ssl_context, connect_timeout,
+                                      ici_local_device)
             s.messenger = messenger
             e.socket = s
             return s
 
     def get_pooled_socket(self, ep: EndPoint, messenger=None,
                           group: Any = "", ssl_context=None,
-                          connect_timeout: float = 5.0) -> Socket:
+                          connect_timeout: float = 5.0,
+                          ici_local_device: Optional[int] = None) -> Socket:
         """An exclusive connection from the pool (reference
         GetPooledSocket); return it with return_pooled_socket."""
         e = self._entry(ep, group)
@@ -76,13 +82,15 @@ class SocketMap:
                 s = e.pooled.pop()
                 if not s.failed and not s.logoff:
                     return s
-        s = self._checked_connect(ep, ssl_context, connect_timeout)
+        s = self._checked_connect(ep, ssl_context, connect_timeout,
+                                  ici_local_device)
         s.messenger = messenger
         return s
 
     @classmethod
     def _checked_connect(cls, ep: EndPoint, ssl_context=None,
-                         connect_timeout: float = 5.0) -> Socket:
+                         connect_timeout: float = 5.0,
+                         ici_local_device: Optional[int] = None) -> Socket:
         """_connect, but an unreachable endpoint is handed to the health
         checker before the error propagates: the reference starts a
         health check whenever a connect fails, which keeps a DOWN
@@ -90,7 +98,8 @@ class SocketMap:
         connect creates no socket, so the socket-failure hand-off alone
         would miss retries issued while the peer is gone)."""
         try:
-            return cls._connect(ep, ssl_context, connect_timeout)
+            return cls._connect(ep, ssl_context, connect_timeout,
+                                ici_local_device)
         except Exception:
             try:
                 from .health_check import start_health_check
@@ -117,14 +126,17 @@ class SocketMap:
 
     def get_short_socket(self, ep: EndPoint, messenger=None,
                          ssl_context=None,
-                         connect_timeout: float = 5.0) -> Socket:
-        s = self._checked_connect(ep, ssl_context, connect_timeout)
+                         connect_timeout: float = 5.0,
+                         ici_local_device: Optional[int] = None) -> Socket:
+        s = self._checked_connect(ep, ssl_context, connect_timeout,
+                                  ici_local_device)
         s.messenger = messenger
         return s
 
     @staticmethod
     def _connect(ep: EndPoint, ssl_context=None,
-                 connect_timeout: float = 5.0) -> Socket:
+                 connect_timeout: float = 5.0,
+                 ici_local_device: Optional[int] = None) -> Socket:
         if ep.scheme == SCHEME_MEM:
             from .mem_transport import mem_connect
             return mem_connect(ep.host)
@@ -136,7 +148,7 @@ class SocketMap:
             # routes in-process targets through the zero-copy IciSocket,
             # remote (other-controller) ones through the fabric
             from ..ici.fabric import connect_any
-            return connect_any(ep)
+            return connect_any(ep, ici_local_device)
         raise ValueError(f"unsupported scheme {ep.scheme}")
 
     def remove(self, ep: EndPoint, group: Any = "") -> None:

@@ -135,7 +135,7 @@ class ContinuousBatchScheduler:
         self._dirty = True
         self._tbl = self._seq = self._acc = None
         self._prev = self._stepi = self._rows = None
-        self._jit_cache: Dict[tuple, Callable] = {}
+        self._jit_cache: Dict[int, Callable] = {}
         # counters / gauges
         self.steps = bvar.Adder("serving_steps")
         self.tokens_out = bvar.Adder("serving_tokens")
@@ -437,18 +437,14 @@ class ContinuousBatchScheduler:
         return (self._acc + read * (self._stepi + 1)
                 + self._prev * 31) % self.options.vocab
 
-    def _step_compiled(self, bt: int) -> np.ndarray:
-        """The same step as ONE jit-compiled XLA program, cached per
-        (batch-bucket, table-width-bucket) so roster churn compiles a
-        handful of programs, not one per shape."""
-        import jax
-        import jax.numpy as jnp
-        b = len(self._rows)
-        bpad = 1 << max(b - 1, 0).bit_length()
-        wpad = 1 << max(self._tbl.shape[1] - 1, 0).bit_length()
-        key = (bpad, wpad, bt)
-        fn = self._jit_cache.get(key)
+    def _compiled_step_fn(self, bt: int):
+        """The step as a jitted function of (pos_flat, tbl, seq, acc,
+        prev, stepi) — shape-polymorphic until called, so one function
+        serves every (batch-bucket, table-width-bucket)."""
+        fn = self._jit_cache.get(bt)
         if fn is None:
+            import jax
+            import jax.numpy as jnp
             vocab = self.options.vocab
 
             def _step(pos_flat, tbl, seq, acc, prev, stepi):
@@ -458,7 +454,17 @@ class ContinuousBatchScheduler:
                 read = pos_flat[blk * bt + pos % bt]
                 return (acc + read * (stepi + 1) + prev * 31) % vocab
 
-            fn = self._jit_cache[key] = jax.jit(_step)
+            fn = self._jit_cache[bt] = jax.jit(_step)
+        return fn
+
+    def _step_compiled(self, bt: int) -> np.ndarray:
+        """The same step as ONE jit-compiled XLA program; inputs are
+        padded to (batch-bucket, table-width-bucket) so roster churn
+        compiles a handful of programs, not one per shape."""
+        b = len(self._rows)
+        bpad = 1 << max(b - 1, 0).bit_length()
+        wpad = 1 << max(self._tbl.shape[1] - 1, 0).bit_length()
+        fn = self._compiled_step_fn(bt)
 
         def pad(a, n, fill=0):
             out = np.full((n,) + a.shape[1:], fill, a.dtype)

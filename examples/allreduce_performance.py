@@ -35,4 +35,6 @@ def main(size_mb: int = 64) -> None:
 
 
 if __name__ == "__main__":
+    from brpc_tpu.butil import compile_cache
+    compile_cache.enable()
     main()

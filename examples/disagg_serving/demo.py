@@ -135,4 +135,6 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    from brpc_tpu.butil import compile_cache
+    compile_cache.enable()
     sys.exit(main())

@@ -40,4 +40,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from brpc_tpu.butil import compile_cache
+    compile_cache.enable()
     main()

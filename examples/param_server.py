@@ -72,4 +72,6 @@ def main(steps: int = 6, resume_at: int = 3) -> None:
 
 
 if __name__ == "__main__":
+    from brpc_tpu.butil import compile_cache
+    compile_cache.enable()
     main()

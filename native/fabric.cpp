@@ -434,16 +434,31 @@ struct Listener {
   std::condition_variable cv;
   std::unordered_map<std::string, std::shared_ptr<BulkConn>> pending;
   bool stopped = false;
+  std::atomic<bool> closing{false};  // stop() asked the acceptors to exit
   // chaos: refuse the next N key handshakes (the parked conn is closed
   // right after its binding header, so the claim never finds it)
   std::atomic<int64_t> chaos_refuse{0};
 
   void accept_loop(int afd, bool tcp) {
+    // Bounded poll + non-blocking accept, never a thread parked in
+    // accept(): whether shutdown()/close() of a LISTENING socket wakes a
+    // blocked accept() is the kernel's choice (the TPU hosts' does not,
+    // and stop() joins this thread — every fabric process hung at exit
+    // there).  stop() raises `closing`, joins, and only then closes the
+    // fds, so this loop never touches a recycled descriptor.
+    fcntl(afd, F_SETFL, fcntl(afd, F_GETFL, 0) | O_NONBLOCK);
     for (;;) {
+      struct pollfd pfd{afd, POLLIN, 0};
+      int pr = ::poll(&pfd, 1, 50);
+      if (closing.load(std::memory_order_acquire)) break;
+      if (pr < 0 && errno != EINTR) break;
+      if (pr <= 0) continue;
       int cfd = ::accept(afd, nullptr, nullptr);
       if (cfd < 0) {
-        if (errno == EINTR) continue;
-        break;  // listener closed
+        if (errno == EINTR || errno == EAGAIN || errno == EWOULDBLOCK ||
+            errno == ECONNABORTED)
+          continue;
+        break;  // listener broken
       }
       if (tcp) set_nodelay(cfd);
       set_bulk_buffers(cfd, !tcp);
@@ -516,14 +531,15 @@ struct Listener {
       stopped = true;
       cv.notify_all();
     }
+    closing.store(true, std::memory_order_release);
+    if (acceptor.joinable()) acceptor.join();
+    if (uacceptor.joinable()) uacceptor.join();
     ::shutdown(fd, SHUT_RDWR);
     ::close(fd);
     if (ufd >= 0) {
       ::shutdown(ufd, SHUT_RDWR);
       ::close(ufd);
     }
-    if (acceptor.joinable()) acceptor.join();
-    if (uacceptor.joinable()) uacceptor.join();
     for (auto& kv : pending) kv.second->close_join();
     pending.clear();
   }

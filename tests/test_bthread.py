@@ -315,3 +315,21 @@ class TestDeviceWaiter:
         bthread.start_background(task)
         assert done.wait(30) == 0
         assert results == [(0, 90)]
+
+
+def test_device_poller_counts_a_failed_completion_and_still_fires():
+    """A block_until_ready that raises is logged and counted — and the
+    callback still runs, so no waiter hangs on a failed program."""
+    import threading
+    from brpc_tpu.bthread.device_waiter import DeviceEventDispatcher
+
+    class Poisoned:
+        def block_until_ready(self):
+            raise RuntimeError("device program failed")
+
+    disp = DeviceEventDispatcher.instance()
+    before = disp.failures()
+    fired = threading.Event()
+    disp.on_ready([Poisoned()], fired.set)
+    assert fired.wait(10)
+    assert disp.failures() == before + 1

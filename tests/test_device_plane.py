@@ -292,6 +292,37 @@ class TestSocketIntegration:
         finally:
             server.stop()
 
+    @pytest.mark.parametrize("nbytes", [64 * 1024, 9 * 1024 * 1024],
+                             ids=["native-tier", "python-plane"])
+    def test_response_lands_on_the_callers_device(self, plane_on, nbytes):
+        """ChannelOptions.ici_local_device holds on BOTH ici planes: a
+        call under the native send window (native tier) and one above it
+        (Python plane) both relocate the response toward the caller's
+        chip — the Python plane used to default to the server's
+        neighbour, whatever the option said."""
+        mesh = IciMesh.default()
+        server = self._echo_server("ici://1")
+        try:
+            ch = rpc.Channel()
+            ch.init("ici://1", options=rpc.ChannelOptions(
+                timeout_ms=60000, max_retry=0, ici_local_device=0))
+            payload = _payload(nbytes, 0)
+            cntl = rpc.Controller()
+            cntl.request_attachment.append_device_array(payload)
+            ch.call_method("EchoService.Echo", cntl,
+                           EchoRequest(message="home"), EchoResponse)
+            assert not cntl.failed(), cntl.error_text
+            refs = cntl.response_attachment.device_refs()
+            assert refs
+            for r in refs:
+                assert set(r.block.data.devices()) == {mesh.device(0)}
+            got = np.frombuffer(cntl.response_attachment.to_bytes(),
+                                dtype=np.uint8)
+            np.testing.assert_array_equal(got, np.asarray(payload))
+            ch.close()
+        finally:
+            server.stop()
+
     def test_python_ici_socket_routes_through_plane(self, plane_on):
         """The Python-plane IciSocket (streaming / non-tpu_std wire):
         a DEVICE block in a written IOBuf crosses via the plane and is

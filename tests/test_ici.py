@@ -404,3 +404,24 @@ class TestRing:
         for i, chunk in enumerate(got):
             np.testing.assert_allclose(chunk, np.full((n, 4), i))
         assert stream.in_flight == 0
+
+
+def test_delivery_gate_samples_readiness_once(monkeypatch):
+    """An array that turns ready between two looks must still open its
+    delivery entry: readiness is sampled once, so what was counted as a
+    gate is what is handed to the poller (the chip found the stall — the
+    peer never consumed and the writer's window never reopened)."""
+    import threading
+    import jax.numpy as jnp
+    from brpc_tpu.ici import transport as tr
+
+    class Delivery(tr.OrderedDelivery):
+        pass
+
+    d = Delivery()
+    d._init_delivery()
+    looks = iter([False, True, True, True])
+    monkeypatch.setattr(tr, "_all_ready", lambda arrays: next(looks))
+    committed = threading.Event()
+    d._enqueue_delivery([jnp.zeros(8)], committed.set)
+    assert committed.wait(10), "the delivery entry never opened"

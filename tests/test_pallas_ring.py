@@ -1,5 +1,7 @@
-"""Pallas ring collective kernels (interpret mode on the CPU mesh — the
-exact control flow the TPU executes, with remote DMA emulated)."""
+"""Pallas ring collective kernels (the Pallas TPU interpreter on the CPU
+mesh — the exact control flow the TPU executes, with remote DMA and the
+semaphores emulated; tests/test_tpu_compile.py hands the same kernels to the
+real TPU compiler)."""
 import numpy as np
 import pytest
 
@@ -9,9 +11,15 @@ from brpc_tpu.ici import pallas_ring
 
 @pytest.fixture(scope="module")
 def mesh():
+    """Seven devices, NOT the process's first: the interpreter's callbacks
+    run small jax ops on ``jax.devices()[0]``, and when that device is
+    itself parked inside one of those callbacks they queue behind it —
+    the kernel's results are out, but its trailing barrier callbacks hold
+    every mesh device for good and the next test file to touch them hangs
+    (seen once the suite ran under xdist).  Off the mesh, device 0 stays
+    free to serve them."""
     import jax
-    m = ici.IciMesh(jax.devices())
-    return m
+    return ici.IciMesh(jax.devices()[1:])
 
 
 class TestPallasRing:
