@@ -20,12 +20,15 @@ import threading
 from typing import Any, Callable, Deque, Dict, Optional, Tuple
 
 from .butex import Butex
+from ..butil import layer_span as _span
 
 
 class _DevicePoller:
     def __init__(self, device_key: str):
         self.key = device_key
-        self.queue: Deque[Tuple[Any, Callable[[], None]]] = collections.deque()
+        # entries: (arrays, on_ready, the submitter's layer mark or None)
+        self.queue: Deque[Tuple[Any, Callable[[], None], Any]] = \
+            collections.deque()
         self.cv = threading.Condition()
         # fablint: thread-quiesced(process-lifetime CQ poller parked on its condvar; owns no native state at exit)
         self.thread = threading.Thread(
@@ -36,7 +39,11 @@ class _DevicePoller:
 
     def submit(self, arrays: Any, on_ready: Callable[[], None]) -> None:
         with self.cv:
-            self.queue.append((arrays, on_ready))
+            # layer span brpc.poller.queue (butil/layer_span.py): the wait
+            # behind older entries, from here to the popleft; n = its depth
+            mark = _span.layer_mark(len(self.queue)) \
+                if _span.layer_on() else None
+            self.queue.append((arrays, on_ready, mark))
             self.cv.notify()
 
     def _run(self) -> None:
@@ -45,7 +52,11 @@ class _DevicePoller:
             with self.cv:
                 while not self.queue:
                     self.cv.wait()
-                arrays, on_ready = self.queue.popleft()
+                arrays, on_ready, mark = self.queue.popleft()
+            ls = None
+            if mark is not None:
+                _span.layer_waited("brpc.poller.queue", mark)
+                ls = _span.layer_begin("brpc.poller.block", mark=mark)
             try:
                 jax.block_until_ready(arrays)
             except Exception as e:
@@ -57,11 +68,17 @@ class _DevicePoller:
                 log.error("device poller %s: block_until_ready failed: "
                           "%s: %s", self.key, type(e).__name__, e)
             self.completed_count += 1
+            if ls is not None:
+                ls.end()
+            if mark is not None:
+                ls = _span.layer_begin("brpc.poller.callback", mark=mark)
             try:
                 on_ready()
             except Exception:
                 from ..butil import logging as log
                 log.error("device completion callback raised", exc_info=True)
+            if ls is not None:
+                ls.end()
 
 
 class DeviceEventDispatcher:

@@ -33,6 +33,7 @@ from typing import Any, Dict, List, Optional, Tuple
 from .. import bvar
 from ..butil import debug_sync as _dbg
 from ..butil import flags as _flags
+from ..butil import layer_span as _lspan
 from ..butil import logging as log
 from ..butil import native
 from ..butil.iobuf import IOBuf, DEVICE
@@ -149,8 +150,9 @@ _stage_hot = None
 def _stage_modules():
     global _stage_hot
     if _stage_hot is None:
-        from ..policy.tpu_std import _record_stage, _stage_flag
-        _stage_hot = (_stage_flag, _record_stage)
+        from ..policy.tpu_std import (_enter_handler, _record_stage,
+                                      _stages_on)
+        _stage_hot = (_stages_on, _record_stage, _enter_handler)
     return _stage_hot
 
 
@@ -823,7 +825,8 @@ class ServerBinding:
         self._fused_inline1 = self._fused and bool(
             getattr(server.options, "usercode_inline", False))
         self._fcache: Dict[bytes, tuple] = {}   # mkey -> dispatch tuple
-        self._stage_flag, self._record_stage = _stage_modules()
+        self._stages_on, self._record_stage, self._enter_handler = \
+            _stage_modules()
         self._pool = _controller_pool()
         # dispatch-route truth (OBSERVABILITY.md): how many requests ran
         # the fused body vs the legacy chain on this listener — plain
@@ -1293,12 +1296,12 @@ class ServerBinding:
                           (pri_wire, tenant, ddl))
             return
         self.fused_dispatched += 1
-        stages = self._stage_flag.value == "on"
+        stages = self._stages_on()
         if stages:
             recv_ns = r.recv_ns
             if recv_ns:
-                q_us = (_time.monotonic_ns() - recv_ns) // 1000
-                self._record_stage("queue", max(q_us, 0), None)
+                self._record_stage("queue", recv_ns, _time.monotonic_ns(),
+                                   None, token)
         if not server.on_request_in():
             if attachment is not None and \
                     type(attachment) is NativeAttachment:
@@ -1348,12 +1351,16 @@ class ServerBinding:
             if collector is None or not collector.add(item):
                 self._respond_item(item)
             return
+        opened = None
         if stages:
-            self._record_stage(
-                "parse", (_time.monotonic_ns() - start_ns) // 1000, None)
+            self._record_stage("parse", start_ns, _time.monotonic_ns(),
+                               None, token)
+            # the handler stage runs from start_ns, parse included, as
+            # the method's latency does
+            opened = self._enter_handler(start_ns, token)
         response = response_cls()
         fd = _FusedDone(self, token, cntl, response, status, start_ns,
-                        collector, stages)
+                        collector, stages, opened)
         d["_server_done"] = fd       # cntl.send_response() support
         try:
             # the context scope installs only when it would matter: the
@@ -1379,16 +1386,18 @@ class ServerBinding:
                 cntl.set_failed(errors.EINTERNAL,
                                 f"{type(e).__name__}: {e}")
                 fd()
+        finally:
+            if opened is not None:
+                opened.leave()
 
     def _process(self, token, full, payload, attachment, log_id, peer_dev,
                  recv_ns, collector, adm_meta=None) -> None:
         self.legacy_dispatched += 1
         server = self._server
-        stage_flag, record_stage = _stage_modules()
-        stages = stage_flag.value == "on"
+        record_stage = self._record_stage
+        stages = self._stages_on()
         if stages and recv_ns:
-            q_us = (_time.monotonic_ns() - recv_ns) // 1000
-            record_stage("queue", max(q_us, 0), None)
+            record_stage("queue", recv_ns, _time.monotonic_ns(), None, token)
         if server._draining:
             # lame-duck: the native front door stays open through the
             # grace window so in-flight calls finish, but new ones bounce
@@ -1418,7 +1427,8 @@ class ServerBinding:
             def _admitted(queued_us: int,
                           _stages=stages, _rs=record_stage) -> None:
                 if _stages and queued_us:
-                    _rs("queue", queued_us, None)
+                    now = _time.monotonic_ns()
+                    _rs("queue", now - queued_us * 1000, now, None, token)
                 self._execute(token, full, payload, attachment, log_id,
                               peer_dev, collector, md, status, adm_meta)
 
@@ -1466,8 +1476,8 @@ class ServerBinding:
         """Gates held: parse → invoke → batched write-back."""
         server_controller_pool = _controller_pool()
         server = self._server
-        stage_flag, record_stage = _stage_modules()
-        stages = stage_flag.value == "on"
+        record_stage = self._record_stage
+        stages = self._stages_on()
         cntl = server_controller_pool.acquire()  # fablint: custody-moved(request-lifecycle) the shim rides the request; _maybe_recycle releases it back to the pool when the response (or failure path) completes
         if log_id:
             cntl.log_id = log_id
@@ -1499,9 +1509,13 @@ class ServerBinding:
                               f"fail to parse request: {e}", collector,
                               post=parse_post)
             return
+        opened = None
         if stages:
-            record_stage("parse",
-                         (_time.monotonic_ns() - start_ns) // 1000, None)
+            record_stage("parse", start_ns, _time.monotonic_ns(), None,
+                         token)
+            # the handler stage runs from start_ns, parse included, as
+            # the method's latency does
+            opened = self._enter_handler(start_ns, token)
         response = md.response_cls()
         done_called = [False]
 
@@ -1512,7 +1526,8 @@ class ServerBinding:
             t_done = _time.monotonic_ns()
             latency_us = (t_done - start_ns) // 1000
             if stages:
-                record_stage("handler", latency_us, None)
+                record_stage("handler", start_ns, t_done, None, token,
+                             opened)
             cntl._release_session_data()
             err = cntl.error_code_
 
@@ -1560,9 +1575,8 @@ class ServerBinding:
             item = (token, 0, b"", response.SerializeToString(),
                     att_host, segs, post, 0, pass_h)
             if stages:
-                record_stage("encode",
-                             (_time.monotonic_ns() - t_done) // 1000,
-                             None)
+                record_stage("encode", t_done, _time.monotonic_ns(), None,
+                             token)
             if collector is None or not collector.add(item):
                 self._respond_item(item)
 
@@ -1577,6 +1591,9 @@ class ServerBinding:
                 done()
                 cntl._release_session_data()
                 cntl._maybe_recycle()
+        finally:
+            if opened is not None:
+                opened.leave()
 
     def _peer_endpoint(self, peer_dev: int):
         """Per-request endpoint objects are identical for a given peer —
@@ -1638,11 +1655,11 @@ class ServerBinding:
             seg_arr = None
             e.segs = None
             e.nsegs = 0
-        if self._stage_flag.value == "on":
+        if self._stages_on():
             t0 = _time.monotonic_ns()
             self._lib.brpc_tpu_ici_respond_batch(arr, 1)
-            self._record_stage("write",
-                               (_time.monotonic_ns() - t0) // 1000, None)
+            self._record_stage("write", t0, _time.monotonic_ns(), None,
+                               token)
         else:
             self._lib.brpc_tpu_ici_respond_batch(arr, 1)
         del seg_arr, payload, att_host, err_text   # alive across the call
@@ -1689,15 +1706,15 @@ class ServerBinding:
                 e.segs = seg_arr
                 e.nsegs = len(segs)
                 keep.append(seg_arr)
-        if self._stage_flag.value == "on":
+        if self._stages_on():
             t0 = _time.monotonic_ns()
             self._lib.brpc_tpu_ici_respond_batch(arr, n)
             # under batched delivery the write stage is the SHARED flush
             # crossing: every response in the batch records the same
             # crossing latency (what the request actually waited)
-            w_us = (_time.monotonic_ns() - t0) // 1000
-            for _ in range(n):
-                self._record_stage("write", w_us, None)
+            t1 = _time.monotonic_ns()
+            for it in items:
+                self._record_stage("write", t0, t1, None, it[0])
         else:
             self._lib.brpc_tpu_ici_respond_batch(arr, n)
         del keep
@@ -1724,10 +1741,10 @@ class _FusedDone:
     per-RPC closure.  Idempotent like the legacy done."""
 
     __slots__ = ("binding", "token", "cntl", "response", "status",
-                 "start_ns", "collector", "stages", "called")
+                 "start_ns", "collector", "stages", "opened", "called")
 
     def __init__(self, binding, token, cntl, response, status, start_ns,
-                 collector, stages):
+                 collector, stages, opened=None):
         self.binding = binding
         self.token = token
         self.cntl = cntl
@@ -1736,6 +1753,7 @@ class _FusedDone:
         self.start_ns = start_ns
         self.collector = collector
         self.stages = stages
+        self.opened = opened          # the handler stage's layer span
         self.called = False
 
     def __call__(self) -> None:
@@ -1748,7 +1766,8 @@ class _FusedDone:
         latency_us = (t_done - self.start_ns) // 1000
         stages = self.stages
         if stages:
-            b._record_stage("handler", latency_us, None)
+            b._record_stage("handler", self.start_ns, t_done, None,
+                            self.token, self.opened)
         d = cntl.__dict__
         if d.get("_session_data") is not None:
             cntl._release_session_data()
@@ -1782,9 +1801,8 @@ class _FusedDone:
                     att_host, segs, (status, 0, latency_us, server),
                     0, pass_h)
             if stages:
-                b._record_stage(
-                    "encode", (_time.monotonic_ns() - t_done) // 1000,
-                    None)
+                b._record_stage("encode", t_done, _time.monotonic_ns(),
+                                None, self.token)
         coll = self.collector
         if coll is None or not coll.add(item):
             b._respond_item(item)
@@ -1966,6 +1984,9 @@ class ChannelBinding:
         blocked = scheduler.in_worker()
         if blocked:
             scheduler.note_worker_blocked()
+        # layer span brpc.call.wait: the one call into the native core
+        ls = _lspan.layer_begin("brpc.call.wait") \
+            if _lspan.layer_on() else None
         try:
             rc = self._call3(
                 self._handle, name_b, reqb, len(req), attb,
@@ -1973,6 +1994,8 @@ class ChannelBinding:
                 tenant_b, int(tms) if tms is not None and tms > 0 else 0,
                 out_ref)
         finally:
+            if ls is not None:
+                ls.end()
             if blocked:
                 scheduler.note_worker_unblocked()
         try:
@@ -2198,6 +2221,8 @@ class ChannelBinding:
             blocked = getattr(scheduler._tls, "group", None) is not None
             if blocked:
                 scheduler.note_worker_blocked()
+            ls = _lspan.layer_begin("brpc.call.wait") \
+                if _lspan.layer_on() else None
             try:
                 rc = self._callf(
                     self._handle, name_b, req or None, len(req),
@@ -2206,6 +2231,8 @@ class ChannelBinding:
                     int(tms) if tms is not None and tms > 0 else 0,
                     out_ref)
             finally:
+                if ls is not None:
+                    ls.end()
                 if blocked:
                     scheduler.note_worker_unblocked()
             result = None

@@ -35,6 +35,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 from ..butil.endpoint import EndPoint
 from ..butil import flags as _flags
+from ..butil import layer_span as _span
 from ..butil import debug_sync as _dbg
 from ..butil import logging as log
 from ..butil.iobuf import IOBuf, IOPortal, DEVICE
@@ -130,31 +131,39 @@ class CreditWindow:
 
     def _wait_writable(self, timeout: float = 30.0) -> bool:
         deadline = _time.monotonic() + timeout
-        while not self.failed:
-            gen = self._window_gen.value
-            with self._window_lock:
-                if self._send_window > 0:
-                    return True
-            if self._peer_gone():
-                self.set_failed(errors.EFAILEDSOCKET,
-                                "ici peer closed while window full")
-                return False
-            left = deadline - _time.monotonic()
-            if left <= 0:
-                # a stalled window must not black-hole the socket: fail it
-                # so queued writes complete with an error and callers see
-                # EFAILEDSOCKET rather than waiting forever
-                log.error("ici socket to %s: send window stalled >%.0fs "
-                          "(peer not consuming); %d bytes unacked — "
-                          "socket set failed", self.remote_side, timeout,
-                          self.unacked_send_bytes())
-                self.set_failed(
-                    errors.EFAILEDSOCKET,
-                    f"ici send window stalled >{timeout:.0f}s "
-                    f"(peer not consuming)")
-                return False
-            self._window_gen.wait(gen, min(left, 0.5))
-        return False
+        stall = None    # layer span brpc.ici.stall, once it really waits
+        try:
+            while not self.failed:
+                gen = self._window_gen.value
+                with self._window_lock:
+                    if self._send_window > 0:
+                        return True
+                if self._peer_gone():
+                    self.set_failed(errors.EFAILEDSOCKET,
+                                    "ici peer closed while window full")
+                    return False
+                left = deadline - _time.monotonic()
+                if left <= 0:
+                    # a stalled window must not black-hole the socket: fail
+                    # it so queued writes complete with an error and callers
+                    # see EFAILEDSOCKET rather than waiting forever
+                    log.error("ici socket to %s: send window stalled >%.0fs "
+                              "(peer not consuming); %d bytes unacked — "
+                              "socket set failed", self.remote_side, timeout,
+                              self.unacked_send_bytes())
+                    self.set_failed(
+                        errors.EFAILEDSOCKET,
+                        f"ici send window stalled >{timeout:.0f}s "
+                        f"(peer not consuming)")
+                    return False
+                if stall is None and _span.layer_on():
+                    stall = _span.layer_begin(
+                        "brpc.ici.stall", n=self.unacked_send_bytes())
+                self._window_gen.wait(gen, min(left, 0.5))
+            return False
+        finally:
+            if stall is not None:
+                stall.end()
 
 
 class OrderedDelivery:
@@ -172,13 +181,16 @@ class OrderedDelivery:
     # fablint: init
     def _init_delivery(self) -> None:
         import collections
-        self._dq = collections.deque()    # entries: [ready, commit_fn]
+        self._dq = collections.deque()    # entries: [ready, commit_fn, mark]
         self._dq_lock = _dbg.make_lock("OrderedDelivery._dq_lock")
         self._dq_draining = False
 
     def _enqueue_delivery(self, waits: List,
                           commit_fn: Callable[[], None]) -> None:
-        entry = [False, commit_fn]
+        # layer span brpc.ici.gate: from here until commit_fn runs, behind
+        # its own gates and the entries ahead of it
+        entry = [False, commit_fn,
+                 _span.layer_mark(len(waits)) if _span.layer_on() else None]
         with self._dq_lock:
             self._dq.append(entry)
 
@@ -219,8 +231,10 @@ class OrderedDelivery:
                         or not self._dq[0][0]):
                     return
                 self._dq_draining = True
-                fn = self._dq.popleft()[1]
+                _, fn, mark = self._dq.popleft()
             try:
+                if mark is not None:
+                    _span.layer_waited("brpc.ici.gate", mark)
                 fn()
             finally:
                 with self._dq_lock:
@@ -273,9 +287,21 @@ class IciSocket(CreditWindow, OrderedDelivery, Socket):
         n = self._consume_window(len(data))
         if n < 0:
             return -1                     # window full: not writable now
-        frame = data.cut(n)
-        chunks = self._relocate(frame)
-        self._deliver(peer, chunks)
+        # layer spans: one window piece, and as its child (stamped, in the
+        # store only) the cut and the slice / device_put / plane post
+        # dispatches
+        piece = _span.layer_begin("brpc.ici.piece", n=n) \
+            if _span.layer_on() else None
+        try:
+            frame = data.cut(n)
+            chunks = self._relocate(frame)
+            if piece is not None:
+                _span.layer_record("brpc.ici.relocate", piece.start_ns,
+                                   _time.perf_counter_ns())
+            self._deliver(peer, chunks)
+        finally:
+            if piece is not None:
+                piece.end()
         global _ici_bytes_moved
         with _ici_stats_lock:
             _ici_bytes_moved += n

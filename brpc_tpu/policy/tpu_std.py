@@ -16,6 +16,7 @@ from typing import Any
 from .. import bvar
 from ..butil.iobuf import IOBuf
 from ..butil import flags as _flags
+from ..butil import layer_span as _span
 from ..butil import logging as log
 from ..bthread import id as bthread_id
 from ..proto import rpc_meta_pb2 as meta_pb
@@ -47,6 +48,11 @@ HEADER_SIZE = 12
 # the five tpu_std_server_<stage> recorders on EVERY request (the
 # /vars-distribution mode for dedicated measurement runs); "off"
 # disables everything.
+#
+# The same five boundaries are the layer spans brpc.server.<stage>
+# (butil/layer_span.py): while a profiler session is on, every request
+# is decomposed unless the flag is "off", and ONE clock read per
+# boundary feeds the recorders, the rpcz annotation and the layer span.
 _flags.define_flag("tpu_std_stage_metrics", "sampled",
                    "per-stage server latency decomposition: 'sampled' "
                    "(annotations on rpcz-sampled spans), 'on' (every "
@@ -60,20 +66,53 @@ _stage_recorders = {s: bvar.LatencyRecorder(f"tpu_std_server_{s}")
 _stage_flag = _flags.flag_object("tpu_std_stage_metrics")
 
 
-def _stages_active(cntl: Controller) -> bool:
+_STAGE_SPANS = {s: f"brpc.server.{s}" for s in _STAGES}
+
+
+def _stages_on() -> bool:
+    """Every request is decomposed: the flag says so, or a profiler
+    session wants the layer spans and the flag is not "off"."""
     mode = _stage_flag.value
-    if mode == "on":
+    return mode == "on" or (mode != "off" and _span.layer_on())
+
+
+def _stages_active(cntl: Controller) -> bool:
+    if _stages_on():
         return True
-    if mode == "off":
-        return False
-    return cntl.span is not None
+    return _stage_flag.value != "off" and cntl.span is not None
 
 
-def _record_stage(stage: str, us: int, span) -> None:
+def _record_stage(stage: str, start_ns: int, end_ns: int, span,
+                  call_id: int = 0, opened=None) -> None:
+    """One stage of one request, from its two stamps (CLOCK_MONOTONIC:
+    ``time.monotonic_ns``, the native tier's ``recv_ns`` and
+    ``perf_counter_ns`` read the same clock), to each consumer that is
+    on: the ``tpu_std_server_<stage>`` recorder, the rpcz span's
+    annotation, the layer span ``brpc.server.<stage>`` (``opened``: the
+    one ``_enter_handler`` began)."""
+    us = max(end_ns - start_ns, 0) // 1000
     if _stage_flag.value == "on":
         _stage_recorders[stage] << us
     if span is not None:
         span.annotate(f"{stage}_us={us}")
+    if opened is not None:
+        opened.finish(end_ns)
+    elif _span.layer_on():
+        _span.layer_record(_STAGE_SPANS[stage], start_ns, end_ns, call_id)
+
+
+def _enter_handler(start_ns: int, call_id: int):
+    """The handler stage as the thread's innermost layer span while user
+    code runs on it, so that what the handler hands to other threads (a
+    device completion, a nested call) names it as its cause.  The caller
+    ``leave()``s it when the handler returns; ``_record_stage`` finishes
+    it at ``done()``, on whichever thread that runs."""
+    if not _span.layer_on():
+        return None
+    opened = _span.layer_begin(_STAGE_SPANS["handler"], call_id)
+    if opened is not None:
+        opened.start_ns = start_ns
+    return opened
 
 
 def stage_p50s_us() -> dict:
@@ -274,19 +313,18 @@ def process_request(msg: StdMessage, socket, server) -> None:
                       req_meta.span_id)
     stages = _stages_active(cntl)
     if stages and msg.recv_ns:
-        _record_stage("queue",
-                      (time.monotonic_ns() - msg.recv_ns) // 1000,
-                      cntl.span)
+        _record_stage("queue", msg.recv_ns, time.monotonic_ns(),
+                      cntl.span, cid)
     md = server.find_method(full_name)
     status = server.method_status(full_name) if md is not None else None
     server_counted = [False]
-    handler_t0 = [0]
+    handler_t0 = [0, None]      # start stamp, the open layer span
 
     def send_response(resp: Any = None) -> None:
         t_enc0 = time.monotonic_ns() if stages else 0
         if stages and handler_t0[0]:
-            _record_stage("handler", (t_enc0 - handler_t0[0]) // 1000,
-                          cntl.span)
+            _record_stage("handler", handler_t0[0], t_enc0, cntl.span, cid,
+                          handler_t0[1])
         rmeta = meta_pb.RpcMeta()
         rmeta.correlation_id = cid
         rmeta.response.error_code = cntl.error_code_
@@ -319,12 +357,11 @@ def process_request(msg: StdMessage, socket, server) -> None:
         frame = pack_frame(rmeta, payload)
         t_wr0 = time.monotonic_ns() if stages else 0
         if stages:
-            _record_stage("encode", (t_wr0 - t_enc0) // 1000, cntl.span)
+            _record_stage("encode", t_enc0, t_wr0, cntl.span, cid)
         socket.write(frame)
         if stages:
-            _record_stage("write",
-                          (time.monotonic_ns() - t_wr0) // 1000,
-                          cntl.span)
+            _record_stage("write", t_wr0, time.monotonic_ns(), cntl.span,
+                          cid)
         if cntl.span is not None:
             end_server_span(cntl)
         if status is not None:
@@ -366,13 +403,14 @@ def process_request(msg: StdMessage, socket, server) -> None:
             cntl._maybe_recycle()
             return
         if stages:
-            _record_stage("parse",
-                          (time.monotonic_ns() - t_parse0) // 1000,
-                          cntl.span)
+            _record_stage("parse", t_parse0, time.monotonic_ns(),
+                          cntl.span, cid)
 
         response = md.response_cls()
         done_called = [False]
-        handler_t0[0] = time.monotonic_ns() if stages else 0
+        if stages:
+            handler_t0[0] = time.monotonic_ns()
+            handler_t0[1] = _enter_handler(handler_t0[0], cid)
 
         def done() -> None:
             if done_called[0]:
@@ -382,7 +420,11 @@ def process_request(msg: StdMessage, socket, server) -> None:
 
         cntl.set_server_done(done)
         try:
-            md.invoke(cntl, request, response, done)
+            try:
+                md.invoke(cntl, request, response, done)
+            finally:
+                if handler_t0[1] is not None:
+                    handler_t0[1].leave()
         except Exception as e:   # uncaught user exception → EINTERNAL
             log.error("method %s raised: %s", full_name, e, exc_info=True)
             if not done_called[0]:
@@ -444,7 +486,9 @@ def process_request(msg: StdMessage, socket, server) -> None:
         server_counted[0] = True
         if stages and queued_us:
             # admission-queue wait feeds the queue-stage decomposition
-            _record_stage("queue", queued_us, cntl.span)
+            now = time.monotonic_ns()
+            _record_stage("queue", now - queued_us * 1000, now, cntl.span,
+                          cid)
         if server.options.auth is not None:
             if not server.options.auth.verify(cntl.auth_token, socket):
                 cntl.set_failed(errors.ERPCAUTH, "authentication failed")

@@ -12,6 +12,7 @@ from dataclasses import dataclass
 from typing import Any, Callable, Optional
 
 from ..butil.endpoint import EndPoint, parse_endpoint
+from ..butil import layer_span as _span
 from . import errors
 from .controller import Controller
 from .input_messenger import InputMessenger
@@ -159,155 +160,176 @@ class Channel:
                     request: Any, response_cls: Any = None,
                     done: Optional[Callable[[Controller], None]] = None):
         """Sync when done is None (returns the response); async otherwise."""
-        # fused native fast path (ISSUE 13): a cached in-process ici
-        # binding bound with ici_fused_dispatch serves sync calls
-        # through ONE flat code object (context inherit, screens, issue,
-        # response, error tails all inside call_fused).  Anything it
-        # can't serve — oversize frames, hedging, a dead conn's one-shot
-        # re-route — returns the FALLTHROUGH sentinel and the unfused
-        # body below handles it exactly as before.
-        nch0 = self._native_ici
-        if (nch0 is not None and done is None and nch0._fused
-                and cntl.stream_creator is None):
-            result = nch0.call_fused(method_full_name, cntl, request,
-                                     response_cls, self)
-            if result is not nch0.FUSED_FALLTHROUGH:
-                return result
-            skip_native = True     # the fused leg already decided the
-        else:                      # re-route; don't re-enter the native
-            skip_native = False    # block below
-        # cascading inbound context (rpc/request_context.py): a call made
-        # inside a handler's scope inherits the inbound priority/tenant
-        # unless THIS call overrides them, and its timeout is capped at
-        # the inbound deadline budget minus the handler time already
-        # spent.  Inherited values beat channel-wide defaults (a static
-        # channel config must not demote a critical inbound request).
-        _ctx = _reqctx.current()
-        if _ctx is not None:
-            if cntl.priority is None and _ctx.priority is not None:
-                cntl.priority = _ctx.priority
-            if not cntl.tenant and _ctx.tenant:
-                cntl.tenant = _ctx.tenant
-            residual = _ctx.residual_deadline_ms()
-            if residual is not None:
-                if residual <= 0:
-                    cntl.set_failed(
-                        errors.ERPCTIMEDOUT,
-                        "inherited deadline budget spent before call")
-                    if cntl.span is not None:
-                        end_client_span(cntl)
-                    if done is not None:
-                        done(cntl)
-                        return None
-                    return None
-                base = cntl.timeout_ms if cntl.timeout_ms is not None \
-                    else self.options.timeout_ms
-                if base is None or base <= 0 or base > residual:
-                    cntl.timeout_ms = max(int(residual), 1)
-        # channel-level admission defaults (per-call Controller wins)
-        if cntl.priority is None and self.options.priority is not None:
-            cntl.priority = self.options.priority
-        if not cntl.tenant and self.options.tenant:
-            cntl.tenant = self.options.tenant
-        # ici:// fast path: when the target device has a native listener in
-        # this process, the whole unary hot path (frame/window/dispatch/
-        # correlation) runs in native/rpc.cpp — no Python between
-        # serialize and parse except device-ref relocation (VERDICT r3 #1).
-        # Streaming, auth, non-tpu_std protocols, backup-request hedging,
-        # and frames too large for the native send window ride the Python
-        # plane (which drains big payloads chunkwise through its credit
-        # window).
-        nch = None if skip_native else self._native_ici
-        if nch is None:
-            if not skip_native:
-                nch = self._native_ici_binding(cntl)
-        elif cntl.stream_creator is not None:
-            # the cached-binding fast path must re-screen the ONE
-            # eligibility input that varies per call; the channel-level
-            # ones (protocol, auth, endpoint) were screened at cache time
-            nch = None
-        if nch is not None and not self._fast_call_fits(nch, cntl, request):
-            nch = None
-        if nch is not None:
-            if cntl.timeout_ms is None:
-                cntl.timeout_ms = self.options.timeout_ms
-            if done is None:
-                result = self._native_ici_call(nch, method_full_name, cntl,
-                                               request, response_cls)
-                result = self._native_shed_retry(nch, method_full_name,
-                                                 cntl, request,
-                                                 response_cls, result)
-                if not self._native_ici_fallback(cntl):
-                    if cntl.span is not None:
-                        end_client_span(cntl)
+        # layer span brpc.call: entry -> return, under the correlation id
+        # where this plane has one (the native tier correlates in C++)
+        ls = _span.layer_begin("brpc.call") if _span.layer_on() else None
+        try:
+            # fused native fast path (ISSUE 13): a cached in-process ici
+            # binding bound with ici_fused_dispatch serves sync calls
+            # through ONE flat code object (context inherit, screens, issue,
+            # response, error tails all inside call_fused).  Anything it
+            # can't serve — oversize frames, hedging, a dead conn's one-shot
+            # re-route — returns the FALLTHROUGH sentinel and the unfused
+            # body below handles it exactly as before.
+            nch0 = self._native_ici
+            if (nch0 is not None and done is None and nch0._fused
+                    and cntl.stream_creator is None):
+                result = nch0.call_fused(method_full_name, cntl, request,
+                                         response_cls, self)
+                if result is not nch0.FUSED_FALLTHROUGH:
                     return result
-            else:
-                from ..bthread import scheduler
-
-                def _run():
-                    try:
-                        self._native_ici_call(nch, method_full_name, cntl,
-                                              request, response_cls)
-                    except Exception as e:   # done() must ALWAYS fire
-                        if not cntl.failed():
-                            cntl.set_failed(errors.EINTERNAL,
-                                            f"{type(e).__name__}: {e}")
-                        done(cntl)
-                        return
-                    if self._native_ici_fallback(cntl):
-                        # dead native conn (server restarted) or oversize
-                        # fast-fail: re-route through the Python plane
-                        self.call_method(method_full_name, cntl, request,
-                                         response_cls, done=done)
-                    else:
+                skip_native = True     # the fused leg already decided the
+            else:                      # re-route; don't re-enter the native
+                skip_native = False    # block below
+            # cascading inbound context (rpc/request_context.py): a call made
+            # inside a handler's scope inherits the inbound priority/tenant
+            # unless THIS call overrides them, and its timeout is capped at
+            # the inbound deadline budget minus the handler time already
+            # spent.  Inherited values beat channel-wide defaults (a static
+            # channel config must not demote a critical inbound request).
+            _ctx = _reqctx.current()
+            if _ctx is not None:
+                if cntl.priority is None and _ctx.priority is not None:
+                    cntl.priority = _ctx.priority
+                if not cntl.tenant and _ctx.tenant:
+                    cntl.tenant = _ctx.tenant
+                residual = _ctx.residual_deadline_ms()
+                if residual is not None:
+                    if residual <= 0:
+                        cntl.set_failed(
+                            errors.ERPCTIMEDOUT,
+                            "inherited deadline budget spent before call")
                         if cntl.span is not None:
                             end_client_span(cntl)
-                        done(cntl)
-
-                scheduler.start_background(
-                    _run, name=f"ici-call:{method_full_name}")
-                return None
-        # mem:// loopback fast plane (loopback.py): in-process direct
-        # dispatch, no byte codec / socket machinery.  Per-call screens:
-        # anything the wire plane implements that loopback doesn't
-        # (streaming handshakes, compression, fault injection, rpc_dump
-        # sampling) falls through.
-        lb_name = getattr(self, "_loopback_name", None)
-        if (lb_name is not None and cntl.stream_creator is None
-                and cntl.compress_type == 0 and not cntl.auth_token
-                and _loopback.enabled()):
-            hot = _loopback_screen_modules()
-            _fi, _dump, _stage_flag = hot
-            if cntl.span is None:
-                maybe_start_client_span(cntl, method_full_name)
-            srv = _loopback.server_for(lb_name)
-            # rpcz-sampled requests and the stage-metrics measurement
-            # mode ride the wire plane: they exist to observe it (server
-            # span, five-stage decomposition); auth verification needs
-            # the wire socket context
-            if (srv is not None and cntl.span is None
-                    and srv.options.auth is None
-                    and not self._loopback_breaker.is_isolated()
-                    and _stage_flag.value != "on"
-                    and _fi.active() is None
-                    and not _dump.dump_enabled()):
+                        if done is not None:
+                            done(cntl)
+                            return None
+                        return None
+                    base = cntl.timeout_ms if cntl.timeout_ms is not None \
+                        else self.options.timeout_ms
+                    if base is None or base <= 0 or base > residual:
+                        cntl.timeout_ms = max(int(residual), 1)
+            # channel-level admission defaults (per-call Controller wins)
+            if cntl.priority is None and self.options.priority is not None:
+                cntl.priority = self.options.priority
+            if not cntl.tenant and self.options.tenant:
+                cntl.tenant = self.options.tenant
+            # ici:// fast path: when the target device has a native listener in
+            # this process, the whole unary hot path (frame/window/dispatch/
+            # correlation) runs in native/rpc.cpp — no Python between
+            # serialize and parse except device-ref relocation (VERDICT r3 #1).
+            # Streaming, auth, non-tpu_std protocols, backup-request hedging,
+            # and frames too large for the native send window ride the Python
+            # plane (which drains big payloads chunkwise through its credit
+            # window).
+            nch = None if skip_native else self._native_ici
+            if nch is None:
+                if not skip_native:
+                    nch = self._native_ici_binding(cntl)
+            elif cntl.stream_creator is not None:
+                # the cached-binding fast path must re-screen the ONE
+                # eligibility input that varies per call; the channel-level
+                # ones (protocol, auth, endpoint) were screened at cache time
+                nch = None
+            if nch is not None \
+                    and not self._fast_call_fits(nch, cntl, request):
+                nch = None
+            if nch is not None:
                 if cntl.timeout_ms is None:
                     cntl.timeout_ms = self.options.timeout_ms
-                # loopback completes the client span itself (the span
-                # ends with the response, also on async completions)
-                return _loopback.call(srv, method_full_name, cntl,
-                                      request, response_cls, done)
-        if self.options.auth is not None and not cntl.auth_token:
-            cntl.auth_token = self.options.auth.generate_credential(cntl)
-        payload = self._protocol.serialize_request(request, cntl)
-        if cntl.span is None:
-            maybe_start_client_span(cntl, method_full_name)
-        cntl._start_call(self, method_full_name, payload, response_cls, done)
-        if done is None:
-            timeout = ((cntl.timeout_ms or 0) / 1000.0 + 35.0)
-            cntl.join(timeout)
+                if done is None:
+                    result = self._native_ici_call(nch, method_full_name, cntl,
+                                                   request, response_cls)
+                    result = self._native_shed_retry(nch, method_full_name,
+                                                     cntl, request,
+                                                     response_cls, result)
+                    if not self._native_ici_fallback(cntl):
+                        if cntl.span is not None:
+                            end_client_span(cntl)
+                        return result
+                else:
+                    from ..bthread import scheduler
+
+                    def _run():
+                        try:
+                            self._native_ici_call(nch, method_full_name, cntl,
+                                                  request, response_cls)
+                        except Exception as e:   # done() must ALWAYS fire
+                            if not cntl.failed():
+                                cntl.set_failed(errors.EINTERNAL,
+                                                f"{type(e).__name__}: {e}")
+                            done(cntl)
+                            return
+                        if self._native_ici_fallback(cntl):
+                            # dead native conn (server restarted) or oversize
+                            # fast-fail: re-route through the Python plane
+                            self.call_method(method_full_name, cntl, request,
+                                             response_cls, done=done)
+                        else:
+                            if cntl.span is not None:
+                                end_client_span(cntl)
+                            done(cntl)
+
+                    scheduler.start_background(
+                        _run, name=f"ici-call:{method_full_name}")
+                    return None
+            # mem:// loopback fast plane (loopback.py): in-process direct
+            # dispatch, no byte codec / socket machinery.  Per-call screens:
+            # anything the wire plane implements that loopback doesn't
+            # (streaming handshakes, compression, fault injection, rpc_dump
+            # sampling) falls through.
+            lb_name = getattr(self, "_loopback_name", None)
+            if (lb_name is not None and cntl.stream_creator is None
+                    and cntl.compress_type == 0 and not cntl.auth_token
+                    and _loopback.enabled()):
+                hot = _loopback_screen_modules()
+                _fi, _dump, _stage_flag = hot
+                if cntl.span is None:
+                    maybe_start_client_span(cntl, method_full_name)
+                srv = _loopback.server_for(lb_name)
+                # rpcz-sampled requests and the stage-metrics measurement
+                # mode ride the wire plane: they exist to observe it (server
+                # span, five-stage decomposition); auth verification needs
+                # the wire socket context
+                if (srv is not None and cntl.span is None
+                        and srv.options.auth is None
+                        and not self._loopback_breaker.is_isolated()
+                        and _stage_flag.value != "on"
+                        and _fi.active() is None
+                        and not _dump.dump_enabled()):
+                    if cntl.timeout_ms is None:
+                        cntl.timeout_ms = self.options.timeout_ms
+                    # loopback completes the client span itself (the span
+                    # ends with the response, also on async completions)
+                    return _loopback.call(srv, method_full_name, cntl,
+                                          request, response_cls, done)
+            if self.options.auth is not None and not cntl.auth_token:
+                cntl.auth_token = self.options.auth.generate_credential(cntl)
+            payload = self._protocol.serialize_request(request, cntl)
+            if cntl.span is None:
+                maybe_start_client_span(cntl, method_full_name)
+            if done is not None:
+                cntl._start_call(self, method_full_name, payload,
+                                 response_cls, done)
+                return None
+            # layer span brpc.call.wait: the frame handed to the socket (the
+            # caller writes the first window piece itself) until this thread
+            # resumes with the response
+            wait = _span.layer_begin("brpc.call.wait") \
+                if ls is not None else None
+            try:
+                cntl._start_call(self, method_full_name, payload,
+                                 response_cls, done)
+                cntl.join((cntl.timeout_ms or 0) / 1000.0 + 35.0)
+            finally:
+                if wait is not None:
+                    wait.call_id = cntl._cid
+                    wait.end()
             return cntl.response
-        return None
+        finally:
+            if ls is not None:
+                ls.call_id = cntl._cid
+                ls.end()
 
     def _fast_call_fits(self, nch, cntl: Controller, request) -> bool:
         """Per-call screen for the native fast plane: the frame (payload
@@ -476,6 +498,8 @@ class Channel:
         cntl.remote_side = sock.remote_side
         cntl._pack_socket = sock       # connection-stateful protocols (h2)
         cid = cntl.current_cid()
+        if _span.layer_on():
+            _span.layer_adopt_call(cid)
         packet = self._protocol.pack_request(
             cntl._request_buf, cid, cntl, cntl._method_full_name)
         if cntl.span is not None:

@@ -134,8 +134,13 @@ class ContentionMutex:
 # ---- device profiling (jax tracer) ------------------------------------
 
 def start_device_trace(log_dir: str) -> bool:
+    """A jax profiler session: the device's operations and, with them, the
+    RPC stack's layer spans (butil/layer_span.py) on the host plane and in
+    ``span.layer_spans()``, which starts empty."""
     try:
         import jax
+        from . import span
+        span.layer_spans_reset()
         jax.profiler.start_trace(log_dir)
         return True
     except Exception:

@@ -217,3 +217,15 @@ def recent_spans(limit: int = 100) -> List[Span]:
 def find_trace(trace_id: int) -> List[Span]:
     with _store_lock:
         return [s for s in _store if s.trace_id == trace_id]
+
+
+# ---- layer spans ------------------------------------------------------
+#
+# The other recorder: every request of a profiler session, by layer, on
+# the profiler's clock (rpcz above is sampled, cross-process, for /rpcz).
+# It lives in butil/layer_span.py, a leaf, because bthread and ici record
+# into it too; readers and the rpc layer's own sites use it by these names.
+from ..butil.layer_span import (  # noqa: E402,F401
+    LAYER_SPAN_CAP, LayerMark, LayerSpan, layer_adopt_call, layer_begin,
+    layer_mark, layer_on, layer_record, layer_spans, layer_spans_dropped,
+    layer_spans_reset, layer_waited)

@@ -1,0 +1,509 @@
+"""Layer spans (brpc_tpu/butil/layer_span.py, by the names rpc/span.py
+re-exports): the recorder that a jax profiler
+session switches on, and the span sites at the layer boundaries — the client
+call, the five server stages, the ici plane's window pieces, the device
+poller.
+
+One profiler session is made for the whole file (``traced``): a call whose
+reply is parked on a device completion over the native ici tier and over tcp
+tpu_std, and an echo whose frames cross a small ici send window in three
+pieces each way.  Most cases below read what that session recorded; the
+closed window's stall is driven on a socket pair, where it cannot be missed.
+"""
+import glob
+import os
+import subprocess
+import sys
+import time
+
+import numpy as np
+import pytest
+
+import brpc_tpu.policy  # noqa: F401  (registers protocols)
+from brpc_tpu import rpc, ici
+from brpc_tpu.bthread.device_waiter import device_on_ready
+from brpc_tpu.butil import flags as _flags
+from brpc_tpu.butil import layer_span
+from brpc_tpu.ici import native_plane
+from brpc_tpu.rpc import span
+from tests.echo_pb2 import EchoRequest, EchoResponse
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+STAGES = ["queue", "parse", "handler", "encode", "write"]
+WINDOW = 2 << 20                # the small send window of the 3-piece echo
+BULK = 5_000_000                # above the native tier's 4 MB window
+
+
+class LayerService(rpc.Service):
+    SERVICE_NAME = "LayerService"
+
+    @rpc.method(EchoRequest, EchoResponse)
+    def Echo(self, cntl, request, response, done):
+        response.message = request.message
+        cntl.response_attachment.append(cntl.request_attachment)
+        done()
+
+    @rpc.method(EchoRequest, EchoResponse)
+    def Parked(self, cntl, request, response, done):
+        """Compute on the device, answer on its completion."""
+        import jax.numpy as jnp
+        x = jnp.asarray(np.frombuffer(
+            cntl.request_attachment.to_bytes(), dtype=np.uint8))
+        y = x ^ jnp.uint8(0x5A)
+
+        def reply():
+            response.message = request.message
+            done()
+
+        cntl.response_attachment.append_device_array(y)
+        device_on_ready([y], reply)
+
+
+def _call(ch, method, payload, message="m"):
+    cntl = rpc.Controller()
+    cntl.request_attachment.append_device_array(payload)
+    resp = ch.call_method(f"LayerService.{method}", cntl,
+                          EchoRequest(message=message), EchoResponse)
+    assert not cntl.failed(), cntl.error_text
+    assert resp.message == message
+    return cntl
+
+
+class _Deployment:
+    def __init__(self, address, options=None, server_options=None):
+        self.server = rpc.Server(server_options or rpc.ServerOptions())
+        self.server.add_service(LayerService())
+        assert self.server.start(address) == 0
+        if address.startswith("tcp"):
+            address = f"tcp://127.0.0.1:{self.server.listen_port}"
+        self.channel = rpc.Channel()
+        assert self.channel.init(
+            address, options=options or rpc.ChannelOptions()) == 0
+
+    def close(self):
+        self.channel.close()
+        self.server.stop()
+
+
+@pytest.fixture(scope="module")
+def mesh():
+    import jax
+    m = ici.IciMesh(jax.devices())
+    before = ici.IciMesh._default
+    ici.IciMesh.set_default(m)
+    yield m
+    ici.IciMesh.set_default(before)
+
+
+def _payload(mesh, dev, nbytes):
+    import jax
+    import jax.numpy as jnp
+    arr = jax.device_put(jnp.arange(nbytes, dtype=jnp.uint8),
+                         mesh.device(dev))
+    jax.block_until_ready(arr)
+    return arr
+
+
+@pytest.fixture(scope="module")
+def traced(mesh, tmp_path_factory):
+    """{scenario: its spans}, the session's trace directory under
+    ``"dir"``, what the store held before the session under ``"before"``."""
+    import jax
+    if not native_plane.available():
+        pytest.skip("native core unavailable")
+    trace_dir = str(tmp_path_factory.mktemp("layer_trace"))
+    old_window = _flags.get_flag("ici_socket_window_bytes")
+    native = _Deployment("ici://2", rpc.ChannelOptions(ici_local_device=2))
+    tcp = _Deployment("tcp://127.0.0.1:0")
+    _flags.set_flag("ici_socket_window_bytes", WINDOW)
+    inline = rpc.ServerOptions()
+    inline.usercode_inline = True
+    bulk = _Deployment("ici://3", rpc.ChannelOptions(ici_local_device=3),
+                       inline)
+    small, big = _payload(mesh, 2, 4096), _payload(mesh, 3, BULK)
+    out = {}
+    try:
+        span.layer_spans_reset()
+        for _ in range(100):            # no session: nothing is recorded
+            _call(native.channel, "Echo", small)
+        _call(tcp.channel, "Parked", small)
+        _call(bulk.channel, "Echo", big)
+        out["before"] = (span.layer_spans(), span.layer_spans_dropped())
+        jax.profiler.start_trace(trace_dir)
+        try:
+            for name, dep, method, payload in (
+                    ("native", native, "Parked", small),
+                    ("tcp", tcp, "Parked", small),
+                    ("bulk", bulk, "Echo", big)):
+                since = span.layer_mark().ns
+                cntl = _call(dep.channel, method, payload)
+                # the server stamps its write stage once the respond call
+                # has returned, which the caller's wake-up may beat
+                deadline = time.monotonic() + 10
+                while not _named(span.layer_spans(since),
+                                 "brpc.server.write") \
+                        and time.monotonic() < deadline:
+                    time.sleep(0.005)
+                out[name] = span.layer_spans(since)
+                if name == "bulk":
+                    assert cntl.response_attachment.to_bytes() == bytes(
+                        np.asarray(big))
+        finally:
+            jax.profiler.stop_trace()
+        out["dir"] = trace_dir
+        out["native_requests"] = native.server._native_ici.requests()
+    finally:
+        _flags.set_flag("ici_socket_window_bytes", old_window)
+        for dep in (native, tcp, bulk):
+            dep.close()
+        span.layer_spans_reset()
+    return out
+
+
+def _named(spans, name):
+    return [s for s in spans if s.name == name]
+
+
+def _one(spans, name):
+    found = _named(spans, name)
+    assert len(found) == 1, (name, found)
+    return found[0]
+
+
+# ---- the switch ------------------------------------------------------------
+
+def test_span_module_imports_without_jax():
+    code = ("import sys; import brpc_tpu.rpc.span as s; "
+            "assert 'jax' not in sys.modules; assert s.layer_on() is False")
+    subprocess.run([sys.executable, "-c", code], cwd=REPO, check=True,
+                   timeout=120)
+
+
+def test_the_poller_reaches_the_recorder_without_the_rpc_layer():
+    code = ("import sys; import brpc_tpu.bthread.device_waiter; "
+            "import brpc_tpu.butil.layer_span as s; "
+            "assert not [m for m in sys.modules if m.startswith("
+            "'brpc_tpu.rpc') or m == 'jax']; assert s.layer_on() is False")
+    subprocess.run([sys.executable, "-c", code], cwd=REPO, check=True,
+                   timeout=120)
+
+
+@pytest.mark.parametrize("mode,session,want", [
+    ("on", False, True), ("sampled", True, True), ("sampled", False, False),
+    ("off", True, False),       # "off" disables everything, a session too
+])
+def test_a_session_decomposes_every_request_unless_the_flag_is_off(
+        monkeypatch, mode, session, want):
+    from brpc_tpu.policy import tpu_std
+    monkeypatch.setattr(tpu_std._stage_flag, "value", mode)
+    monkeypatch.setattr(layer_span, "layer_on", lambda: session)
+    assert tpu_std._stages_on() is want
+
+
+def test_no_profiler_session_no_record(traced):
+    spans, dropped = traced["before"]
+    assert spans == [] and dropped == 0
+    assert traced["native_requests"] >= 100     # the traffic was real
+    assert span.layer_on() is False
+
+
+# ---- one call, layer by layer ----------------------------------------------
+
+@pytest.mark.parametrize("plane", ["native", "tcp"])
+def test_call_span_encloses_its_wait(traced, plane):
+    spans = traced[plane]
+    call, wait = _one(spans, "brpc.call"), _one(spans, "brpc.call.wait")
+    assert call.start_ns <= wait.start_ns <= wait.end_ns <= call.end_ns
+    assert wait.cause_id == call.span_id and call.cause_id == 0
+    assert wait.thread == call.thread
+    if plane == "tcp":
+        assert call.call_id != 0 and wait.call_id == call.call_id
+    else:                       # native/rpc.cpp correlates: Python sees no id
+        assert call.call_id == 0
+
+
+@pytest.mark.parametrize("stage", STAGES)
+@pytest.mark.parametrize("plane", ["native", "tcp"])
+def test_server_stage_is_recorded_under_the_calls_id(traced, plane, stage):
+    spans = traced[plane]
+    call = _one(spans, "brpc.call")
+    got = _one(spans, f"brpc.server.{stage}")
+    assert call.start_ns <= got.start_ns <= got.end_ns <= call.end_ns
+    ids = {_one(spans, f"brpc.server.{s}").call_id for s in STAGES}
+    assert len(ids) == 1 and got.call_id != 0
+    if plane == "tcp":          # the correlation id rides RpcMeta
+        assert got.call_id == call.call_id
+
+
+@pytest.mark.parametrize("plane", ["native", "tcp"])
+def test_stages_follow_each_other(traced, plane):
+    q, p, h, e, w = (_one(traced[plane], f"brpc.server.{s}") for s in STAGES)
+    assert q.end_ns <= p.end_ns <= h.end_ns <= e.end_ns <= w.end_ns
+    # one clock read per boundary: the handler's end IS the encode's start
+    assert h.end_ns == e.start_ns and e.end_ns <= w.start_ns
+
+
+@pytest.mark.parametrize("plane", ["native", "tcp"])
+def test_poller_spans_in_order_caused_by_the_handler(traced, plane):
+    spans = traced[plane]
+    handler = _one(spans, "brpc.server.handler")
+    queue, block, callback = (_one(spans, f"brpc.poller.{s}")
+                              for s in ("queue", "block", "callback"))
+    assert handler.start_ns <= queue.start_ns <= queue.end_ns \
+        <= block.start_ns <= block.end_ns <= callback.start_ns \
+        <= callback.end_ns
+    assert {queue.cause_id, block.cause_id, callback.cause_id} == \
+        {handler.span_id}
+    assert {queue.call_id, block.call_id, callback.call_id} == \
+        {handler.call_id}
+    assert queue.thread.startswith("device_poller_")
+    assert queue.n == 0                 # nothing was parked ahead of it
+    # done() ran in the callback: the handler stage ends inside it, and the
+    # response's encode and write are its children
+    assert callback.start_ns <= handler.end_ns <= callback.end_ns
+    assert _one(spans, "brpc.server.write").cause_id == callback.span_id
+
+
+# ---- the ici plane's window -------------------------------------------------
+
+def test_three_pieces_each_way(traced):
+    spans = traced["bulk"]
+    pieces = _named(spans, "brpc.ici.piece")
+    assert len(pieces) == 6
+    frame = BULK + 12                   # attachment and header, at least
+    assert sum(p.n for p in pieces) >= 2 * frame
+    assert max(p.n for p in pieces) <= WINDOW
+    call = _one(spans, "brpc.call")
+    assert call.call_id != 0            # the Python plane: a correlation id
+    first = min(pieces, key=lambda p: p.start_ns)
+    assert first.call_id == call.call_id
+    assert first.cause_id == _one(spans, "brpc.call.wait").span_id
+
+
+@pytest.mark.parametrize("child", ["brpc.ici.relocate", "brpc.ici.gate"])
+def test_every_piece_has_one(traced, child):
+    spans = traced["bulk"]
+    pieces = _named(spans, "brpc.ici.piece")
+    kids = _named(spans, child)
+    assert sorted(k.cause_id for k in kids) == \
+        sorted(p.span_id for p in pieces)
+    by_id = {p.span_id: p for p in pieces}
+    for k in kids:
+        p = by_id[k.cause_id]
+        assert p.start_ns <= k.start_ns
+        if child == "brpc.ici.relocate":
+            assert k.end_ns <= p.end_ns and k.thread == p.thread
+
+
+@pytest.fixture
+def three_piece_write(mesh, monkeypatch):
+    """One write of three windows of bytes to a socket whose peer reads
+    only after the writer has met the closed window: the spans of it, the
+    sites switched on without a profiler session."""
+    from brpc_tpu.butil.iobuf import IOBuf, IOPortal
+    from brpc_tpu.ici.transport import IciSocket
+    import jax.profiler  # noqa: F401
+    layer_span.layer_on()   # binds the annotation class
+    span.layer_spans_reset()
+    monkeypatch.setattr(layer_span, "layer_on", lambda: True)
+    monkeypatch.setattr(_flags.flag_object("ici_socket_window_bytes"),
+                        "value", 4096)
+    a, b = IciSocket(0, 0, mesh), IciSocket(0, 0, mesh)
+    a.peer, b.peer = b, a
+    try:
+        done = []
+        assert a.write(IOBuf(b"x" * (3 * 4096)), on_done=done.append) == 0
+        deadline = time.monotonic() + 10
+        while not _named(span.layer_spans(), "brpc.ici.piece") \
+                and time.monotonic() < deadline:
+            time.sleep(0.005)
+        time.sleep(0.05)                # the writer is parked on the window
+        got, portal = 0, IOPortal()
+        while got < 3 * 4096 and time.monotonic() < deadline:
+            n = b._do_read(portal, 1 << 20)
+            got += max(n, 0)
+            if n <= 0:
+                time.sleep(0.005)
+        while not done and time.monotonic() < deadline:
+            time.sleep(0.005)
+        assert got == 3 * 4096 and done == [0]
+        yield span.layer_spans()
+    finally:
+        a.set_failed()
+        b.set_failed()
+        monkeypatch.undo()
+        span.layer_spans_reset()
+
+
+def test_three_piece_write_stalls_on_the_closed_window(three_piece_write):
+    spans = three_piece_write
+    pieces = _named(spans, "brpc.ici.piece")
+    assert [p.n for p in pieces] == [4096] * 3
+    for child in ("brpc.ici.relocate", "brpc.ici.gate"):
+        assert sorted(k.cause_id for k in _named(spans, child)) == \
+            sorted(p.span_id for p in pieces)
+    stalls = _named(spans, "brpc.ici.stall")
+    assert stalls and all(s.n == 4096 for s in stalls)  # unacked bytes
+    assert max(s.end_ns - s.start_ns for s in stalls) >= 40e6
+    # a stalled writer resumes with the next piece
+    assert all(any(p.start_ns >= s.end_ns for p in pieces) for s in stalls)
+
+
+def test_bulk_call_also_records_server_stages(traced):
+    spans = traced["bulk"]
+    call = _one(spans, "brpc.call")
+    for s in STAGES:
+        assert _one(spans, f"brpc.server.{s}").call_id == call.call_id
+
+
+# ---- the same clock as the device trace -------------------------------------
+
+@pytest.mark.parametrize("name", ["brpc.call", "brpc.call.wait",
+                                  "brpc.server.handler",
+                                  "brpc.poller.block", "brpc.poller.callback",
+                                  "brpc.ici.piece"])
+def test_lexical_span_is_on_the_profilers_host_plane(traced, name):
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(traced["dir"], "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    on_host = [e for plane in ProfileData.from_file(path).planes
+               if plane.name.startswith("/host:")
+               for line in plane.lines for e in line.events
+               if e.name == name]
+    recorded = sum(len(_named(traced[s], name))
+                   for s in ("native", "tcp", "bulk"))
+    assert recorded and len(on_host) == recorded
+
+
+# ---- the store ---------------------------------------------------------------
+
+@pytest.fixture
+def empty_store():
+    import jax.profiler  # noqa: F401
+    span.layer_on()     # binds the annotation class, as any site does first
+    span.layer_spans_reset()
+    yield
+    span.layer_spans_reset()
+
+
+def test_the_bound_drops_and_counts(monkeypatch, empty_store):
+    monkeypatch.setattr(layer_span, "LAYER_SPAN_CAP", 10)
+    for i in range(8):
+        span.layer_record("brpc.test", i, i + 1)
+    kept = [span.layer_begin("brpc.test") for _ in range(5)]
+    assert [k is not None for k in kept] == [True, True, False, False, False]
+    for k in reversed(kept[:2]):    # lexical: the inner one ends first
+        k.end()
+    span.layer_record("brpc.test", 100, 101)
+    assert len(span.layer_spans()) == 10
+    assert span.layer_spans_dropped() == 4
+    span.layer_spans_reset()
+    assert span.layer_spans() == [] and span.layer_spans_dropped() == 0
+    span.layer_record("brpc.test", 1, 2)
+    assert len(span.layer_spans()) == 1
+
+
+def test_nesting_gives_cause_and_call_id(empty_store):
+    outer = span.layer_begin("brpc.outer", 7)
+    inner = span.layer_begin("brpc.inner", n=3)
+    mark = span.layer_mark(5)
+    inner.end()
+    span.layer_record("brpc.stamped", 10, 20)
+    outer.end()
+    span.layer_waited("brpc.waited", mark)
+    after = span.layer_begin("brpc.after")
+    after.end()
+    by = {s.name: s for s in span.layer_spans()}
+    assert by["brpc.inner"].cause_id == by["brpc.outer"].span_id
+    assert by["brpc.inner"].call_id == 7 and by["brpc.inner"].n == 3
+    assert by["brpc.stamped"].cause_id == by["brpc.outer"].span_id
+    assert by["brpc.waited"].cause_id == by["brpc.inner"].span_id
+    assert by["brpc.waited"].call_id == 7 and by["brpc.waited"].n == 5
+    assert by["brpc.after"].cause_id == 0 and by["brpc.after"].call_id == 0
+
+
+@pytest.mark.parametrize("since,until,name,want", [
+    (0, None, None, ["a", "b", "c"]),
+    (15, None, None, ["b", "c"]),       # a ended before
+    (0, 25, None, ["a", "b"]),          # c began after
+    (12, 22, None, ["b"]),              # b straddles both ends: in
+    (0, None, "c", ["c"]),
+])
+def test_layer_spans_selects_by_overlap_and_name(empty_store, since, until,
+                                                 name, want):
+    span.layer_record("a", 0, 10)
+    span.layer_record("b", 11, 30)
+    span.layer_record("c", 31, 40)
+    got = span.layer_spans(since, until, name)
+    assert [s.name for s in got] == want
+
+
+def test_finish_on_another_thread_keeps_the_opening_threads_name(empty_store):
+    import threading
+    opened = span.layer_begin("brpc.server.handler", 9)
+    opened.leave()
+    t = threading.Thread(target=opened.finish, name="finisher")
+    t.start()
+    t.join(10)
+    assert not t.is_alive()
+    got, = span.layer_spans()
+    assert got.thread == threading.current_thread().name
+    assert got.call_id == 9 and got.end_ns >= got.start_ns
+
+
+def test_device_trace_hook_starts_with_an_empty_store(tmp_path, empty_store):
+    from brpc_tpu.rpc import profiler
+    span.layer_record("brpc.stale", 1, 2)
+    assert profiler.start_device_trace(str(tmp_path))
+    try:
+        assert span.layer_on() is True
+        assert span.layer_spans() == []
+    finally:
+        assert profiler.stop_device_trace()
+    assert span.layer_on() is False
+
+
+@pytest.mark.parametrize("between", ["a_site_ran", "idle_and_the_hook"])
+def test_each_session_has_the_whole_cap_and_starts_empty(
+        tmp_path, monkeypatch, empty_store, between):
+    """Two sessions in one process: what the first recorded and dropped is
+    gone when the second's first site runs."""
+    import jax
+    from brpc_tpu.rpc import profiler
+    monkeypatch.setattr(layer_span, "LAYER_SPAN_CAP", 4)
+    jax.profiler.start_trace(str(tmp_path / "one"))
+    try:
+        assert span.layer_on() is True
+        for i in range(6):
+            span.layer_record("brpc.first", i, i + 1)
+    finally:
+        jax.profiler.stop_trace()
+    assert len(span.layer_spans()) == 4 and span.layer_spans_dropped() == 2
+    if between == "a_site_ran":
+        assert span.layer_on() is False
+        jax.profiler.start_trace(str(tmp_path / "two"))
+    else:
+        assert profiler.start_device_trace(str(tmp_path / "two"))
+    try:
+        assert span.layer_on() is True
+        assert span.layer_spans() == [] and span.layer_spans_dropped() == 0
+        for i in range(3):
+            span.layer_record("brpc.second", i, i + 1)
+        assert span.layer_on() is True      # only the first site resets
+    finally:
+        jax.profiler.stop_trace()
+    assert [s.name for s in span.layer_spans()] == ["brpc.second"] * 3
+    assert span.layer_spans_dropped() == 0
+
+
+def test_a_thread_with_no_span_open_adopts_no_call(empty_store):
+    span.layer_adopt_call(77)           # a retry issued from a timer thread
+    span.layer_record("brpc.before", 1, 2)
+    outer = span.layer_begin("brpc.outer")
+    span.layer_adopt_call(77)           # the caller, inside its brpc.call
+    span.layer_record("brpc.inside", 3, 4)
+    outer.end()
+    span.layer_record("brpc.after", 5, 6)
+    by = {s.name: s.call_id for s in span.layer_spans()}
+    assert by == {"brpc.before": 0, "brpc.inside": 77, "brpc.outer": 0,
+                  "brpc.after": 0}
