@@ -99,6 +99,7 @@ class TaskControl:
         "_blocked_workers": "_blocked_lock",
         "tasklet_count": "_count_lock",
         "_pending_signal": "_parking",
+        "_parked": "_parking",
     }
 
     def __init__(self, concurrency: Optional[int] = None):
@@ -107,6 +108,8 @@ class TaskControl:
         self.pool: ResourcePool = ResourcePool()
         self._parking = threading.Condition()     # ParkingLot
         self._pending_signal = 0
+        self._parked = 0                          # workers asleep in it
+        self._idle_sources: List[Callable[[], bool]] = []
         self._workers: List[threading.Thread] = []
         self._blocked_workers = 0
         self._blocked_lock = _dbg.make_lock("TaskControl._blocked_lock")
@@ -139,13 +142,50 @@ class TaskControl:
         while not self._stop:
             task = group.pop_local() or self._steal_task(group)
             if task is None:
+                if self._serve_idle():
+                    continue
                 with self._parking:
                     if self._pending_signal > 0:
                         self._pending_signal -= 1
                         continue
-                    self._parking.wait(timeout=0.5)
+                    self._parked += 1
+                    try:
+                        self._parking.wait(timeout=0.5)
+                    finally:
+                        self._parked -= 1
                 continue
             self._run_task(task)
+
+    # -- idle sources: work below every tasklet -------------------------
+    def add_idle_source(self, source: Callable[[], bool]) -> None:
+        """``source()`` is called by a worker that found no tasklet to run,
+        before it parks; it does one piece of work and returns True, or
+        returns False when it has none (the device waiter's parked
+        completions).  It may block.  Whoever gives a source work wakes a
+        worker for it (``wake_one``) where none would come by."""
+        self._idle_sources.append(source)
+
+    def _serve_idle(self) -> bool:
+        for source in self._idle_sources:
+            try:
+                if source():
+                    return True
+            except Exception:
+                from ..butil import logging as log
+                log.error("scheduler idle source raised", exc_info=True)
+        return False
+
+    def wake_one(self) -> None:
+        """One parked worker looks for work again; with none parked, the
+        next that would park does."""
+        with self._parking:
+            self._pending_signal += 1
+            self._parking.notify()
+
+    def others_parked(self) -> bool:
+        """Called on a worker: does every other worker sleep?"""
+        with self._parking:
+            return self._parked >= len(self._workers) - 1
 
     def _steal_task(self, thief: TaskGroup) -> Optional[Tasklet]:
         n = len(self.groups)
@@ -186,9 +226,7 @@ class TaskControl:
             # remote submission: round-robin a group's FIFO side
             victim = self.groups[task.tid % len(self.groups)]
             victim.push_background(task)
-        with self._parking:
-            self._pending_signal += 1
-            self._parking.notify()
+        self.wake_one()
         self._maybe_compensate()
         return task.tid
 

@@ -64,7 +64,8 @@ from ..butil import flags as _flags
 from ..butil import debug_sync as _dbg
 from ..butil import logging as log
 from ..butil import custody_ledger as _ledger
-from ..bthread.device_waiter import DeviceCompletion, device_on_ready
+from ..bthread.device_waiter import (DeviceCompletion,
+                                     DeviceEventDispatcher)
 from .mesh import IciMesh
 
 _flags.define_flag("ici_device_plane", True,
@@ -674,8 +675,9 @@ class DevicePlane:
 
         if out is not None:
             # the device stream is the CQ: completion fires when the
-            # transfer's output is physically resident at dst
-            device_on_ready([out], done)
+            # transfer's output is physically resident at dst, on the
+            # poller thread (DeviceCompletion.signal: must not block)
+            DeviceEventDispatcher.instance().on_ready([out], done)
         else:
             done()           # sender-only half: participation is complete
 

@@ -317,6 +317,7 @@ def phase_device_handler(rng, dev) -> None:
 
     completions_before = sum(DeviceEventDispatcher.instance().stats()
                              .values())
+    handoffs_before = DeviceEventDispatcher.instance().handoffs()
     ici_server = rpc.Server()
     ici_server.add_service(ChipService())
     check(ici_server.start("ici://0") == 0, "server start on ici://0")
@@ -361,6 +362,9 @@ def phase_device_handler(rng, dev) -> None:
         - completions_before
     check(completions >= 11,
           f"only {completions} completions went through the device poller")
+    handoffs = DeviceEventDispatcher.instance().handoffs() - handoffs_before
+    check(handoffs == 11,
+          f"{handoffs} of 11 handler completions were served off the poller")
     assert_clean_counters("device handler")
 
 

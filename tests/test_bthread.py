@@ -86,6 +86,41 @@ class TestScheduler:
         assert seen == {"a": "a", "b": "b"}
 
 
+def test_an_idle_source_is_served_by_workers_that_have_no_tasklet(monkeypatch):
+    """Work below every tasklet (TaskControl.add_idle_source): served on a
+    worker, one piece a call; a source that raises is logged and the
+    workers go on."""
+    from brpc_tpu.butil import logging as log
+    logged = []
+    monkeypatch.setattr(log, "error",
+                        lambda fmt, *a, **kw: logged.append(fmt))
+    ctl = bthread.TaskControl.instance()
+    work, seen, lock = [1, 2, "boom", 3], [], threading.Lock()
+    done = bthread.CountdownEvent(3)
+
+    def source():
+        with lock:
+            if not work:
+                return False
+            piece = work.pop(0)
+        if piece == "boom":
+            raise RuntimeError("a source failed")
+        seen.append((piece, bthread.in_worker()))
+        done.signal()
+        return True
+
+    ctl.add_idle_source(source)
+    try:
+        ctl.wake_one()
+        assert done.wait(10) == 0
+        assert sorted(seen) == [(1, True), (2, True), (3, True)]
+        assert "scheduler idle source raised" in logged
+        tid = bthread.start_background(lambda: 7)    # tasklets still run
+        assert bthread.join(tid, timeout=10) in (7, None)
+    finally:
+        ctl._idle_sources.remove(source)
+
+
 class TestButex:
     def test_wait_wake(self):
         b = bthread.Butex(0)
@@ -291,15 +326,23 @@ class TestDeviceWaiter:
         assert float(y) == 128 * 128 * 128
 
     def test_on_ready_callback_order(self):
+        """The inline entry: on the poller thread, in submit order."""
+        import threading
         import jax.numpy as jnp
         order = []
         done = bthread.CountdownEvent(3)
+        disp = bthread.DeviceEventDispatcher.instance()
+        handed = disp.handoffs()
         for i in range(3):
             arr = jnp.full((4,), i)
-            bthread.device_on_ready(
-                arr, lambda i=i: (order.append(i), done.signal()))
+            disp.on_ready(arr, lambda i=i: (
+                order.append((i, threading.current_thread().name)),
+                done.signal()))
         assert done.wait(30) == 0
-        assert order == [0, 1, 2]   # stream completion order is FIFO
+        # stream completion order is FIFO
+        assert [i for i, _ in order] == [0, 1, 2]
+        assert all(t.startswith("device_poller_") for _, t in order)
+        assert disp.handoffs() == handed    # none of them went to a worker
 
     def test_wait_from_tasklet(self):
         import jax.numpy as jnp
@@ -333,3 +376,384 @@ def test_device_poller_counts_a_failed_completion_and_still_fires():
     disp.on_ready([Poisoned()], fired.set)
     assert fired.wait(10)
     assert disp.failures() == before + 1
+
+
+# ---- device_on_ready: user completions leave the poller thread -------------
+
+class _Ready:
+    """A leaf that is ready at once."""
+
+    def __init__(self):
+        self.blocked = 0
+
+    def is_ready(self):
+        return True
+
+    def block_until_ready(self):
+        self.blocked += 1
+        return self
+
+
+class _Pending:
+    """A leaf that is ready once it has been blocked on."""
+
+    def __init__(self):
+        self.blocked = 0
+
+    def is_ready(self):
+        return self.blocked > 0
+
+    def block_until_ready(self):
+        self.blocked += 1
+        return self
+
+
+class _Poisoned:
+    def block_until_ready(self):
+        raise RuntimeError("device program failed")
+
+
+class _ReadyPoisoned(_Poisoned):
+    """A failed program whose array nevertheless reports ready."""
+
+    def is_ready(self):
+        return True
+
+
+class _Slow:
+    """A leaf of a device of its own that takes its time."""
+
+    def __init__(self, device, seconds):
+        self.device, self.seconds, self.done = device, seconds, False
+
+    def devices(self):
+        return {self.device}
+
+    def is_ready(self):
+        return self.done
+
+    def block_until_ready(self):
+        time.sleep(self.seconds)
+        self.done = True
+        return self
+
+
+def _dispatcher():
+    from brpc_tpu.bthread.device_waiter import DeviceEventDispatcher
+    return DeviceEventDispatcher.instance()
+
+
+def _submit(entry):
+    from brpc_tpu.bthread.device_waiter import device_on_ready
+    return device_on_ready if entry == "device_on_ready" \
+        else _dispatcher().on_ready
+
+
+def test_device_on_ready_runs_off_the_poller_and_counts_a_handoff():
+    import threading
+    from brpc_tpu.bthread.device_waiter import device_on_ready
+    disp = _dispatcher()
+    handed, completed = disp.handoffs(), sum(disp.stats().values())
+    seen, fired = [], threading.Event()
+    device_on_ready([_Ready()], lambda: (
+        seen.append(threading.current_thread().name), fired.set()))
+    assert fired.wait(10)
+    assert seen[0].startswith("bthread_worker_")
+    assert disp.handoffs() == handed + 1
+    assert sum(disp.stats().values()) == completed + 1
+
+
+def test_blocking_completions_overlap_and_the_poller_keeps_serving():
+    """Eight callbacks that each block 100 ms: on the poller thread they
+    took 800 ms one after another and every later completion of the device
+    waited behind them.  Off it they overlap, on half of the workers (and
+    the backstop thread, once they are overdue), and the poller thread is
+    free."""
+    import threading
+    from brpc_tpu.bthread import device_waiter
+    ends, lock = [], threading.Lock()
+    running = [0, 0]                        # now, at most
+    all_done = bthread.CountdownEvent(8)
+
+    def blocking():
+        with lock:
+            running[0] += 1
+            running[1] = max(running)
+        time.sleep(0.1)
+        with lock:
+            running[0] -= 1
+            ends.append(time.monotonic())
+        all_done.signal()
+
+    ninth = []
+    ninth_fired = threading.Event()
+    start = time.monotonic()
+    for _ in range(8):
+        device_waiter.device_on_ready([_Ready()], blocking)
+    _dispatcher().on_ready([_Ready()], lambda: (
+        ninth.append(time.monotonic()), ninth_fired.set()))
+    assert ninth_fired.wait(10)
+    assert all_done.wait(10) == 0
+    limit = max(1, bthread.TaskControl.instance().concurrency // 2)
+    assert running[1] in (limit, limit + 1)
+    assert max(ends) - start < 0.7          # not 8 x 100 ms
+    assert ninth[0] < min(ends)             # served while they blocked
+
+
+def test_completions_are_served_while_every_worker_waits_for_them():
+    """A tasklet may register a completion and wait for it, on every worker
+    at once and with a wait the scheduler does not see: what no worker
+    takes, the backstop thread does."""
+    import threading
+    from brpc_tpu.bthread.device_waiter import device_on_ready
+    n = bthread.TaskControl.instance().worker_count()
+    got = []
+
+    def handler():
+        fired = threading.Event()
+        device_on_ready([_Ready()], fired.set)
+        got.append(fired.wait(5))
+
+    start = time.monotonic()
+    for tid in [bthread.start_background(handler) for _ in range(n)]:
+        bthread.join(tid, timeout=20)
+    assert got == [True] * n
+    assert time.monotonic() - start < 2
+
+
+def test_completions_are_served_under_a_backlog_of_tasklets():
+    """While requests keep every worker busy, replies still go out."""
+    import threading
+    from brpc_tpu.bthread.device_waiter import device_on_ready
+    n = 100 * bthread.TaskControl.instance().worker_count()
+    left = bthread.CountdownEvent(n)
+
+    def request():
+        time.sleep(0.005)
+        left.signal()
+
+    start = time.monotonic()
+    for _ in range(n):
+        bthread.start_background(request)
+    fired = threading.Event()
+    device_on_ready([_Ready()], fired.set)
+    assert fired.wait(0.3)                  # half a second of work is queued
+    waited = time.monotonic() - start
+    assert left.wait(0) != 0, f"the backlog was gone after {waited:.3f}s"
+    assert left.wait(30) == 0
+
+
+def test_a_slow_device_holds_no_worker():
+    """An entry that is not computed at its turn goes to its device's
+    poller: as many of them as there are workers, and another device's
+    completion does not wait behind any."""
+    import threading
+    from brpc_tpu.bthread import device_waiter
+    n = bthread.TaskControl.instance().worker_count()
+    slow = bthread.CountdownEvent(n)
+    for _ in range(n):
+        device_waiter.device_on_ready([_Slow("slow-device", 0.2)],
+                                      slow.signal)
+    fired = threading.Event()
+    start = time.monotonic()
+    device_waiter.device_on_ready([_Ready()], fired.set)
+    assert fired.wait(10)
+    assert time.monotonic() - start < 0.15
+    assert slow.wait(10) == 0
+
+
+def test_tasklets_go_before_parked_completions():
+    """A worker takes a parked completion only when it finds no tasklet."""
+    import threading
+    from brpc_tpu.bthread.device_waiter import device_on_ready
+    order, lock = [], threading.Lock()
+    done = bthread.CountdownEvent(1 + 12)
+    gate = threading.Event()
+
+    def note(what):
+        with lock:
+            order.append(what)
+        done.signal()
+
+    def occupy():           # holds every worker while the rest is queued
+        gate.wait(10)
+
+    n = bthread.TaskControl.instance().worker_count()
+    holders = [bthread.start_background(occupy) for _ in range(n)]
+    time.sleep(0.1)
+    device_on_ready([_Ready()], lambda: note("completion"))
+    for i in range(12):
+        bthread.start_background(note, "tasklet")
+    gate.set()
+    assert done.wait(10) == 0
+    for tid in holders:
+        bthread.join(tid, timeout=10)
+    # whichever worker ran out of tasklets first took it: it is not first
+    assert order[0] == "tasklet" and "completion" in order
+
+
+def test_a_raising_completion_is_logged_and_the_next_still_runs(monkeypatch):
+    import threading
+    from brpc_tpu.butil import logging as log
+    from brpc_tpu.bthread.device_waiter import device_on_ready
+    logged, was_logged = [], threading.Event()
+    monkeypatch.setattr(log, "error", lambda fmt, *a, **kw: (
+        logged.append((fmt, kw)), was_logged.set()))
+    fired = threading.Event()
+
+    def raising():
+        raise ValueError("a handler's completion failed")
+
+    device_on_ready([_Ready()], raising)
+    device_on_ready([_Ready()], fired.set)
+    assert fired.wait(10) and was_logged.wait(10)
+    assert [fmt for fmt, kw in logged if kw.get("exc_info")] == \
+        ["device completion callback raised"]
+
+
+def test_a_failed_device_program_still_fires_off_the_poller_and_is_counted():
+    import threading
+    from brpc_tpu.bthread.device_waiter import device_on_ready
+    disp = _dispatcher()
+    failed, handed = disp.failures(), disp.handoffs()
+    seen, fired = [], threading.Event()
+    device_on_ready([_Poisoned()], lambda: (
+        seen.append(threading.current_thread().name), fired.set()))
+    assert fired.wait(10)
+    assert not seen[0].startswith("device_poller_")
+    assert disp.failures() == failed + 1
+    assert disp.handoffs() == handed + 1
+
+
+@pytest.mark.parametrize("entry", ["on_ready", "device_on_ready"])
+def test_a_failed_program_that_reports_ready_is_logged_and_counted(
+        entry, monkeypatch):
+    """Its wait is spared, its failure is not: the callback's own access
+    to the array raises it (a handler's completion reads its result), and
+    a callback that raises is logged and counted."""
+    import threading
+    from brpc_tpu.butil import logging as log
+    logged, was_logged = [], threading.Event()
+    monkeypatch.setattr(log, "error", lambda fmt, *a, **kw: (
+        logged.append(fmt % a), was_logged.set()))
+    disp = _dispatcher()
+    failed = disp.failures()
+    leaf = _ReadyPoisoned()
+    _submit(entry)([_Ready(), leaf], lambda: leaf.block_until_ready())
+    assert was_logged.wait(10)
+    deadline = time.monotonic() + 5
+    while disp.failures() == failed and time.monotonic() < deadline:
+        time.sleep(0.005)
+    assert disp.failures() == failed + 1
+    assert logged == ["device completion callback raised"]
+
+
+@pytest.mark.parametrize("entry", ["on_ready", "device_on_ready"])
+def test_arrays_that_are_ready_at_their_turn_are_not_blocked_on(entry):
+    """block_until_ready gives the interpreter lock away: only for an
+    entry that has a leaf still to wait for."""
+    import threading
+    ready, pending = threading.Event(), threading.Event()
+    leaves = [_Ready(), _Ready()]
+    _submit(entry)(leaves, ready.set)
+    assert ready.wait(10)
+    time.sleep(0.05)                        # nor afterwards
+    assert [x.blocked for x in leaves] == [0, 0]
+    leaf = _Pending()
+    _submit(entry)([_Ready(), leaf], pending.set)
+    assert pending.wait(10) and leaf.blocked == 1
+
+
+@pytest.mark.parametrize("others", ["asleep", "one_busy"])
+def test_a_completion_registered_by_a_busy_tasklet_does_not_wait_for_it(
+        others):
+    """The worker that parks a completion comes back for it when its
+    tasklet ends.  Where that tasklet goes on: every other worker sleeps,
+    and one is woken; or one is awake and busy, nobody is woken, and the
+    backstop thread has it — not a worker's 0.5 s park timeout."""
+    import threading
+    from brpc_tpu.bthread.device_waiter import device_on_ready
+    fired, release = threading.Event(), threading.Event()
+    at = {}
+
+    def busy():
+        while not release.is_set():
+            time.sleep(0.001)
+
+    def handler():
+        device_on_ready([_Ready()], lambda: (
+            at.setdefault("fired", time.monotonic()), fired.set()))
+        release.wait(10)                    # ...and computes on
+        at["ended"] = time.monotonic()
+
+    time.sleep(0.6)         # the other workers have parked by now
+    tids = [bthread.start_background(busy)] if others == "one_busy" else []
+    time.sleep(0.05)
+    start = time.monotonic()
+    tids.append(bthread.start_background(handler))
+    got = fired.wait(0.3)
+    release.set()
+    for tid in tids:
+        bthread.join(tid, timeout=10)
+    assert got and at["fired"] < at["ended"]
+    assert at["fired"] - start < 0.3
+
+
+def test_every_user_completion_runs_once_whatever_its_way():
+    """Ready at its turn, waited for by the poller, failed: callbacks of
+    ``device_on_ready`` have no order, and each runs exactly once."""
+    import threading
+    from brpc_tpu.bthread.device_waiter import device_on_ready
+    kinds = [_Ready, _Pending, _Poisoned, _ReadyPoisoned]
+    n_threads, per = 4, 120
+    runs = [[0] * per for _ in range(n_threads)]
+    fired = bthread.CountdownEvent(n_threads * per)
+
+    def ran(t, i):
+        runs[t][i] += 1                     # only entry (t, i) writes it
+        fired.signal()
+
+    def submitter(t):
+        for i in range(per):
+            device_on_ready([kinds[(t + i) % len(kinds)]()],
+                            lambda t=t, i=i: ran(t, i))
+
+    threads = [threading.Thread(target=submitter, args=(t,))
+               for t in range(n_threads)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(30)
+    assert fired.wait(30) == 0
+    time.sleep(0.1)                         # a second run would come now
+    assert runs == [[1] * per for _ in range(n_threads)]
+
+
+@pytest.mark.parametrize("entry", ["on_ready", "device_on_ready"])
+def test_submits_racing_the_servers_sleep_are_all_served(entry):
+    """A serving thread pops without its condition variable and takes it
+    only to sleep, and a submit takes it only where a thread sleeps: a
+    submit between a thread's last look and its sleep must not be lost."""
+    import sys
+    import threading
+    submit = _submit(entry)
+    n_threads, per = 8, 200
+    fired = bthread.CountdownEvent(n_threads * per)
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        def submitter():
+            for i in range(per):
+                submit([_Ready()], fired.signal)
+                if i % 16 == 0:
+                    time.sleep(0.001)       # lets the queue run empty
+        threads = [threading.Thread(target=submitter)
+                   for _ in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(30)
+        assert not any(t.is_alive() for t in threads)
+        assert fired.wait(30) == 0
+    finally:
+        sys.setswitchinterval(old)

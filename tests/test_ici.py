@@ -425,3 +425,46 @@ def test_delivery_gate_samples_readiness_once(monkeypatch):
     committed = threading.Event()
     d._enqueue_delivery([jnp.zeros(8)], committed.set)
     assert committed.wait(10), "the delivery entry never opened"
+
+
+def test_a_windowed_echos_gates_never_leave_the_poller(mesh, monkeypatch):
+    """Every piece of an echo cut by a small send window is gated through
+    the device poller's inline entry: its commit runs on the poller thread
+    and the hand-off of ``device_on_ready`` is not taken once."""
+    import jax
+    import jax.numpy as jnp
+    from brpc_tpu.bthread.device_waiter import DeviceEventDispatcher
+    from brpc_tpu.butil import flags as _flags
+    from brpc_tpu.ici import transport as tr
+    # above the native tier's 4 MB window, so the Python ici plane carries
+    # it: three pieces each way
+    window, nbytes = 2 << 20, 5_000_000
+    monkeypatch.setattr(_flags.flag_object("ici_socket_window_bytes"),
+                        "value", window)
+    # on the CPU every slice is ready at once: send each through the poller
+    monkeypatch.setattr(tr, "_all_ready", lambda arrays: False)
+    disp = DeviceEventDispatcher.instance()
+    options = rpc.ServerOptions()
+    options.usercode_inline = True
+    server = rpc.Server(options)
+    server.add_service(DeviceEchoService())
+    assert server.start("ici://5") == 0
+    try:
+        payload = jax.device_put(
+            jnp.arange(nbytes, dtype=jnp.uint8), mesh.device(5))
+        ch = rpc.Channel()
+        assert ch.init("ici://5",
+                       options=rpc.ChannelOptions(ici_local_device=5)) == 0
+        handed, completed = disp.handoffs(), sum(disp.stats().values())
+        cntl = rpc.Controller()
+        cntl.request_attachment.append_device_array(payload)
+        resp = ch.call_method("EchoService.Echo", cntl,
+                              EchoRequest(message="windowed"), EchoResponse)
+        assert not cntl.failed(), cntl.error_text
+        assert resp.message == "windowed"
+        assert cntl.response_attachment.to_bytes() == bytes(
+            np.asarray(payload))
+        assert sum(disp.stats().values()) >= completed + 6
+        assert disp.handoffs() == handed
+    finally:
+        server.stop()

@@ -256,7 +256,9 @@ def test_poller_spans_in_order_caused_by_the_handler(traced, plane):
         {handler.span_id}
     assert {queue.call_id, block.call_id, callback.call_id} == \
         {handler.call_id}
-    assert queue.thread.startswith("device_poller_")
+    # a handler's completion: parked, then taken by a scheduler worker
+    assert {queue.thread, block.thread, callback.thread} == {queue.thread}
+    assert queue.thread.startswith("bthread_worker_")
     assert queue.n == 0                 # nothing was parked ahead of it
     # done() ran in the callback: the handler stage ends inside it, and the
     # response's encode and write are its children
@@ -354,6 +356,49 @@ def test_bulk_call_also_records_server_stages(traced):
     call = _one(spans, "brpc.call")
     for s in STAGES:
         assert _one(spans, f"brpc.server.{s}").call_id == call.call_id
+
+
+def test_the_planes_own_completions_never_leave_the_poller(traced):
+    """Delivery gates ride the inline entry: whatever of them met the
+    poller was served on its thread, callback and all."""
+    assert all(s.thread.startswith("device_poller_")
+               for s in traced["bulk"] if s.name.startswith("brpc.poller."))
+
+
+@pytest.fixture
+def sites_on(monkeypatch, empty_store):
+    """The sites switched on without a profiler session."""
+    monkeypatch.setattr(layer_span, "layer_on", lambda: True)
+    yield
+    monkeypatch.undo()
+
+
+@pytest.mark.parametrize("entry,thread", [
+    ("on_ready", "device_poller_"), ("device_on_ready", "bthread_worker_")])
+def test_each_entry_point_records_the_three_spans_on_its_own_thread(
+        sites_on, entry, thread):
+    import threading
+    import jax.numpy as jnp
+    from brpc_tpu.bthread.device_waiter import DeviceEventDispatcher
+    submit = device_on_ready if entry == "device_on_ready" \
+        else DeviceEventDispatcher.instance().on_ready
+    fired = threading.Event()
+    outer = span.layer_begin("brpc.submitter", 41)
+    submit([jnp.arange(8) + 1], fired.set)
+    outer.end()
+    assert fired.wait(10)
+    deadline = time.monotonic() + 10
+    while not _named(span.layer_spans(), "brpc.poller.callback") \
+            and time.monotonic() < deadline:
+        time.sleep(0.005)
+    got = [s for s in span.layer_spans() if s.name.startswith("brpc.poller.")]
+    assert [s.name for s in got] == [
+        "brpc.poller.queue", "brpc.poller.block", "brpc.poller.callback"]
+    assert {(s.cause_id, s.call_id) for s in got} == {(outer.span_id, 41)}
+    assert len({s.thread for s in got}) == 1
+    assert got[0].thread.startswith(thread)
+    assert got[0].end_ns <= got[1].start_ns <= got[1].end_ns \
+        <= got[2].start_ns
 
 
 # ---- the same clock as the device trace -------------------------------------
