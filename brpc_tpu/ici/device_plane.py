@@ -64,6 +64,7 @@ from ..butil import flags as _flags
 from ..butil import debug_sync as _dbg
 from ..butil import logging as log
 from ..butil import custody_ledger as _ledger
+from ..butil import layer_span as _layer
 from ..bthread.device_waiter import (DeviceCompletion,
                                      DeviceEventDispatcher)
 from .mesh import IciMesh
@@ -502,6 +503,19 @@ class DevicePlane:
         descriptor exists) when refused — chaos injection, or a plane
         that cannot serve the route — so the caller can fall back in the
         same frame."""
+        # layer span brpc.plane.post: the program's fetch or build, the
+        # descriptor and its tracking (under the writer's brpc.ici.piece)
+        post = _layer.layer_begin("brpc.plane.post", n=int(arr.shape[0])) \
+            if _layer.layer_on() else None
+        try:
+            return self._post_send(arr, src_dev, dst_dev, socket, uuid,
+                                   remote)
+        finally:
+            if post is not None:
+                post.end()
+
+    def _post_send(self, arr, src_dev: int, dst_dev: int, socket,
+                   uuid: Optional[int], remote: bool) -> DeviceTransfer:
         from ..rpc import fault_injection as _fi
         plan = _fi.fabric_active()
         if plan is not None and plan.on_device_post(socket):
@@ -619,25 +633,33 @@ class DevicePlane:
         dummy/other-process shard.  Returns the dst-resident flat array
         (None when dst is not addressable from this process)."""
         import jax
-        fn, sharding, mesh2, src, dst = self._program(
-            t.nbytes, t.src_dev, t.dst_dev)
-        shards = []
-        for dev_id, device in ((t.src_dev, src), (t.dst_dev, dst)):
-            row = rows.get(dev_id)
-            if row is None:
-                if local_only and not _is_local(device):
-                    continue               # the peer process's shard
-                row = self._zeros_row(dev_id, t.nbytes)
-            shards.append(row)
-        ga = jax.make_array_from_single_device_arrays(
-            (2, t.nbytes), sharding, shards)
-        out_global = fn(ga)
-        out = None
-        for s in out_global.addressable_shards:
-            if s.device == dst:
-                out = s.data.reshape(t.nbytes)
-                break
-        return out
+        # layer span brpc.plane.run: the zeros row, the global operand's
+        # assembly, the program's dispatch and the pick of dst's shard
+        run = _layer.layer_begin("brpc.plane.run", n=t.nbytes) \
+            if _layer.layer_on() else None
+        try:
+            fn, sharding, mesh2, src, dst = self._program(
+                t.nbytes, t.src_dev, t.dst_dev)
+            shards = []
+            for dev_id, device in ((t.src_dev, src), (t.dst_dev, dst)):
+                row = rows.get(dev_id)
+                if row is None:
+                    if local_only and not _is_local(device):
+                        continue           # the peer process's shard
+                    row = self._zeros_row(dev_id, t.nbytes)
+                shards.append(row)
+            ga = jax.make_array_from_single_device_arrays(
+                (2, t.nbytes), sharding, shards)
+            out_global = fn(ga)
+            out = None
+            for s in out_global.addressable_shards:
+                if s.device == dst:
+                    out = s.data.reshape(t.nbytes)
+                    break
+            return out
+        finally:
+            if run is not None:
+                run.end()
 
     def _matched(self, t: DeviceTransfer, out) -> None:
         t.state = MATCHED
@@ -655,8 +677,13 @@ class DevicePlane:
         _g_transfers << 1
         if sender:
             _g_bytes_sent << t.nbytes
+        # layer span brpc.plane.complete: from here to done(), on whichever
+        # thread signals it: the transfer itself and its wait for the poller
+        mark = _layer.layer_mark(t.nbytes) if _layer.layer_on() else None
 
         def done() -> None:
+            if mark is not None:
+                _layer.layer_waited("brpc.plane.complete", mark)
             t.state = COMPLETE
             t.complete_ns = time.monotonic_ns()
             if out is not None:
