@@ -32,7 +32,7 @@ device_plane.py):
     makes every member observe the same order.  The operand cannot be
     *placed* onto a remote device, so the xproc program broadcasts from
     the client row instead: every non-client participant contributes a
-    zeros row (the ``_zeros_row`` discipline) and ``psum`` over the axis
+    zeros row (the ``_zeros_block`` discipline) and ``psum`` over the axis
     reconstructs the request everywhere — scatter by collective, not by
     placement.  Backends without multi-controller programs (this
     container's CPU jaxlib) refuse at the screen (``xproc_compiled_ok``)

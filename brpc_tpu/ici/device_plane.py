@@ -15,9 +15,11 @@ in the datapath.
 
 QP semantics (rdma_endpoint.h:37-108):
 
-  * ``post_send(arr, src, dst)`` posts a work request and returns a
-    :class:`DeviceTransfer` (the WR handle).  Nothing moves yet — like a
-    posted SGE, the source array is pinned by the plane until completion.
+  * ``post_send(block, src, dst, start=, nbytes=)`` posts a work request
+    and returns a :class:`DeviceTransfer` (the WR handle).  Nothing moves
+    yet — like a posted SGE (address + length inside a registered block),
+    the payload is the piece ``[start, start + nbytes)`` of the block and
+    the WHOLE block is pinned by the plane until completion.
   * a 16-byte descriptor ``(uuid, nbytes)`` (+ dtype/shape on the fabric
     wire) rides the transport's existing control/delivery channel;
   * the receiver ``post_recv(uuid)``s the matching recv — the rendezvous:
@@ -29,10 +31,30 @@ QP semantics (rdma_endpoint.h:37-108):
     yield their M:N worker instead of blocking it, and source pins
     release exactly at completion (the :926 discipline).
 
-Program cache: one compiled executable per (nbytes, src, dst, kernel,
-mesh generation), exactly like the collectives cache — steady workloads
-repost the same shapes and pay compilation once (cache hits/misses are
-counters).
+The program's ABI is FLAT, and the cut is the program's: its operand is
+the global ``u8[2 * B]`` sharded over the two chips (the source's shard IS
+the ``B``-byte block, no slice and no reshape on the host; the
+destination's a cached dummy of ``B`` zeros) plus the start as a
+replicated ``int32`` operand — an operand, never a static, so one program
+serves every offset.  Per chip it runs
+``ppermute(dynamic_slice(block, start, n))``; the output is the global
+``u8[2 * n]`` whose destination shard IS the delivered flat piece
+(``t.out``).  One program dispatch per transfer is all the host pays; a
+whole array is the case ``start == 0, B == n`` of the same path (XLA folds
+the slice away), which is what the fabric and the native tier's relocation
+upcall post.  On the TPU a 1-D <-> ``(1, n)`` reshape is a relayout, not a
+bitcast, which is why no ``(1, n)`` row appears anywhere.  The one
+exception is the Pallas kernel: Mosaic refuses a DMA source at a dynamic
+start it cannot prove aligned to the u8 tiling, so for that kernel
+``_post_send`` cuts the piece on the host and the kernel moves a whole
+array (``_pallas_body``).  ``stats()["sliced_in_program"]`` counts the
+transfers whose piece was a sub-range of its block and was cut by the
+program, beside ``transfers``.
+
+Program cache: one compiled executable per (block bytes, piece bytes, src,
+dst, kernel, mesh generation), exactly like the collectives cache — steady
+workloads repost the same shapes and pay compilation once (cache
+hits/misses are counters).
 
 Failure semantics: a refused/failed post raises :class:`DevicePlaneError`
 BEFORE any descriptor exists, so the caller degrades to its previous
@@ -78,7 +100,7 @@ _flags.define_flag("ici_device_plane_threshold", 64 * 1024,
                    "host paths)", _flags.positive_integer)
 # On a host-memory mesh (the 8-virtual-device CPU platform) a compiled
 # transfer program measured ~1.4 GB/s at 4 MB vs ~5.5 GB/s for a plain
-# device_put memcpy — the program pays XLA dispatch plus a (2, n) output
+# device_put memcpy — the program pays XLA dispatch plus its output's
 # materialization for what is physically one host memcpy.  On TPU the
 # program IS the ICI datapath and device_put cannot cross processes at
 # all, so the plane engages there by default; host meshes must opt in
@@ -111,6 +133,7 @@ _flags.define_flag("ici_device_plane_xproc_compiled", "auto",
 _g_bytes_sent = bvar.Adder("ici_device_plane_bytes_sent")
 _g_bytes_recv = bvar.Adder("ici_device_plane_bytes_recv")
 _g_transfers = bvar.Adder("ici_device_plane_transfers")
+_g_sliced = bvar.Adder("ici_device_plane_sliced_in_program")
 _g_fallbacks = bvar.Adder("ici_device_plane_fallbacks")
 _g_cache_hits = bvar.Adder("ici_device_plane_program_cache_hits")
 _g_cache_misses = bvar.Adder("ici_device_plane_program_cache_misses")
@@ -141,22 +164,32 @@ FAILED = "failed"
 class DeviceTransfer:
     """One posted work request: uuid-correlated, completion-signaled.
 
-    ``out`` is the dst-resident flat uint8 array once MATCHED (an XLA
-    future — physically resident at COMPLETE, which is when the source
-    pin releases).  ``wait``/``poll``/``add_done_callback`` are the CQ
-    interface (see DeviceCompletion)."""
+    The payload is the piece ``[start, start + nbytes)`` of the pinned
+    source block (``block_bytes`` long; a whole array is the piece at 0
+    that is as long as its block).  ``out`` is the dst-resident flat
+    uint8 piece once MATCHED (an XLA future — physically resident at
+    COMPLETE, which is when the source pin releases).
+    ``wait``/``poll``/``add_done_callback`` are the CQ interface (see
+    DeviceCompletion)."""
 
-    __slots__ = ("uuid", "src_dev", "dst_dev", "nbytes", "state", "error",
+    __slots__ = ("uuid", "src_dev", "dst_dev", "nbytes", "start",
+                 "block_bytes", "state", "error",
                  "out", "completion", "posted_ns", "matched_ns",
                  "complete_ns", "_src_arr", "_releases", "_lock",
                  "trace_id", "parent_span_id", "span")
 
     def __init__(self, uuid: int, src_dev: int, dst_dev: int, nbytes: int,
-                 src_arr=None, trace_id: int = 0, parent_span_id: int = 0):
+                 src_arr=None, trace_id: int = 0, parent_span_id: int = 0,
+                 start: int = 0):
         self.uuid = uuid
         self.src_dev = src_dev
         self.dst_dev = dst_dev
         self.nbytes = nbytes
+        self.start = start
+        # a receiver-only half holds no block: its program is the whole
+        # array's, which is what the peer entered
+        self.block_bytes = (int(src_arr.shape[0]) if src_arr is not None
+                            else nbytes)
         self.state = POSTED
         self.error = ""
         self.out = None
@@ -306,10 +339,12 @@ class DevicePlane:
     _GUARDED_BY = {
         "_programs": "_lock",
         "_zeros": "_lock",
+        "_starts": "_lock",
         "_pending": "_lock",
         "_active": "_lock",
         "_next_uuid": "_lock",
         "transfers": "_lock",
+        "sliced_in_program": "_lock",
         "bytes_sent": "_lock",
         "bytes_recv": "_lock",
         "fallbacks": "_lock",
@@ -328,16 +363,19 @@ class DevicePlane:
 
     # cache bounds: steady workloads repost a handful of (size, route)
     # shapes, but arbitrary attachment sizes would otherwise compile and
-    # pin one executable + one device-resident zeros row PER DISTINCT
-    # byte count, forever — LRU-bound both
+    # pin one executable + one device-resident dummy block (as long as
+    # the SOURCE block: 64 MiB on the destination of a 64 MiB attachment's
+    # pieces) PER DISTINCT byte count, forever — LRU-bound both
     MAX_PROGRAMS = 64
     MAX_ZEROS = 64
+    MAX_STARTS = 512
 
     def __init__(self, mesh: Optional[IciMesh] = None):
         self._mesh = mesh
         self._lock = _dbg.make_lock("DevicePlane._lock")
         self._programs: "collections.OrderedDict" = collections.OrderedDict()
         self._zeros: "collections.OrderedDict" = collections.OrderedDict()
+        self._starts: Dict[tuple, Any] = {}
         self._pending: Dict[int, DeviceTransfer] = {}   # posted sends
         self._active: set = set()      # posted-but-incomplete (drain gate)
         self._next_uuid = 1
@@ -345,6 +383,7 @@ class DevicePlane:
         # local running totals (the bvar Adders are process-global and
         # shared with other planes a test may construct)
         self.transfers = 0
+        self.sliced_in_program = 0
         self.bytes_sent = 0
         self.bytes_recv = 0
         self.fallbacks = 0
@@ -364,12 +403,14 @@ class DevicePlane:
         return self._mesh or IciMesh.default()
 
     # ---- program cache -------------------------------------------------
-    def _program(self, nbytes: int, src_dev: int, dst_dev: int):
-        """Compile-or-fetch the (src → dst, nbytes) transfer program.
+    def _program(self, block_bytes: int, nbytes: int, src_dev: int,
+                 dst_dev: int):
+        """Compile-or-fetch the (src → dst) transfer program that cuts
+        ``nbytes`` out of a ``block_bytes`` block.
         Returns (fn, input_sharding, mesh2, src_device, dst_device)."""
         kernel = _flags.get_flag("ici_device_plane_kernel")
         gen = IciMesh.generation
-        key = (nbytes, src_dev, dst_dev, kernel, gen)
+        key = (block_bytes, nbytes, src_dev, dst_dev, kernel, gen)
         with self._lock:
             hit = self._programs.get(key)
             if hit is not None:
@@ -378,7 +419,7 @@ class DevicePlane:
         if hit is not None:
             _g_cache_hits << 1
             return hit
-        built = self._build(nbytes, src_dev, dst_dev, kernel)
+        built = self._build(block_bytes, nbytes, src_dev, dst_dev, kernel)
         with self._lock:
             # a racing builder may have won; keep the first (identical)
             entry = self._programs.setdefault(key, built)
@@ -389,7 +430,8 @@ class DevicePlane:
         _g_cache_misses << 1
         return entry
 
-    def _build(self, nbytes: int, src_dev: int, dst_dev: int, kernel: str):
+    def _build(self, block_bytes: int, nbytes: int, src_dev: int,
+               dst_dev: int, kernel: str):
         import jax
         import jax.numpy as jnp
         import numpy as np
@@ -400,16 +442,24 @@ class DevicePlane:
         mesh2 = Mesh(np.array([src, dst]), ("p2p",))
         sharding = NamedSharding(mesh2, P("p2p"))
         if kernel == "pallas":
+            if nbytes != block_bytes:
+                raise ValueError("the Pallas kernel takes whole arrays "
+                                 "(see _pallas_body)")
             per_device = self._pallas_body(nbytes, (src, dst))
         else:
-            def per_device(x_local):          # (1, nbytes) local row
-                return jax.lax.ppermute(x_local, "p2p", [(0, 1)])
+            def per_device(x_local, start):   # the flat block, () int32
+                piece = jax.lax.dynamic_slice(x_local, (start,), (nbytes,))
+                return jax.lax.ppermute(piece, "p2p", [(0, 1)])
         # compiled HERE, not at first call: the compiler's verdict on the
         # program belongs to the build (post_send), before any descriptor
-        fn = jax.jit(shard_map(per_device, mesh=mesh2, in_specs=P("p2p"),
+        fn = jax.jit(shard_map(per_device, mesh=mesh2,
+                               in_specs=(P("p2p"), P()),
                                out_specs=P("p2p"), check_vma=False)).lower(
-            jax.ShapeDtypeStruct((2, nbytes), jnp.uint8,
-                                 sharding=sharding)).compile()
+            jax.ShapeDtypeStruct((2 * block_bytes,), jnp.uint8,
+                                 sharding=sharding),
+            jax.ShapeDtypeStruct((), jnp.int32,
+                                 sharding=NamedSharding(mesh2, P()))
+        ).compile()
         return (fn, sharding, mesh2, src, dst)
 
     @staticmethod
@@ -420,9 +470,17 @@ class DevicePlane:
         enters VMEM, so its size is bounded by HBM alone.  Symmetric
         shift — both submesh members post toward the other after a
         barrier handshake (ICI links are bidirectional, so the unused
-        reverse hop is free on hardware); only the dst row of the output
+        reverse hop is free on hardware); only the dst shard of the output
         is consumed.  Compiled for a TPU submesh, the Pallas TPU
-        interpreter for any other (pallas_ring.interpret_for)."""
+        interpreter for any other (pallas_ring.interpret_for).
+
+        Whole arrays only, and ``start`` is not read: Mosaic refuses
+        ``local_ref.at[pl.ds(start, nbytes)]`` as the DMA's source for a
+        start it cannot prove a multiple of the u8 tiling ("Failed to
+        prove that a tile index in dimension 0 is divisible by the tiling
+        (1024)", v5e), and a window piece starts wherever the frame's
+        header ends.  So for this kernel ``_post_send`` cuts the piece on
+        the host."""
         import jax
         import jax.numpy as jnp
         from jax import lax
@@ -447,8 +505,8 @@ class DevicePlane:
             rdma.start()
             rdma.wait()
 
-        def per_device(x_local):              # (1, nbytes)
-            out = pl.pallas_call(
+        def per_device(x_local, start):       # the flat array; start is 0
+            return pl.pallas_call(
                 kern,
                 out_shape=jax.ShapeDtypeStruct((nbytes,), jnp.uint8),
                 in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
@@ -461,32 +519,56 @@ class DevicePlane:
                                                      collective_id=2),
                 interpret=interpret,
                 name="brpc_device_plane_p2p",
-            )(x_local[0])
-            return out[None]
+            )(x_local)
 
         return per_device
 
-    def _zeros_row(self, dst_dev: int, nbytes: int):
-        """The dst-side input row (ppermute delivers INTO the program, so
-        dst contributes a dummy shard).  Cached per (dst, size): steady
-        workloads pay this device_put once, not per transfer."""
-        import jax
+    def _zeros_block(self, dst_dev: int, block_bytes: int):
+        """The dst-side input shard (ppermute delivers INTO the program,
+        so dst contributes a dummy block as long as the source's).  Cached
+        per (dst, size): steady workloads pay this once, not per
+        transfer."""
         import jax.numpy as jnp
         gen = IciMesh.generation
-        key = (dst_dev, nbytes, gen)
+        key = (dst_dev, block_bytes, gen)
         with self._lock:
             z = self._zeros.get(key)
             if z is not None:
                 self._zeros.move_to_end(key)
         if z is None:
-            z = jax.device_put(jnp.zeros((1, nbytes), jnp.uint8),
-                               self.mesh().device(dst_dev))
+            z = jnp.zeros((block_bytes,), jnp.uint8,
+                          device=self.mesh().device(dst_dev))
             with self._lock:
                 z = self._zeros.setdefault(key, z)
                 self._zeros.move_to_end(key)
                 while len(self._zeros) > self.MAX_ZEROS:
                     self._zeros.popitem(last=False)
         return z
+
+    def _start_operand(self, t: "DeviceTransfer", mesh2):
+        """The piece's start as the program's replicated int32 operand.
+        Kept per (start, route): a frame layout reposts the same offsets,
+        and a host scalar handed to the program is a host-to-device copy
+        per chip per transfer (0.15 ms of a 0.67 ms dispatch on a CPU
+        mesh).  Eight bytes an entry, so a full table is simply dropped."""
+        import jax
+        import numpy as np
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        key = (t.start, t.src_dev, t.dst_dev, IciMesh.generation)
+        with self._lock:
+            op = self._starts.get(key)
+        if op is None:
+            # assembled from this process's copies: a device_put onto a
+            # sharding that spans processes would be a collective itself
+            op = jax.make_array_from_single_device_arrays(
+                (), NamedSharding(mesh2, P()),
+                [jax.device_put(np.int32(t.start), d)
+                 for d in mesh2.devices.flat if _is_local(d)])
+            with self._lock:
+                if len(self._starts) >= self.MAX_STARTS:
+                    self._starts.clear()
+                self._starts[key] = op
+        return op
 
     # ---- QP interface --------------------------------------------------
     def next_uuid(self) -> int:
@@ -496,26 +578,32 @@ class DevicePlane:
             return u
 
     def post_send(self, arr, src_dev: int, dst_dev: int, socket=None,
-                  uuid: Optional[int] = None,
-                  remote: bool = False) -> DeviceTransfer:
+                  uuid: Optional[int] = None, remote: bool = False,
+                  start: int = 0,
+                  nbytes: Optional[int] = None) -> DeviceTransfer:
         """Post one send WR.  ``arr``: flat uint8 jax array resident on
-        mesh device ``src_dev``.  Raises DevicePlaneError (before any
-        descriptor exists) when refused — chaos injection, or a plane
-        that cannot serve the route — so the caller can fall back in the
-        same frame."""
+        mesh device ``src_dev`` — the whole block; the payload is its
+        piece ``[start, start + nbytes)`` (all of it by default), which
+        the transfer program cuts on the chip.  Raises DevicePlaneError
+        (before any descriptor exists) when refused — chaos injection, or
+        a plane that cannot serve the route — so the caller can fall back
+        in the same frame."""
+        if nbytes is None:
+            nbytes = int(arr.shape[0]) - start
         # layer span brpc.plane.post: the program's fetch or build, the
         # descriptor and its tracking (under the writer's brpc.ici.piece)
-        post = _layer.layer_begin("brpc.plane.post", n=int(arr.shape[0])) \
+        post = _layer.layer_begin("brpc.plane.post", n=nbytes) \
             if _layer.layer_on() else None
         try:
-            return self._post_send(arr, src_dev, dst_dev, socket, uuid,
-                                   remote)
+            return self._post_send(arr, start, nbytes, src_dev, dst_dev,
+                                   socket, uuid, remote)
         finally:
             if post is not None:
                 post.end()
 
-    def _post_send(self, arr, src_dev: int, dst_dev: int, socket,
-                   uuid: Optional[int], remote: bool) -> DeviceTransfer:
+    def _post_send(self, arr, start: int, nbytes: int, src_dev: int,
+                   dst_dev: int, socket, uuid: Optional[int],
+                   remote: bool) -> DeviceTransfer:
         from ..rpc import fault_injection as _fi
         plan = _fi.fabric_active()
         if plan is not None and plan.on_device_post(socket):
@@ -526,13 +614,27 @@ class DevicePlane:
         if src_dev == dst_dev:
             raise DevicePlaneError("device plane is point-to-point; "
                                    "same-device payloads are ref passes")
-        nbytes = int(arr.shape[0])
+        block_bytes = int(arr.shape[0])
+        if not 0 <= start <= block_bytes - nbytes:
+            # dynamic_slice would clamp an out-of-range start in silence
+            raise ValueError(f"piece [{start}, {start + nbytes}) is not "
+                             f"inside its {block_bytes}B block")
+        if nbytes != block_bytes and (
+                remote
+                or _flags.get_flag("ici_device_plane_kernel") == "pallas"):
+            # the two posts whose program cannot cut on the chip: the
+            # Pallas kernel's (_pallas_body), and a peer process's, which
+            # enters the program its kind-4 descriptor names — the piece
+            # alone.  Cut here, the piece crosses as a whole array, which
+            # sliced_in_program does not count
+            arr = arr[start:start + nbytes]
+            start, block_bytes = 0, nbytes
         # compile (or fetch) FIRST: a compilation error must surface before
         # the descriptor is committed to any wire, and the seconds a cold
         # compile takes are not the peer's to answer for — the match
         # timeout runs from the post, which starts once the program exists
         try:
-            self._program(nbytes, src_dev, dst_dev)
+            self._program(block_bytes, nbytes, src_dev, dst_dev)
         except Exception as e:
             with self._lock:
                 self.build_failures += 1
@@ -551,7 +653,7 @@ class DevicePlane:
         tid, psid = _span.current_trace_context()
         t = DeviceTransfer(uuid if uuid is not None else self.next_uuid(),
                            src_dev, dst_dev, nbytes, src_arr=arr,
-                           trace_id=tid, parent_span_id=psid)
+                           trace_id=tid, parent_span_id=psid, start=start)
         if not remote:
             with self._lock:
                 self._pending[t.uuid] = t
@@ -572,10 +674,8 @@ class DevicePlane:
             t = self._pending.pop(uuid, None)
         if t is None:
             raise KeyError(f"device plane: no posted send {uuid:#x}")
-        arr = t.source_array()
         try:
-            out = self._run(t, {t.src_dev: arr.reshape(1, t.nbytes),
-                                t.dst_dev: None})
+            out = self._run(t)
         except Exception as e:
             # in-process degrade: device_put the pinned source (counted);
             # the compiled path failed but the bytes must still arrive
@@ -585,6 +685,12 @@ class DevicePlane:
             with self._lock:
                 self.fallbacks += 1
             _g_fallbacks << 1
+            arr = t.source_array()
+            if t.nbytes != t.block_bytes:
+                # cut here after all: a whole array from now on, as the
+                # posts that _post_send cuts on the host
+                arr = arr[t.start:t.start + t.nbytes]
+                t.start, t.block_bytes = 0, t.nbytes
             out = jax.device_put(arr, self.mesh().device(t.dst_dev))
         self._matched(t, out)
         return t
@@ -609,54 +715,52 @@ class DevicePlane:
         return t
 
     def execute_remote(self, t: DeviceTransfer) -> None:
-        """Enter the compiled program with THIS process's shard (payload
-        row when we own src, dummy row when we own dst).  Called on the
-        fabric executor thread; blocks until the peer joins.  Failure
-        fails the transfer (completion signaled with an error) and
-        re-raises so the socket degrades its plane."""
-        shards = {t.src_dev: None, t.dst_dev: None}
-        arr = t.source_array()
-        if arr is not None:                    # we are the sender
-            shards[t.src_dev] = arr.reshape(1, t.nbytes)
+        """Enter the compiled program with THIS process's shard (the
+        payload when we own src, a dummy block when we own dst).  Called
+        on the fabric executor thread; blocks until the peer joins.
+        Failure fails the transfer (completion signaled with an error)
+        and re-raises so the socket degrades its plane."""
         try:
-            out = self._run(t, shards, local_only=True)
+            out = self._run(t, local_only=True)
         except Exception as e:
             self._fail(t, f"remote execution failed: {e}")
             raise
         self._matched(t, out)
 
     # ---- execution -----------------------------------------------------
-    def _run(self, t: DeviceTransfer, rows: Dict[int, Any],
-             local_only: bool = False):
-        """Build the global (2, n) input and run the cached program.
-        ``rows[dev]``: the (1, n) shard for that mesh device, None for a
-        dummy/other-process shard.  Returns the dst-resident flat array
-        (None when dst is not addressable from this process)."""
+    def _run(self, t: DeviceTransfer, local_only: bool = False):
+        """Assemble the global flat ``(2 * block_bytes,)`` operand — the
+        source's shard IS the pinned block, the destination's a cached
+        dummy (the peer process's shard is left out under ``local_only``)
+        — and run the cached program on it and the piece's start.  Returns
+        the dst-resident flat piece, the destination's shard of the
+        output as it is (None when dst is not addressable from this
+        process)."""
         import jax
-        # layer span brpc.plane.run: the zeros row, the global operand's
+        # layer span brpc.plane.run: the dummy block, the global operand's
         # assembly, the program's dispatch and the pick of dst's shard
         run = _layer.layer_begin("brpc.plane.run", n=t.nbytes) \
             if _layer.layer_on() else None
         try:
             fn, sharding, mesh2, src, dst = self._program(
-                t.nbytes, t.src_dev, t.dst_dev)
+                t.block_bytes, t.nbytes, t.src_dev, t.dst_dev)
             shards = []
-            for dev_id, device in ((t.src_dev, src), (t.dst_dev, dst)):
-                row = rows.get(dev_id)
-                if row is None:
+            for dev_id, device, shard in ((t.src_dev, src, t.source_array()),
+                                          (t.dst_dev, dst, None)):
+                if shard is None:
                     if local_only and not _is_local(device):
                         continue           # the peer process's shard
-                    row = self._zeros_row(dev_id, t.nbytes)
-                shards.append(row)
+                    shard = self._zeros_block(dev_id, t.block_bytes)
+                shards.append(shard)
             ga = jax.make_array_from_single_device_arrays(
-                (2, t.nbytes), sharding, shards)
-            out_global = fn(ga)
-            out = None
+                (2 * t.block_bytes,), sharding, shards)
+            # the start is an operand, never a static: one program serves
+            # every offset of its (block, piece) sizes
+            out_global = fn(ga, self._start_operand(t, mesh2))
             for s in out_global.addressable_shards:
                 if s.device == dst:
-                    out = s.data.reshape(t.nbytes)
-                    break
-            return out
+                    return s.data
+            return None
         finally:
             if run is not None:
                 run.end()
@@ -670,11 +774,17 @@ class DevicePlane:
         # recv half, no source pinned) must not inflate it — in-process
         # transfers are both roles and count both directions
         sender = t.source_array() is not None
+        # still a piece of a longer block here: the program cut it
+        sliced = t.nbytes != t.block_bytes
         with self._lock:
             self.transfers += 1
+            if sliced:
+                self.sliced_in_program += 1
             if sender:
                 self.bytes_sent += t.nbytes
         _g_transfers << 1
+        if sliced:
+            _g_sliced << 1
         if sender:
             _g_bytes_sent << t.nbytes
         # layer span brpc.plane.complete: from here to done(), on whichever
@@ -824,6 +934,7 @@ class DevicePlane:
         with self._lock:
             out = {
                 "transfers": self.transfers,
+                "sliced_in_program": self.sliced_in_program,
                 "bytes_sent": self.bytes_sent,
                 "bytes_recv": self.bytes_recv,
                 "fallbacks": self.fallbacks,

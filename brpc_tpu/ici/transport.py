@@ -309,12 +309,16 @@ class IciSocket(CreditWindow, OrderedDelivery, Socket):
 
     def _relocate(self, frame: IOBuf) -> List:
         """Move DEVICE refs to the peer's chip (HBM→HBM over ICI); host
-        refs pass through as bytes.  Device-resident payloads at/above
-        ``ici_device_plane_threshold`` post a send WR on the device plane
-        instead — the payload then crosses through a COMPILED transfer
-        program (shard_map + ppermute / Pallas remote DMA) with only a
-        descriptor riding the delivery path; the matching recv is
-        enqueued by ``_deliver`` (the QP rendezvous).  A refused post
+        refs pass through as bytes.  Three routes, chosen on the ref's
+        BLOCK before anything is cut: resident on the target, the ref is
+        passed (sliced where it is not the whole block); not resident and
+        at/above ``ici_device_plane_threshold``, a send WR is posted on the
+        device plane with the whole block and ``(offset, length)`` — the
+        payload then crosses through a COMPILED transfer program
+        (shard_map + ppermute / Pallas remote DMA) that cuts the piece on
+        the chip, with only a descriptor riding the delivery path; the
+        matching recv is enqueued by ``_deliver`` (the QP rendezvous);
+        else the slice and ``device_put``.  A refused post
         degrades to device_put in the same frame: a chaos/plane-health
         refusal is counted in the plane's ``fallbacks``, a program the
         compiler refused is logged at error and counted in
@@ -331,35 +335,32 @@ class IciSocket(CreditWindow, OrderedDelivery, Socket):
                 if pending_host:
                     chunks.append(b"".join(pending_host))
                     pending_host = []
+                # the route is decided on the BLOCK, not on a slice of it
                 arr = r.block.data
-                if r.offset or r.length != len(arr):
-                    arr = arr[r.offset:r.offset + r.length]
-                if not hasattr(arr, "devices"):
-                    # host-resident numpy delivered by the fabric bulk
-                    # plane, now being forwarded in-process: detach into
-                    # an owned copy before device_put — jax zero-copy
-                    # ALIASES ctypes-backed views without retaining them
-                    import numpy as _np
-                    arr = _np.array(arr, copy=True)
-                    resident = False
-                else:
+                on_device = hasattr(arr, "devices")
+                resident = False
+                if on_device:
                     try:
                         resident = target in arr.devices()
                     except Exception:
-                        resident = False
+                        pass
                 # already in the target chip's HBM: pure ref pass — the
                 # zero-copy case the block_pool discipline exists for
                 if resident:
-                    chunks.append((arr, r.length))
+                    chunks.append((_cut(arr, r), r.length))
                     with _ici_stats_lock:
                         _ici_device_bytes_moved += r.length
                     continue
-                if _dp.eligible(r.length):
+                if on_device and _dp.eligible(r.length):
                     src_idx = _dp.mesh_index_of(arr, self.mesh)
                     if src_idx >= 0 and src_idx != self.remote_dev:
                         try:
+                            # the WHOLE block and where the piece lies in
+                            # it: the transfer program cuts on the chip,
+                            # no arr[a:b] dispatch on the host
                             t = _dp.plane().post_send(
-                                arr, src_idx, self.remote_dev, socket=self)
+                                arr, src_idx, self.remote_dev, socket=self,
+                                start=r.offset, nbytes=r.length)
                             t.add_source_release(
                                 getattr(r.block, "on_send_complete", None))
                             chunks.append(_PlaneDesc(t, r.length))
@@ -369,6 +370,14 @@ class IciSocket(CreditWindow, OrderedDelivery, Socket):
                         except _dp.DevicePlaneError:
                             pass     # counted (and, for a build error,
                             #          logged) by the plane; device_put
+                arr = _cut(arr, r)
+                if not on_device:
+                    # host-resident numpy delivered by the fabric bulk
+                    # plane, now being forwarded in-process: detach into
+                    # an owned copy before device_put — jax zero-copy
+                    # ALIASES ctypes-backed views without retaining them
+                    import numpy as _np
+                    arr = _np.array(arr, copy=True)
                 moved = jax.device_put(arr, target)
                 self._pin_until_sent(r.block, moved)
                 chunks.append((moved, r.length))
@@ -493,6 +502,15 @@ class IciSocket(CreditWindow, OrderedDelivery, Socket):
             peer._wake_window()
         # release our own writers blocked on the (now dead) window
         self._wake_window()
+
+
+def _cut(arr, r):
+    """The host-side cut of a block ref: one ``arr[a:b]`` (on a device
+    array a jnp ``__getitem__`` and a program dispatch), or the block
+    itself where the ref covers all of it."""
+    if r.offset or r.length != len(arr):
+        return arr[r.offset:r.offset + r.length]
+    return arr
 
 
 class _PlaneDesc:

@@ -583,8 +583,8 @@ def phase_cross_device_echo(rng, mesh, kernels) -> None:
                       "device_put reference differs")
                 # the send window cuts a frame above it into pieces, so
                 # the programs that ran are keyed by the PIECE sizes
-                sizes = sorted({k[0] for k in plane._programs
-                                if k[3] == kernel})
+                sizes = sorted({k[1] for k in plane._programs
+                                if k[4] == kernel})
                 lat.sort()
                 say(f"[xchip-echo] kernel={kernel} {fmt_bytes(nbytes):>6} "
                     f"x{calls}: 0->1->0 byte-exact, handler on {d1}, "
@@ -602,7 +602,7 @@ def phase_cross_device_echo(rng, mesh, kernels) -> None:
                 check(set(t.out.devices()) == {d1}
                       and np.array_equal(np.asarray(t.out), host),
                       f"whole-payload {kernel} transfer differs")
-                ma = plane._program(nbytes, 0, 1)[0].memory_analysis()
+                ma = plane._program(nbytes, nbytes, 0, 1)[0].memory_analysis()
                 say(f"[xchip-plane] kernel={kernel} {fmt_bytes(nbytes):>6} "
                     f"as one work request 0->1: byte-exact on {d1}; "
                     f"program argument={fmt_bytes(ma.argument_size_in_bytes)} "

@@ -133,6 +133,132 @@ class TestProgramCache:
         assert dp.mesh_index_of(t.out) == 6
 
 
+class TestPieceOfABlock:
+    """The transfer program cuts the piece on the chip: the post carries
+    the whole block and a start (no arr[a:b] and no (1, n) row on the
+    host), and the destination's output shard is the flat piece."""
+
+    BLOCK, PIECE = 64 * 1024, 8 * 1024
+
+    def _cross(self, plane, block, start, nbytes, src=1, dst=6):
+        t = plane.post_send(block, src, dst, start=start, nbytes=nbytes)
+        assert plane.post_recv(t.uuid) is t
+        assert t.wait(30) == 0, t.error
+        return t
+
+    @pytest.mark.parametrize("start", [0, 25, 64 * 1024 - 8 * 1024],
+                             ids=["head", "odd", "tail"])
+    def test_piece_arrives_byte_exact_and_flat(self, plane_on, start):
+        block = _payload(self.BLOCK, 1)
+        t = self._cross(plane_on, block, start, self.PIECE)
+        assert (t.start, t.nbytes, t.block_bytes) == (start, self.PIECE,
+                                                      self.BLOCK)
+        assert t.out.shape == (self.PIECE,) and t.out.dtype == np.uint8
+        assert dp.mesh_index_of(t.out) == 6
+        np.testing.assert_array_equal(
+            np.asarray(t.out), np.asarray(block)[start:start + self.PIECE])
+
+    def test_every_start_of_one_block_and_piece_is_one_program(
+            self, plane_on):
+        block = _payload(self.BLOCK + 1024, 1)      # sizes no test shares
+        self._cross(plane_on, block, 0, self.PIECE)
+        misses = plane_on.stats()["program_cache_misses"]
+        for start in (25, 4096, self.BLOCK + 1024 - self.PIECE):
+            t = self._cross(plane_on, block, start, self.PIECE)
+            np.testing.assert_array_equal(
+                np.asarray(t.out),
+                np.asarray(block)[start:start + self.PIECE])
+        assert plane_on.stats()["program_cache_misses"] == misses
+
+    def test_sliced_in_program_counts_pieces_not_whole_arrays(
+            self, plane_on):
+        block = _payload(self.BLOCK, 1)
+        before = plane_on.stats()
+        t = self._cross(plane_on, block, 0, self.BLOCK)     # the whole array
+        np.testing.assert_array_equal(np.asarray(t.out), np.asarray(block))
+        mid = plane_on.stats()
+        assert mid["transfers"] == before["transfers"] + 1
+        assert mid["sliced_in_program"] == before["sliced_in_program"]
+        self._cross(plane_on, block, 0, self.PIECE)         # a head is a cut
+        self._cross(plane_on, block, 25, self.PIECE)
+        after = plane_on.stats()
+        assert after["transfers"] == before["transfers"] + 3
+        assert after["sliced_in_program"] == before["sliced_in_program"] + 2
+        assert after["bytes_sent"] == (before["bytes_sent"] + self.BLOCK
+                                       + 2 * self.PIECE)
+
+    def test_source_block_is_released_once_per_transfer(self, plane_on):
+        block = _payload(self.BLOCK, 1)
+        released = []
+        posted = [plane_on.post_send(block, 1, 6, start=k * self.PIECE,
+                                     nbytes=self.PIECE) for k in range(3)]
+        for k, t in enumerate(posted):
+            t.add_source_release(lambda k=k: released.append(k))
+        assert released == [] and plane_on.active_transfers() >= 3
+        for t in posted:
+            assert t.source_array() is block    # the block, not a slice
+            plane_on.post_recv(t.uuid)
+            assert t.wait(30) == 0
+        assert sorted(released) == [0, 1, 2]
+        assert all(t.source_array() is None for t in posted)
+
+    def test_device_put_fallback_delivers_the_piece_not_the_block(
+            self, plane_on, monkeypatch):
+        block = _payload(self.BLOCK, 1)
+        f0 = plane_on.stats()["fallbacks"]
+        s0 = plane_on.stats()["sliced_in_program"]
+
+        def broken(t, local_only=False):
+            raise RuntimeError("program execution failed")
+        monkeypatch.setattr(plane_on, "_run", broken)
+        t = self._cross(plane_on, block, 25, self.PIECE)
+        assert t.out.shape == (self.PIECE,)
+        assert dp.mesh_index_of(t.out) == 6
+        np.testing.assert_array_equal(
+            np.asarray(t.out), np.asarray(block)[25:25 + self.PIECE])
+        assert plane_on.stats()["fallbacks"] == f0 + 1
+        assert plane_on.stats()["sliced_in_program"] == s0
+
+    def test_pallas_kernel_is_posted_a_host_cut_piece(self, plane_on):
+        """Mosaic refuses a DMA source at an unaligned dynamic start, so
+        that kernel's piece is cut on the host and crosses as a whole
+        array — counted as a transfer, not as sliced in the program."""
+        fl.set_flag("ici_device_plane_kernel", "pallas")
+        block = _payload(self.BLOCK, 2)
+        before = plane_on.stats()
+        t = self._cross(plane_on, block, 25, 2048, src=2, dst=6)
+        assert (t.start, t.block_bytes) == (0, 2048)
+        np.testing.assert_array_equal(np.asarray(t.out),
+                                      np.asarray(block)[25:25 + 2048])
+        after = plane_on.stats()
+        assert after["transfers"] == before["transfers"] + 1
+        assert after["sliced_in_program"] == before["sliced_in_program"]
+
+    @pytest.mark.parametrize("start,nbytes", [(-1, 1024), (1, 64 * 1024),
+                                              (64 * 1024, 1024)])
+    def test_piece_outside_its_block_is_refused(self, plane_on, start,
+                                                nbytes):
+        block = _payload(self.BLOCK, 1)
+        active = plane_on.active_transfers()
+        with pytest.raises(ValueError, match="not inside"):
+            plane_on.post_send(block, 1, 6, start=start, nbytes=nbytes)
+        assert plane_on.active_transfers() == active
+
+    def test_cross_process_post_is_a_whole_array(self, plane_on):
+        """The kind-4 descriptor names the piece alone, so the peer process
+        enters the whole-array program of that size: a sub-range posted
+        for it is cut on the host."""
+        block = _payload(self.BLOCK, 1)
+        t = plane_on.post_send(block, 1, 6, remote=True, start=25,
+                               nbytes=self.PIECE)
+        assert (t.start, t.nbytes, t.block_bytes) == (0, self.PIECE,
+                                                      self.PIECE)
+        np.testing.assert_array_equal(
+            np.asarray(t.source_array()),
+            np.asarray(block)[25:25 + self.PIECE])
+        plane_on.fail_transfer(t, "test teardown")
+
+
 class TestFailureModes:
     def test_match_timeout_fails_only_that_transfer(self, plane_on):
         """A posted send whose recv never arrives (peer died between

@@ -468,3 +468,126 @@ def test_a_windowed_echos_gates_never_leave_the_poller(mesh, monkeypatch):
         assert disp.handoffs() == handed
     finally:
         server.stop()
+
+
+class TestRelocateCutsOnTheChip:
+    """A DEVICE block that is not resident on the target and is large
+    enough for the device plane is posted WHOLE with (offset, length): the
+    transfer program cuts it, the host-side slice (``transport._cut``)
+    is not called on that branch.  The resident branch and the branch
+    under the plane's threshold slice as they did."""
+
+    WINDOW, PIECES = 64 * 1024, 16
+
+    @pytest.fixture()
+    def host_mesh_plane(self):
+        from brpc_tpu.butil import flags as fl
+        from brpc_tpu.ici import device_plane as dp
+        saved = {n: fl.get_flag(n) for n in
+                 ("ici_device_plane", "ici_device_plane_host_mesh",
+                  "ici_device_plane_threshold", "ici_device_plane_kernel")}
+        fl.set_flag("ici_device_plane", True)
+        fl.set_flag("ici_device_plane_host_mesh", True)
+        fl.set_flag("ici_device_plane_threshold", 1024)
+        fl.set_flag("ici_device_plane_kernel", "ppermute")
+        yield dp.plane()
+        for n, v in saved.items():
+            fl.set_flag(n, v)
+
+    def _write_and_drain(self, mesh, monkeypatch, src_dev, dst_dev):
+        """A frame of a 4-byte header and one PIECES x WINDOW device block
+        through a socket pair whose window is WINDOW: PIECES whole window
+        pieces and the header's remainder.  Returns (host cuts made, bytes
+        delivered, the payload's bytes, source releases)."""
+        import jax
+        import jax.numpy as jnp
+        from brpc_tpu.butil.iobuf import IOBuf, IOPortal
+        from brpc_tpu.ici import transport as tr
+        a = tr.IciSocket(src_dev, dst_dev, mesh, window_bytes=self.WINDOW)
+        b = tr.IciSocket(dst_dev, src_dev, mesh, window_bytes=self.WINDOW)
+        a.peer, b.peer = b, a
+        cuts, released = [], []
+        real_cut = tr._cut
+
+        def counting_cut(arr, r):
+            out = real_cut(arr, r)
+            if out is not arr:
+                cuts.append((r.offset, r.length))
+            return out
+        monkeypatch.setattr(tr, "_cut", counting_cut)
+        nbytes = self.PIECES * self.WINDOW
+        payload = jax.device_put(
+            (jnp.arange(nbytes, dtype=jnp.uint32) * 7 % 251).astype(
+                jnp.uint8), mesh.device(src_dev))
+        jax.block_until_ready(payload)
+        buf = IOBuf(b"hdr:")
+        buf.append_device_array(payload)
+        buf.backing_block(1).block.on_send_complete = \
+            lambda: released.append(1)
+        try:
+            assert a.write(buf) == 0
+            portal, got = IOPortal(), 0
+            deadline = time.monotonic() + 30
+            while got < 4 + nbytes and time.monotonic() < deadline:
+                n = b._do_read(portal, 1 << 20)
+                if n <= 0:
+                    time.sleep(0.002)
+                    continue
+                got += n
+            assert got == 4 + nbytes
+            for r in portal.device_refs():
+                assert set(r.block.data.devices()) == {mesh.device(dst_dev)}
+                assert r.block.data.ndim == 1
+            deadline = time.monotonic() + 10
+            while a.inflight_send_blocks() and time.monotonic() < deadline:
+                time.sleep(0.002)
+            return cuts, portal.to_bytes(), bytes(np.asarray(payload)), \
+                released
+        finally:
+            a.set_failed()
+            b.set_failed()
+
+    def test_plane_branch_posts_the_block_and_never_slices(
+            self, mesh, monkeypatch, host_mesh_plane):
+        before = host_mesh_plane.stats()
+        cuts, got, want, released = self._write_and_drain(
+            mesh, monkeypatch, 2, 3)
+        assert got == b"hdr:" + want
+        after = host_mesh_plane.stats()
+        # sixteen window pieces through the plane, each cut by the program
+        assert after["transfers"] - before["transfers"] == self.PIECES
+        assert (after["sliced_in_program"] - before["sliced_in_program"]
+                == self.PIECES)
+        assert after["fallbacks"] == before["fallbacks"]
+        assert (after["bytes_sent"] - before["bytes_sent"]
+                == self.PIECES * self.WINDOW - 4)
+        # the only host-side cut is the header's 4-byte remainder, which is
+        # under the plane's threshold and goes by slice and device_put
+        assert cuts == [(self.PIECES * self.WINDOW - 4, 4)]
+        # the block's pin is released once per transfer and once for the
+        # remainder's device_put
+        deadline = time.monotonic() + 10
+        while len(released) < self.PIECES + 1 \
+                and time.monotonic() < deadline:
+            time.sleep(0.005)
+        assert len(released) == self.PIECES + 1
+
+    def test_resident_branch_slices_as_before(self, mesh, monkeypatch,
+                                              host_mesh_plane):
+        before = host_mesh_plane.stats()["transfers"]
+        cuts, got, want, _ = self._write_and_drain(mesh, monkeypatch, 4, 4)
+        assert got == b"hdr:" + want
+        assert host_mesh_plane.stats()["transfers"] == before
+        assert len(cuts) == self.PIECES + 1
+        assert cuts[0] == (0, self.WINDOW - 4)
+        assert cuts[-1] == (self.PIECES * self.WINDOW - 4, 4)
+
+    def test_without_the_plane_every_piece_is_sliced_and_device_put(
+            self, mesh, monkeypatch, host_mesh_plane):
+        from brpc_tpu.butil import flags as fl
+        fl.set_flag("ici_device_plane_threshold", 1 << 30)
+        before = host_mesh_plane.stats()["transfers"]
+        cuts, got, want, _ = self._write_and_drain(mesh, monkeypatch, 2, 3)
+        assert got == b"hdr:" + want
+        assert host_mesh_plane.stats()["transfers"] == before
+        assert len(cuts) == self.PIECES + 1
