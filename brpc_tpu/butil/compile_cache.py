@@ -2,8 +2,8 @@
 
 Every transfer program, collective and serving step is compiled per shape;
 a process that starts with no compiled code pays all of them again.  Entry
-points (``chip_smoke.py``, ``bench.py`` and its ``--sub`` children, the
-examples' ``main``s) call :func:`enable` once before their first jit — the
+points (``chip_smoke.py``, ``benchmarks/run.py``, the examples'
+``main``s) call :func:`enable` once before their first jit — the
 library never does it at import, so embedding applications keep control of
 their own cache.
 

@@ -316,21 +316,12 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
         ctypes.POINTER(ctypes.c_uint64), ctypes.POINTER(u8p),
         ctypes.POINTER(ctypes.c_uint64), ctypes.POINTER(ctypes.c_char_p)]
     lib.brpc_tpu_npool_close.argtypes = [ctypes.c_uint64]
-    lib.brpc_tpu_native_pooled_throughput_gbps.restype = ctypes.c_double
-    lib.brpc_tpu_native_pooled_throughput_gbps.argtypes = [
-        ctypes.c_int, ctypes.c_int, ctypes.c_int, ctypes.c_int]
-    lib.brpc_tpu_native_async_throughput_gbps.restype = ctypes.c_double
-    lib.brpc_tpu_native_async_throughput_gbps.argtypes = [
-        ctypes.c_int, ctypes.c_int, ctypes.c_int]
     lib.brpc_tpu_native_rpc_echo_p50_ns.restype = ctypes.c_int64
     lib.brpc_tpu_native_rpc_echo_p50_ns.argtypes = [ctypes.c_int,
                                                     ctypes.c_int]
     lib.brpc_tpu_native_rpc_qps.restype = ctypes.c_double
     lib.brpc_tpu_native_rpc_qps.argtypes = [ctypes.c_int, ctypes.c_int,
                                             ctypes.c_int]
-    lib.brpc_tpu_native_rpc_throughput_gbps.restype = ctypes.c_double
-    lib.brpc_tpu_native_rpc_throughput_gbps.argtypes = [
-        ctypes.c_int, ctypes.c_int, ctypes.c_int]
     # ---- native ici:// plane (native/rpc.cpp ici section) ----
     segp = ctypes.POINTER(IciSegC)
     lib.brpc_tpu_ici_set_hooks.argtypes = [_ICI_RELOCATE_FN, _ICI_RELEASE_FN]
@@ -352,29 +343,15 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
     lib.brpc_tpu_ici_close.argtypes = [ctypes.c_uint64]
     lib.brpc_tpu_ici_window_left.restype = ctypes.c_int64
     lib.brpc_tpu_ici_window_left.argtypes = [ctypes.c_uint64]
-    lib.brpc_tpu_ici_call.restype = ctypes.c_uint64
-    lib.brpc_tpu_ici_call.argtypes = [
-        ctypes.c_uint64, ctypes.c_char_p, u8p, ctypes.c_uint64, u8p,
-        ctypes.c_uint64, segp, ctypes.c_uint64, ctypes.c_int64,
-        ctypes.POINTER(u8p), ctypes.POINTER(ctypes.c_uint64),
-        ctypes.POINTER(u8p), ctypes.POINTER(ctypes.c_uint64),
-        ctypes.POINTER(segp), ctypes.POINTER(ctypes.c_uint64),
-        ctypes.POINTER(ctypes.c_char_p)]
     lib.brpc_tpu_ici_call2.restype = ctypes.c_uint64
     lib.brpc_tpu_ici_call2.argtypes = [
         ctypes.c_uint64, ctypes.c_char_p, u8p, ctypes.c_uint64, u8p,
         ctypes.c_uint64, segp, ctypes.c_uint64, ctypes.c_int64,
         ctypes.POINTER(IciCallOut)]
     # call2 + admission meta (priority wire-encoded, tenant, remaining
-    # deadline budget); out.retry_after_ms carries the shed hint back
-    lib.brpc_tpu_ici_call3.restype = ctypes.c_uint64
-    lib.brpc_tpu_ici_call3.argtypes = [
-        ctypes.c_uint64, ctypes.c_char_p, u8p, ctypes.c_uint64, u8p,
-        ctypes.c_uint64, segp, ctypes.c_uint64, ctypes.c_int64,
-        ctypes.c_int64, ctypes.c_char_p, ctypes.c_int64,
-        ctypes.POINTER(IciCallOut)]
-    # call3 + native att custody on the response (out.att_handle + seg0
-    # inline; error-path response segs released natively)
+    # deadline budget; out.retry_after_ms carries the shed hint back) +
+    # native att custody on the response (out.att_handle + seg0 inline;
+    # error-path response segs released natively)
     lib.brpc_tpu_ici_call4.restype = ctypes.c_uint64
     lib.brpc_tpu_ici_call4.argtypes = [
         ctypes.c_uint64, ctypes.c_char_p, u8p, ctypes.c_uint64, u8p,
@@ -392,9 +369,6 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
                                           ctypes.c_uint64]
     lib.brpc_tpu_ici_att_count.restype = ctypes.c_uint64
     lib.brpc_tpu_ici_att_count.argtypes = []
-    lib.brpc_tpu_ici_set_att_handles.restype = ctypes.c_int
-    lib.brpc_tpu_ici_set_att_handles.argtypes = [ctypes.c_uint64,
-                                                 ctypes.c_int]
     lib.brpc_tpu_ici_respond.restype = ctypes.c_int
     lib.brpc_tpu_ici_respond.argtypes = [
         ctypes.c_uint64, ctypes.c_uint64, ctypes.c_char_p, u8p,
@@ -589,37 +563,3 @@ def native_rpc_qps(threads: int = 16, duration_ms: int = 1500,
     if lib is None:
         return -1.0
     return lib.brpc_tpu_native_rpc_qps(threads, duration_ms, payload)
-
-
-def native_rpc_throughput_gbps(threads: int = 2, duration_ms: int = 1500,
-                               payload: int = 4 << 20) -> float:
-    """Large-request echo throughput GB/s, 1 client -> 1 server (the
-    reference's 2.3 GB/s headline config); -1 if unavailable."""
-    lib = load()
-    if lib is None:
-        return -1.0
-    return lib.brpc_tpu_native_rpc_throughput_gbps(threads, duration_ms,
-                                                   payload)
-
-
-def native_pooled_throughput_gbps(nconns: int = 2, threads: int = 2,
-                                  duration_ms: int = 1500,
-                                  payload: int = 1 << 20) -> float:
-    """Pooled multi-connection large-request throughput (reference
-    socket.h:256-262 pooled sockets); -1 if unavailable."""
-    lib = load()
-    if lib is None:
-        return -1.0
-    return lib.brpc_tpu_native_pooled_throughput_gbps(
-        nconns, threads, duration_ms, payload)
-
-
-def native_async_throughput_gbps(depth: int = 4, duration_ms: int = 1500,
-                                 payload: int = 256 << 10) -> float:
-    """Pipelined (async, `depth` in flight) throughput on one connection
-    (the KeepWrite batching shape, socket.cpp:1685); -1 if unavailable."""
-    lib = load()
-    if lib is None:
-        return -1.0
-    return lib.brpc_tpu_native_async_throughput_gbps(depth, duration_ms,
-                                                     payload)

@@ -8,10 +8,8 @@ completion): instead of staging device payloads through host memory
 a DEVICE-block payload is moved chip-to-chip by a **compiled XLA
 point-to-point transfer program** — shard_map + ``jax.lax.ppermute`` over
 a 2-device submesh (XLA-scheduled; on TPU this lowers to a
-collective-permute over the ICI links), or a Pallas
-``make_async_remote_copy`` kernel (hand-scheduled remote DMA, the literal
-``ibv_post_send``) where ``pltpu`` is available.  No NIC — and no host —
-in the datapath.
+collective-permute over the ICI links).  No NIC — and no host — in the
+datapath.
 
 QP semantics (rdma_endpoint.h:37-108):
 
@@ -43,16 +41,12 @@ serves every offset.  Per chip it runs
 whole array is the case ``start == 0, B == n`` of the same path (XLA folds
 the slice away), which is what the fabric and the native tier's relocation
 upcall post.  On the TPU a 1-D <-> ``(1, n)`` reshape is a relayout, not a
-bitcast, which is why no ``(1, n)`` row appears anywhere.  The one
-exception is the Pallas kernel: Mosaic refuses a DMA source at a dynamic
-start it cannot prove aligned to the u8 tiling, so for that kernel
-``_post_send`` cuts the piece on the host and the kernel moves a whole
-array (``_pallas_body``).  ``stats()["sliced_in_program"]`` counts the
-transfers whose piece was a sub-range of its block and was cut by the
-program, beside ``transfers``.
+bitcast, which is why no ``(1, n)`` row appears anywhere.
+``stats()["sliced_in_program"]`` counts the transfers whose piece was a
+sub-range of its block and was cut by the program, beside ``transfers``.
 
 Program cache: one compiled executable per (block bytes, piece bytes, src,
-dst, kernel, mesh generation), exactly like the collectives cache — steady
+dst, mesh generation), exactly like the collectives cache — steady
 workloads repost the same shapes and pay compilation once (cache
 hits/misses are counters).
 
@@ -104,16 +98,11 @@ _flags.define_flag("ici_device_plane_threshold", 64 * 1024,
 # materialization for what is physically one host memcpy.  On TPU the
 # program IS the ICI datapath and device_put cannot cross processes at
 # all, so the plane engages there by default; host meshes must opt in
-# (tests, bench, and the dryrun do — the code path is identical).
+# (tests and the dryrun do — the code path is identical).
 _flags.define_flag("ici_device_plane_host_mesh", False,
                    "engage the device plane on non-TPU (host-memory) "
                    "meshes too; slower than device_put there, real code "
                    "path for CI")
-_flags.define_flag("ici_device_plane_kernel", "ppermute",
-                   "transfer kernel: 'ppermute' (XLA-scheduled "
-                   "shard_map + lax.ppermute) or 'pallas' "
-                   "(make_async_remote_copy remote DMA; interpret mode "
-                   "off-TPU)")
 _flags.define_flag("ici_device_plane_match_timeout_s", 30.0,
                    "seconds a posted send waits for its matching recv "
                    "before failing (peer died post-descriptor)")
@@ -408,9 +397,8 @@ class DevicePlane:
         """Compile-or-fetch the (src → dst) transfer program that cuts
         ``nbytes`` out of a ``block_bytes`` block.
         Returns (fn, input_sharding, mesh2, src_device, dst_device)."""
-        kernel = _flags.get_flag("ici_device_plane_kernel")
         gen = IciMesh.generation
-        key = (block_bytes, nbytes, src_dev, dst_dev, kernel, gen)
+        key = (block_bytes, nbytes, src_dev, dst_dev, gen)
         with self._lock:
             hit = self._programs.get(key)
             if hit is not None:
@@ -419,7 +407,7 @@ class DevicePlane:
         if hit is not None:
             _g_cache_hits << 1
             return hit
-        built = self._build(block_bytes, nbytes, src_dev, dst_dev, kernel)
+        built = self._build(block_bytes, nbytes, src_dev, dst_dev)
         with self._lock:
             # a racing builder may have won; keep the first (identical)
             entry = self._programs.setdefault(key, built)
@@ -431,7 +419,7 @@ class DevicePlane:
         return entry
 
     def _build(self, block_bytes: int, nbytes: int, src_dev: int,
-               dst_dev: int, kernel: str):
+               dst_dev: int):
         import jax
         import jax.numpy as jnp
         import numpy as np
@@ -441,15 +429,11 @@ class DevicePlane:
         src, dst = mesh.device(src_dev), mesh.device(dst_dev)
         mesh2 = Mesh(np.array([src, dst]), ("p2p",))
         sharding = NamedSharding(mesh2, P("p2p"))
-        if kernel == "pallas":
-            if nbytes != block_bytes:
-                raise ValueError("the Pallas kernel takes whole arrays "
-                                 "(see _pallas_body)")
-            per_device = self._pallas_body(nbytes, (src, dst))
-        else:
-            def per_device(x_local, start):   # the flat block, () int32
-                piece = jax.lax.dynamic_slice(x_local, (start,), (nbytes,))
-                return jax.lax.ppermute(piece, "p2p", [(0, 1)])
+
+        def per_device(x_local, start):       # the flat block, () int32
+            piece = jax.lax.dynamic_slice(x_local, (start,), (nbytes,))
+            return jax.lax.ppermute(piece, "p2p", [(0, 1)])
+
         # compiled HERE, not at first call: the compiler's verdict on the
         # program belongs to the build (post_send), before any descriptor
         fn = jax.jit(shard_map(per_device, mesh=mesh2,
@@ -461,67 +445,6 @@ class DevicePlane:
                                  sharding=NamedSharding(mesh2, P()))
         ).compile()
         return (fn, sharding, mesh2, src, dst)
-
-    @staticmethod
-    def _pallas_body(nbytes: int, devices):
-        """The hand-scheduled variant: one remote-DMA hop, HBM → HBM
-        (pltpu.make_async_remote_copy = ibv_post_send over ICI; see
-        pallas_ring.py for the ring-shaped sibling).  The payload never
-        enters VMEM, so its size is bounded by HBM alone.  Symmetric
-        shift — both submesh members post toward the other after a
-        barrier handshake (ICI links are bidirectional, so the unused
-        reverse hop is free on hardware); only the dst shard of the output
-        is consumed.  Compiled for a TPU submesh, the Pallas TPU
-        interpreter for any other (pallas_ring.interpret_for).
-
-        Whole arrays only, and ``start`` is not read: Mosaic refuses
-        ``local_ref.at[pl.ds(start, nbytes)]`` as the DMA's source for a
-        start it cannot prove a multiple of the u8 tiling ("Failed to
-        prove that a tile index in dimension 0 is divisible by the tiling
-        (1024)", v5e), and a window piece starts wherever the frame's
-        header ends.  So for this kernel ``_post_send`` cuts the piece on
-        the host."""
-        import jax
-        import jax.numpy as jnp
-        from jax import lax
-        import jax.experimental.pallas as pl
-        import jax.experimental.pallas.tpu as pltpu
-        from .pallas_ring import interpret_for, neighbour_barrier
-        interpret = interpret_for(devices, f"device-plane {nbytes}B")
-
-        def kern(local_ref, out_ref, send_sem, recv_sem):
-            other = 1 - lax.axis_index("p2p")
-            # the QP handshake: the peer is inside the kernel (its
-            # out_ref is live) before our DMA targets it
-            neighbour_barrier(other, other)
-            rdma = pltpu.make_async_remote_copy(
-                src_ref=local_ref,
-                dst_ref=out_ref,
-                send_sem=send_sem,
-                recv_sem=recv_sem,
-                device_id=other,
-                device_id_type=pltpu.DeviceIdType.LOGICAL,
-            )
-            rdma.start()
-            rdma.wait()
-
-        def per_device(x_local, start):       # the flat array; start is 0
-            return pl.pallas_call(
-                kern,
-                out_shape=jax.ShapeDtypeStruct((nbytes,), jnp.uint8),
-                in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-                out_specs=pl.BlockSpec(memory_space=pl.ANY),
-                scratch_shapes=[
-                    pltpu.SemaphoreType.DMA(()),
-                    pltpu.SemaphoreType.DMA(()),
-                ],
-                compiler_params=pltpu.CompilerParams(has_side_effects=True,
-                                                     collective_id=2),
-                interpret=interpret,
-                name="brpc_device_plane_p2p",
-            )(x_local)
-
-        return per_device
 
     def _zeros_block(self, dst_dev: int, block_bytes: int):
         """The dst-side input shard (ppermute delivers INTO the program,
@@ -619,14 +542,11 @@ class DevicePlane:
             # dynamic_slice would clamp an out-of-range start in silence
             raise ValueError(f"piece [{start}, {start + nbytes}) is not "
                              f"inside its {block_bytes}B block")
-        if nbytes != block_bytes and (
-                remote
-                or _flags.get_flag("ici_device_plane_kernel") == "pallas"):
-            # the two posts whose program cannot cut on the chip: the
-            # Pallas kernel's (_pallas_body), and a peer process's, which
-            # enters the program its kind-4 descriptor names — the piece
-            # alone.  Cut here, the piece crosses as a whole array, which
-            # sliced_in_program does not count
+        if remote and nbytes != block_bytes:
+            # the one post whose program cannot cut on the chip: a peer
+            # process enters the program its kind-4 descriptor names —
+            # the piece alone.  Cut here, the piece crosses as a whole
+            # array, which sliced_in_program does not count
             arr = arr[start:start + nbytes]
             start, block_bytes = 0, nbytes
         # compile (or fetch) FIRST: a compilation error must surface before
@@ -640,10 +560,8 @@ class DevicePlane:
                 self.build_failures += 1
             _g_build_failures << 1
             log.error("device plane ici://%d->%d: %dB transfer program "
-                      "(kernel=%s) was refused by the compiler: %s: %s",
-                      src_dev, dst_dev, nbytes,
-                      _flags.get_flag("ici_device_plane_kernel"),
-                      type(e).__name__, e)
+                      "was refused by the compiler: %s: %s",
+                      src_dev, dst_dev, nbytes, type(e).__name__, e)
             raise DevicePlaneBuildError(
                 f"transfer program build failed: {e}") from e
         # trace context at post time: the server span being served, or

@@ -797,8 +797,8 @@ class FabricNode:
         """Both ends hold the shm capability AND share this host.  The
         flag is re-checked at CONNECT time (not just at the start-time
         probe) so a tool pinning the tier off after the node joined —
-        rpc_press --bulk-plane uds, the bench's pinned legs — takes
-        effect on every later socket."""
+        rpc_press --bulk-plane uds — takes effect on every later
+        socket."""
         if not self._shm_ok or not _flags.get_flag("ici_fabric_shm"):
             return False
         try:

@@ -61,30 +61,25 @@ _string_at = ctypes._string_at
 _fused_ffi = None
 
 
-def _fused_call_binding(att_custody: bool):
-    """Fused-path FFI bindings for call3/call4 whose payload/att-host
-    argtypes are ``c_char_p`` — bytes objects pass straight through
-    (ABI-identical pointer) instead of paying two ``ctypes.cast``
-    frames per call.  Bound on a SEPARATE CDLL handle so the legacy
-    ``call`` keeps its POINTER(c_uint8) binding byte-for-byte."""
+def _fused_call_binding():
+    """Fused-path FFI binding for call4 whose payload/att-host argtypes
+    are ``c_char_p`` — bytes objects pass straight through (ABI-identical
+    pointer) instead of paying two ``ctypes.cast`` frames per call.
+    Bound on a SEPARATE CDLL handle so the legacy ``call`` keeps its
+    POINTER(c_uint8) binding byte-for-byte."""
     global _fused_ffi
     if _fused_ffi is None:
         lib = native.load()
         lib2 = ctypes.CDLL(lib._name)
-        segp = ctypes.POINTER(IciSegC)
-        argt = [ctypes.c_uint64, ctypes.c_char_p, ctypes.c_char_p,
-                ctypes.c_uint64, ctypes.c_char_p, ctypes.c_uint64,
-                segp, ctypes.c_uint64, ctypes.c_int64, ctypes.c_int64,
-                ctypes.c_char_p, ctypes.c_int64,
-                ctypes.POINTER(IciCallOut)]
-        f3 = lib2.brpc_tpu_ici_call3
-        f3.restype = ctypes.c_uint64
-        f3.argtypes = argt
         f4 = lib2.brpc_tpu_ici_call4
         f4.restype = ctypes.c_uint64
-        f4.argtypes = argt
-        _fused_ffi = (f3, f4)
-    return _fused_ffi[1] if att_custody else _fused_ffi[0]
+        f4.argtypes = [ctypes.c_uint64, ctypes.c_char_p, ctypes.c_char_p,
+                       ctypes.c_uint64, ctypes.c_char_p, ctypes.c_uint64,
+                       ctypes.POINTER(IciSegC), ctypes.c_uint64,
+                       ctypes.c_int64, ctypes.c_int64, ctypes.c_char_p,
+                       ctypes.c_int64, ctypes.POINTER(IciCallOut)]
+        _fused_ffi = f4
+    return _fused_ffi
 
 # Batched one-struct upcall tuning (native/rpc.cpp enqueue_batch): the
 # drainer takes up to max_batch requests per GIL crossing; an arrival
@@ -102,13 +97,10 @@ _flags.define_flag("ici_upcall_batch_age_us", 50,
 # Native attachment custody (ISSUE 12): device-seg lists park in a
 # NATIVE att table and move as one opaque handle — the handler tier
 # receives a ready zero-copy IOBuf view (NativeAttachment) instead of
-# walking seg descriptors through the registry twice per RPC.  Off =
-# the PR-8 take-during-upcall walk, byte-for-byte (the A/B leg).
-_flags.define_flag("ici_native_att_custody", True,
-                   "resolve ici attachment seg tokens native-side: "
-                   "handlers receive a lazily-materialized zero-copy "
-                   "view backed by native custody instead of a "
-                   "per-seg registry walk")
+# walking seg descriptors through the registry twice per RPC.  A
+# host-mixed attachment alone keeps the take-during-upcall walk
+# (build_attachment_from_c): its host spans interleave with the device
+# segs positionally.
 
 # Fused dispatch (ISSUE 13): the per-RPC interpreter-frame chain on the
 # native-ici hot path collapses into single flat code objects —
@@ -117,8 +109,7 @@ _flags.define_flag("ici_native_att_custody", True,
 # screens + ChannelBinding.call fuse into ChannelBinding.call_fused on
 # the client, with per-method dispatch resolved ONCE per (listener,
 # method) instead of per call.  Off = the PR-12 frame chain
-# byte-for-byte (the A/B leg).  Snapshot at bind/connect time, like
-# ici_native_att_custody.
+# byte-for-byte (the A/B leg).  Snapshot at bind/connect time.
 _flags.define_flag("ici_fused_dispatch", True,
                    "collapse the native-ici per-RPC dispatch chain "
                    "into fused code objects (server process/execute/"
@@ -806,22 +797,15 @@ class ServerBinding:
         lib.brpc_tpu_ici_set_batch_params(
             h, int(_flags.get_flag("ici_upcall_max_batch")),
             int(_flags.get_flag("ici_upcall_batch_age_us")))
-        # native att custody: device-seg lists arrive as parked handles
-        # (IciReqC.att_handle) instead of take-during-upcall seg walks.
-        # Snapshot at bind time — the A/B bench flips the flag between
-        # server generations, never mid-listener.
-        self._att_custody = bool(
-            _flags.get_flag("ici_native_att_custody"))
-        lib.brpc_tpu_ici_set_att_handles(h, 1 if self._att_custody else 0)
-        # fused dispatch (ISSUE 13), snapshot at bind like att custody:
-        # the inline hot path runs through _process_fused — one flat
-        # code object per request — with the per-method dispatch tuple
-        # resolved once per raw method key and every hot module handle
-        # bound HERE instead of re-resolved per call
+        # fused dispatch (ISSUE 13), snapshot at bind: the inline hot
+        # path runs through _process_fused — one flat code object per
+        # request — with the per-method dispatch tuple resolved once per
+        # raw method key and every hot module handle bound HERE instead
+        # of re-resolved per call
         self._fused = bool(_flags.get_flag("ici_fused_dispatch"))
         # the batch-of-1 fast lane's gate, snapshot at bind (options
-        # are final once start() ran; the A/B flips flags between
-        # server generations, never mid-listener)
+        # are final once start() ran; a flag flipped later takes effect
+        # with the next listener, never mid-listener)
         self._fused_inline1 = self._fused and bool(
             getattr(server.options, "usercode_inline", False))
         self._fcache: Dict[bytes, tuple] = {}   # mkey -> dispatch tuple
@@ -831,7 +815,7 @@ class ServerBinding:
         # dispatch-route truth (OBSERVABILITY.md): how many requests ran
         # the fused body vs the legacy chain on this listener — plain
         # ints bumped on the hot path (an Adder op per RPC is real µs),
-        # published by describe()/bench
+        # published by describe()
         self.fused_dispatched = 0
         self.legacy_dispatched = 0
         with _server_bindings_lock:
@@ -874,9 +858,9 @@ class ServerBinding:
         the queued counter counts BATCH CONTENTS, one per request, so
         the lame-duck drain gate sees each of them)."""
         # the idle/low-load fast lane: ONE fused inline request, no
-        # collector, no loop setup — the dominant shape on the echo
-        # bench (the snapshot below is taken at bind; options are
-        # final once the server started)
+        # collector, no loop setup — the dominant shape of a closed-loop
+        # echo (the snapshot below is taken at bind; options are final
+        # once the server started)
         if n == 1 and self._fused_inline1:
             try:
                 self._process_fused(reqs[0], None)
@@ -1864,23 +1848,17 @@ class ChannelBinding:
         self._names: Dict[str, bytes] = {}      # method encode cache
         self._tenants: Dict[str, bytes] = {}    # tenant encode cache
         self._tls = threading.local()           # reused IciCallOut
-        # native att custody (snapshot at init, like ServerBinding):
-        # call4 parks device-only response attachments under a handle
-        # and releases error-path segs natively — the client sheds its
-        # take-walks both ways
-        self._att_custody = bool(
-            _flags.get_flag("ici_native_att_custody"))
-        self._call3 = lib.brpc_tpu_ici_call4 if self._att_custody \
-            else lib.brpc_tpu_ici_call3         # bound once: attr-chain
+        # native att custody: call4 parks device-only response
+        # attachments under a handle and releases error-path segs
+        # natively — the client sheds its take-walks both ways
+        self._call4 = lib.brpc_tpu_ici_call4    # bound once: attr-chain
         self._free = lib.brpc_tpu_buf_free      # lookups are per-call
-        # fused client path (ISSUE 13), snapshot at connect like att
-        # custody: Channel.call_method routes sync calls through
-        # call_fused — the preamble/screen/issue/response chain as one
-        # flat code object.  Hot module handles resolve on first call
+        # fused client path (ISSUE 13), snapshot at connect:
+        # Channel.call_method routes sync calls through call_fused — the
+        # preamble/screen/issue/response chain as one flat code object.  Hot module handles resolve on first call
         # (the lazy import dance exists only for load-time cycles).
         self._fused = bool(_flags.get_flag("ici_fused_dispatch"))
-        self._callf = _fused_call_binding(self._att_custody) \
-            if self._fused else None
+        self._callf = _fused_call_binding() if self._fused else None
         self._hot = None
         from ..rpc import span as _span_mod
         self._rpcz_flag = _span_mod._rpcz_flag
@@ -1988,7 +1966,7 @@ class ChannelBinding:
         ls = _lspan.layer_begin("brpc.call.wait") \
             if _lspan.layer_on() else None
         try:
-            rc = self._call3(
+            rc = self._call4(
                 self._handle, name_b, reqb, len(req), attb,
                 len(att_host), seg_arr, len(segs), timeout_us, pri_wire,
                 tenant_b, int(tms) if tms is not None and tms > 0 else 0,
@@ -2002,15 +1980,9 @@ class ChannelBinding:
             cntl.remote_side = self.remote_side
             nsegs = out.nsegs
             if rc != 0:
-                if not self._att_custody:
-                    # native copies response segs to segs_out even when
-                    # the handler responded with an error: release their
-                    # device keys or they strand in the registry forever
-                    # (the exactly-one-exit custody invariant).  call4
-                    # releases them native-side — no walk at all.
-                    for i in range(nsegs):
-                        if out.segs[i].is_dev and out.segs[i].key:
-                            _registry.release(out.segs[i].key)
+                # response segs a failed handler shipped were released
+                # native-side by call4 (the exactly-one-exit custody
+                # invariant) — no walk here
                 text = ctypes.string_at(out.err_text).decode() \
                     if out.err_text else errors.berror(int(rc))
                 cntl.set_failed(int(rc), text)
@@ -2246,10 +2218,6 @@ class ChannelBinding:
                 cntl.remote_side = self.remote_side
                 nsegs = out.nsegs
                 if rc != 0:
-                    if not self._att_custody:
-                        for i in range(nsegs):
-                            if out.segs[i].is_dev and out.segs[i].key:
-                                _registry.release(out.segs[i].key)
                     text = _string_at(err_p, -1).decode() \
                         if err_p else errors.berror(int(rc))
                     cntl.set_failed(int(rc), text)
@@ -2347,7 +2315,7 @@ def native_ici_echo_p50_us(iters: int = 3000, payload: int = 128,
         # past the try/finally below (fablint custody true positive)
         nbytes = device_array.nbytes
         dev = _device_index(device_array)
-        key = _registry.put(device_array)    # borrowed for the bench
+        key = _registry.put(device_array)    # borrowed for the loop
     try:
         ns = lib.brpc_tpu_ici_echo_p50_ns(iters, payload, key, nbytes, dev)
         return ns / 1000.0 if ns > 0 else -1.0

@@ -27,7 +27,7 @@ from .collective import Collectives
 def ring_all_reduce(x, mesh: Optional[IciMesh] = None):
     """All-reduce (sum) of a (n, chunk...) sharded array via explicit ring
     hops.  Equivalent to ``Collectives.all_reduce`` but lowered as 2(n−1)
-    chained ppermutes — the chained-Send/Recv benchmark path.  Returns the
+    chained ppermutes — the chained-Send/Recv path.  Returns the
     summed value replicated as (n, chunk...) rows (row i = full sum of
     chunk i's shards … i.e. a reduce-scatter + all-gather pipeline)."""
     import jax

@@ -199,8 +199,8 @@ def record_collective(event: str, reason: str = "", n: int = 1) -> None:
 
 
 def collective_stats() -> dict:
-    """Snapshot {event_label: count} for /ici, bench extra, and the
-    tools' route assertions."""
+    """Snapshot {event_label: count} for /ici and the tools' route
+    assertions."""
     with _counters_lock:
         items = list(_events.items())
     return {label: a.get_value() for label, a in items}

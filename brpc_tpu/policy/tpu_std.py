@@ -115,16 +115,6 @@ def _enter_handler(start_ns: int, call_id: int):
     return opened
 
 
-def stage_p50s_us() -> dict:
-    """Per-stage p50s from the tpu_std_server_* recorders (µs) — the
-    BENCH `extra` decomposition (only meaningful after a run with
-    tpu_std_stage_metrics=on).  Reads the lifetime reservoir, not the
-    10s window: a short measurement pass finishes before the window
-    sampler's first tick."""
-    return {s: _stage_recorders[s]._percentile.get_value().get_number(0.5)
-            for s in _STAGES}
-
-
 class StdMessage:
     """A cut but not yet parsed frame.  ``recv_ns`` stamps the cut on
     the read loop — the queue-wait stage's start."""

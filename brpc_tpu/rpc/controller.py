@@ -79,7 +79,7 @@ class Controller:
     # Partition fan-out, the merged result, and which route actually
     # carried the call ("collective" = one compiled SPMD program,
     # "rpc" = the per-member loop, "" = not an operand fan-out) — the
-    # route assertion surface for bench/tools/tests
+    # route assertion surface for tools and tests
     fanout_operand: Any = None
     fanout_result: Any = None
     fanout_route: str = ""

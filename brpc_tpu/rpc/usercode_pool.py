@@ -13,8 +13,7 @@ same seam:
   (3.13t, GIL disabled) scales with plain threads; CPython ≥3.12 gives
   subinterpreters their own GIL; 3.8–3.11 subinterpreters are functional
   but SHARE the GIL (isolation without scaling — the capability record
-  says so and the bench leg SKIPs, the striped-shm precedent); anything
-  else falls back to the plain backup pool.
+  says so); anything else falls back to the plain backup pool.
 * **UsercodePool**: the backup ``ThreadPoolExecutor`` surface
   (``submit``/``shutdown``) stays byte-identical — regular handlers,
   queued-counter accounting, drain bounce, and admission ordering are
@@ -75,9 +74,8 @@ def probe_isolation() -> IsolationCaps:
     ``mode``: "free-threading" | "subinterp" | "subinterp-shared-gil" |
     "none".  ``functional`` — isolated registration/dispatch works;
     ``scaling`` — isolated handlers can actually run CPU concurrently
-    (the ≥2× bench acceptance needs this AND >1 core).  The record is
-    surfaced verbatim in /status and bench extra so a SKIP always
-    carries its reason."""
+    (scaling needs this AND >1 core).  The record is surfaced verbatim
+    in /status with its reason."""
     global _caps
     if _caps is not None:
         return _caps

@@ -121,7 +121,7 @@ _flags.define_flag(
     "demote pressure victims to the host arena tier instead of "
     "evicting them (pools built with host_blocks > 0).  False restores "
     "the PR-16 evict-on-pressure behavior byte-for-byte for same-run "
-    "A/B — the capacity-under-pressure bench leg flips exactly this")
+    "A/B")
 
 _flags.define_flag(
     "serving_kv_spill_reprobe_s", 0.25,

@@ -104,7 +104,7 @@ stats = _KvLoadStats()
 def kv_load_stats() -> dict:
     """{route: loads, copy_bytes, payload_bytes_by_route} — the /status
     serving block's ``kv_load`` field, rpc_press's serving summary, and
-    the bench/tests' per-call route assertion."""
+    the tests' per-call route assertion."""
     return stats.snapshot()
 
 

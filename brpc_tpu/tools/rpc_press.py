@@ -12,7 +12,7 @@ JSON request body, reporting qps/latency through bvar.  Usage:
 (``mesh://``, ``pod://name``, ``list://...``): one channel per resolved
 endpoint, workers spread round-robin, and the summary — including the
 graceful-SIGINT one — reports per-endpoint sent/error/qps counts, so a
-pod/overload bench can drive N servers from one process and see which
+pod or overload run can drive N servers from one process and see which
 member misbehaved.
 
 Mixed-class load (the admission-control adversary): ``--priority`` takes
@@ -570,7 +570,7 @@ def run_press(server: str, method: str, request_json: str,
     sent = [0]
     per_ep = {t: {"sent": 0, "errors": 0} for t in targets}
     # per (priority, tenant) class: sent / shed (ELIMIT) / errors /
-    # latency recorder — the overload bench's fairness view
+    # latency recorder — an overload run's fairness view
     per_class: dict = {}
     lock = threading.Lock()
     deadline = time.monotonic() + duration

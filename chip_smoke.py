@@ -510,7 +510,7 @@ def run_one_chip(rng) -> None:
 # four chips (--multichip): the cross-chip path and its plain references
 # ---------------------------------------------------------------------------
 
-def phase_cross_device_echo(rng, mesh, kernels) -> None:
+def phase_cross_device_echo(rng, mesh) -> None:
     """Server on ici://1, caller on device 0: the request relocates 0->1
     and the response 1->0 through the device plane's compiled transfer
     program; compared with a plain jax.device_put of the same bytes."""
@@ -532,7 +532,6 @@ def phase_cross_device_echo(rng, mesh, kernels) -> None:
             done()
 
     plane = dp.plane()
-    saved_kernel = fl.get_flag("ici_device_plane_kernel")
     saved_threshold = fl.get_flag("ici_device_plane_threshold")
     fl.set_flag("ici_device_plane_threshold", 4 * KB)   # 4 KB rides it too
     server = rpc.Server()
@@ -542,80 +541,76 @@ def phase_cross_device_echo(rng, mesh, kernels) -> None:
         ch = rpc.Channel()
         ch.init("ici://1", options=rpc.ChannelOptions(
             timeout_ms=600000, max_retry=0, ici_local_device=0))
-        for kernel in kernels:
-            fl.set_flag("ici_device_plane_kernel", kernel)
-            for nbytes, calls in XCHIP_SWEEP:
-                host, x = device_payload(rng, nbytes, d0)
-                before = plane.stats()
-                lat = []
-                for i in range(calls):
-                    cntl = rpc.Controller()
-                    cntl.request_attachment.append_device_array(x)
-                    t0 = time.perf_counter()
-                    ch.call_method("EchoService.Echo", cntl,
-                                   EchoRequest(message="x"), EchoResponse)
-                    lat.append(time.perf_counter() - t0)
-                    check(not cntl.failed(),
-                          f"xchip echo {kernel} {fmt_bytes(nbytes)}: "
-                          f"{cntl.error_text}")
-                    check(all(d == {d1} for d in seen["devices"])
-                          and seen["devices"],
-                          f"handler saw the attachment on "
-                          f"{seen['devices']}, expected {d1}")
-                    got = attachment_to_host(cntl.response_attachment,
-                                             want_devices=[d0])
-                    check(np.array_equal(got, host),
-                          f"xchip echo {kernel} {fmt_bytes(nbytes)} call "
-                          f"{i}: bytes differ")
-                after = plane.stats()
-                moved = after["transfers"] - before["transfers"]
-                check(moved >= 2 * calls,
-                      f"device plane ran {moved} transfers for {calls} "
-                      f"cross-chip echoes")
-                # the plain reference: the same bytes by device_put
+        for nbytes, calls in XCHIP_SWEEP:
+            host, x = device_payload(rng, nbytes, d0)
+            before = plane.stats()
+            lat = []
+            for i in range(calls):
+                cntl = rpc.Controller()
+                cntl.request_attachment.append_device_array(x)
                 t0 = time.perf_counter()
-                ref = jax.device_put(x, d1)
-                back = jax.device_put(ref, d0)
-                jax.block_until_ready(back)
-                ref_s = time.perf_counter() - t0
-                check(set(ref.devices()) == {d1}
-                      and np.array_equal(np.asarray(back), host),
-                      "device_put reference differs")
-                # the send window cuts a frame above it into pieces, so
-                # the programs that ran are keyed by the PIECE sizes
-                sizes = sorted({k[1] for k in plane._programs
-                                if k[4] == kernel})
-                lat.sort()
-                say(f"[xchip-echo] kernel={kernel} {fmt_bytes(nbytes):>6} "
-                    f"x{calls}: 0->1->0 byte-exact, handler on {d1}, "
-                    f"reply on {d0}, {moved} device-plane transfers "
-                    f"(program sizes so far: "
-                    f"{[fmt_bytes(b) for b in sizes]}); smoke timing rpc "
-                    f"median {lat[len(lat) // 2] * 1e3:.2f}ms vs "
-                    f"device_put round trip {ref_s * 1e3:.2f}ms")
-                # the whole payload as ONE posted work request: the
-                # transfer program at the attachment's full size
-                t0 = time.perf_counter()
-                t = plane.transfer_local(x, 0, 1)
-                check(t.wait(120) == 0, f"whole-payload transfer: {t.error}")
-                whole_s = time.perf_counter() - t0
-                check(set(t.out.devices()) == {d1}
-                      and np.array_equal(np.asarray(t.out), host),
-                      f"whole-payload {kernel} transfer differs")
-                ma = plane._program(nbytes, nbytes, 0, 1)[0].memory_analysis()
-                say(f"[xchip-plane] kernel={kernel} {fmt_bytes(nbytes):>6} "
-                    f"as one work request 0->1: byte-exact on {d1}; "
-                    f"program argument={fmt_bytes(ma.argument_size_in_bytes)} "
-                    f"output={fmt_bytes(ma.output_size_in_bytes)} "
-                    f"temp={fmt_bytes(ma.temp_size_in_bytes)} per chip "
-                    f"(argument/payload = "
-                    f"{ma.argument_size_in_bytes / nbytes:g}x); "
-                    f"smoke timing of the post {whole_s * 1e3:.2f}ms "
-                    f"(compile inside)")
-                del t
-                del x, host
+                ch.call_method("EchoService.Echo", cntl,
+                               EchoRequest(message="x"), EchoResponse)
+                lat.append(time.perf_counter() - t0)
+                check(not cntl.failed(),
+                      f"xchip echo {fmt_bytes(nbytes)}: "
+                      f"{cntl.error_text}")
+                check(all(d == {d1} for d in seen["devices"])
+                      and seen["devices"],
+                      f"handler saw the attachment on "
+                      f"{seen['devices']}, expected {d1}")
+                got = attachment_to_host(cntl.response_attachment,
+                                         want_devices=[d0])
+                check(np.array_equal(got, host),
+                      f"xchip echo {fmt_bytes(nbytes)} call {i}: "
+                      f"bytes differ")
+            after = plane.stats()
+            moved = after["transfers"] - before["transfers"]
+            check(moved >= 2 * calls,
+                  f"device plane ran {moved} transfers for {calls} "
+                  f"cross-chip echoes")
+            # the plain reference: the same bytes by device_put
+            t0 = time.perf_counter()
+            ref = jax.device_put(x, d1)
+            back = jax.device_put(ref, d0)
+            jax.block_until_ready(back)
+            ref_s = time.perf_counter() - t0
+            check(set(ref.devices()) == {d1}
+                  and np.array_equal(np.asarray(back), host),
+                  "device_put reference differs")
+            # the send window cuts a frame above it into pieces, so
+            # the programs that ran are keyed by the PIECE sizes
+            sizes = sorted({k[1] for k in plane._programs})
+            lat.sort()
+            say(f"[xchip-echo] {fmt_bytes(nbytes):>6} "
+                f"x{calls}: 0->1->0 byte-exact, handler on {d1}, "
+                f"reply on {d0}, {moved} device-plane transfers "
+                f"(program sizes so far: "
+                f"{[fmt_bytes(b) for b in sizes]}); smoke timing rpc "
+                f"median {lat[len(lat) // 2] * 1e3:.2f}ms vs "
+                f"device_put round trip {ref_s * 1e3:.2f}ms")
+            # the whole payload as ONE posted work request: the
+            # transfer program at the attachment's full size
+            t0 = time.perf_counter()
+            t = plane.transfer_local(x, 0, 1)
+            check(t.wait(120) == 0, f"whole-payload transfer: {t.error}")
+            whole_s = time.perf_counter() - t0
+            check(set(t.out.devices()) == {d1}
+                  and np.array_equal(np.asarray(t.out), host),
+                  "whole-payload transfer differs")
+            ma = plane._program(nbytes, nbytes, 0, 1)[0].memory_analysis()
+            say(f"[xchip-plane] {fmt_bytes(nbytes):>6} "
+                f"as one work request 0->1: byte-exact on {d1}; "
+                f"program argument={fmt_bytes(ma.argument_size_in_bytes)} "
+                f"output={fmt_bytes(ma.output_size_in_bytes)} "
+                f"temp={fmt_bytes(ma.temp_size_in_bytes)} per chip "
+                f"(argument/payload = "
+                f"{ma.argument_size_in_bytes / nbytes:g}x); "
+                f"smoke timing of the post {whole_s * 1e3:.2f}ms "
+                f"(compile inside)")
+            del t
+            del x, host
     finally:
-        fl.set_flag("ici_device_plane_kernel", saved_kernel)
         fl.set_flag("ici_device_plane_threshold", saved_threshold)
         server.stop()
     st = plane.stats()
@@ -782,10 +777,9 @@ def run_multichip(rng) -> None:
     # XLA-scheduled programs first, the hand-scheduled Pallas kernels
     # last: what the compiler schedules is on record before a kernel of
     # ours gets the chance to wedge a DMA
-    phase_cross_device_echo(rng, mesh, ("ppermute",))
+    phase_cross_device_echo(rng, mesh)
     phase_all_reduce(mesh)
     phase_fanout(mesh)
-    phase_cross_device_echo(rng, mesh, ("pallas",))
     phase_pallas_ring(mesh)
 
 

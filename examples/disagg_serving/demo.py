@@ -9,7 +9,7 @@ crossed the device plane, the tokens must be bit-identical.
 
 For the cross-process (pod) flavor — every worker its own process, KV
 blocks crossing the fabric's sequenced device plane — see README.md and
-bench.py's ``pod_prefill_decode`` tier.
+tests/test_pod.py.
 """
 from __future__ import annotations
 

@@ -25,7 +25,7 @@ attachments), but the decode side is now the REAL serving subsystem
     from the step loop when the session's tokens are done, so N
     concurrent sessions share each step instead of serializing.
     ``{"mode": "sync"}`` keeps the old one-RPC-one-shot path (the
-    bench's A/B baseline).
+    baseline the tests compare the batched path with).
   * **RouterService** (``Generate``): the front door — prefill via any
     LB channel, decode worker chosen by the LALB divided-weight
     balancer (:class:`~brpc_tpu.serving.LoadAwareRouter`): every decode
@@ -410,7 +410,7 @@ class DecodeService(rpc.Service):
 
     def _decode_sync(self, cntl, session, steps, release, response,
                      done) -> None:
-        """The pre-batching one-RPC-one-shot path (bench A/B baseline):
+        """The pre-batching one-RPC-one-shot path (the tests' baseline):
         read the session out of the pool and decode inline.  The read
         is a zero-copy VIEW when the session's blocks are one
         contiguous extent (the ISSUE-15 materialize bugfix) — pinned

@@ -87,7 +87,6 @@ uint64_t brpc_tpu_ici_listen_batch(int32_t dev,
                                    void (*fn)(const IciReqC*, uint64_t));
 int brpc_tpu_ici_set_batch_params(uint64_t h, int64_t max_batch,
                                   int64_t age_us);
-int brpc_tpu_ici_set_att_handles(uint64_t h, int on);
 int brpc_tpu_ici_batch_stats(uint64_t h, uint64_t* upcalls,
                              uint64_t* requests, uint64_t* max_batch);
 int brpc_tpu_ici_respond_batch(const IciRespC* rs, uint64_t n);
@@ -230,7 +229,6 @@ void att_custody_smoke() {
   uint64_t sh = brpc_tpu_ici_listen_batch(78, att_batch_handler);
   assert(sh != 0);
   brpc_tpu_ici_set_batch_params(sh, 8, 1);
-  assert(brpc_tpu_ici_set_att_handles(sh, 1) == 0);
   std::atomic<uint64_t> next_key{1000};
   std::atomic<uint64_t> keys_issued{0}, keys_taken{0};
   std::atomic<int> errs{0};

@@ -1732,13 +1732,6 @@ class IciServer : public std::enable_shared_from_this<IciServer> {
       batch_age_ns_.store(age_us * 1000, std::memory_order_relaxed);
   }
 
-  // Opt the batched upcall into native att custody (IciReqC.att_handle):
-  // OFF by default so an older Python tier on a newer .so keeps the
-  // take-during-upcall semantics byte-for-byte.
-  void set_att_handles(bool on) {
-    att_handles_.store(on, std::memory_order_relaxed);
-  }
-
   void batch_stats(uint64_t* upcalls, uint64_t* requests,
                    uint64_t* max_batch) const {
     *upcalls = upcalls_.load(std::memory_order_relaxed);
@@ -1951,7 +1944,6 @@ class IciServer : public std::enable_shared_from_this<IciServer> {
     }
     std::vector<IciReqC> reqs;
     reqs.reserve(batch.size());
-    bool handles = att_handles_.load(std::memory_order_relaxed);
     for (auto& it : batch) {
       const uint8_t* base = (const uint8_t*)it.bytes.data();
       IciReqC r;
@@ -1974,7 +1966,7 @@ class IciServer : public std::enable_shared_from_this<IciServer> {
       r.seg0_nbytes = 0;
       r.seg0_dev = 0;
       r._pad3 = 0;
-      if (handles && it.att_len == 0 && !it.segs.empty()) {
+      if (it.att_len == 0 && !it.segs.empty()) {
         // native custody: the seg list PARKS in the att table; Python
         // receives a ready handle + an inline mirror of segs[0] and
         // never walks the list on the hot path.  Host-mixed
@@ -2037,7 +2029,6 @@ class IciServer : public std::enable_shared_from_this<IciServer> {
   bool bq_stopped_ = false;
   std::atomic<uint64_t> batch_max_{64};
   std::atomic<int64_t> batch_age_ns_{50 * 1000};   // ~50 us steal bound
-  std::atomic<bool> att_handles_{false};   // native att custody opt-in
   std::atomic<uint64_t> upcalls_{0};
   std::atomic<uint64_t> upcall_reqs_{0};
   std::atomic<uint64_t> batch_max_seen_{0};
@@ -2697,7 +2688,7 @@ int64_t brpc_tpu_ici_window_left(uint64_t h) {
   return it->second.second->window_left;
 }
 
-// Single-output-struct out-block for the unary ici call (see call2/call3):
+// Single-output-struct out-block for the unary ici call (see call2/call4):
 // one reusable pointer instead of seven byref temporaries.
 struct IciCallOut {
   uint8_t* resp;
@@ -2801,27 +2792,8 @@ static uint64_t ici_call_fill(uint64_t h, const char* method,
   return rc;
 }
 
-// Legacy 17-argument ABI (kept for existing callers; no admission meta).
-uint64_t brpc_tpu_ici_call(uint64_t h, const char* method,
-                           const uint8_t* req, uint64_t req_len,
-                           const uint8_t* att_host, uint64_t att_host_len,
-                           const nrpc::IciSegC* segs, uint64_t nsegs,
-                           int64_t timeout_us, uint8_t** resp_out,
-                           uint64_t* resp_len, uint8_t** att_out,
-                           uint64_t* att_out_len,
-                           nrpc::IciSegC** segs_out, uint64_t* nsegs_out,
-                           char** err_text_out) {
-  IciCallOut o;
-  uint64_t rc = ici_call_fill(h, method, req, req_len, att_host,
-                              att_host_len, segs, nsegs, timeout_us, 0,
-                              nullptr, 0, &o);
-  *resp_out = o.resp; *resp_len = o.resp_len;
-  *att_out = o.att; *att_out_len = o.att_len;
-  *segs_out = o.segs; *nsegs_out = o.nsegs;
-  *err_text_out = o.err_text;
-  return rc;
-}
-
+// The smoke's entry (native/ici_smoke.cpp): no admission meta, owned seg
+// copies on the response.
 uint64_t brpc_tpu_ici_call2(uint64_t h, const char* method,
                             const uint8_t* req, uint64_t req_len,
                             const uint8_t* att_host, uint64_t att_host_len,
@@ -2831,25 +2803,13 @@ uint64_t brpc_tpu_ici_call2(uint64_t h, const char* method,
                        segs, nsegs, timeout_us, 0, nullptr, 0, out);
 }
 
-// call2 + admission-control metadata: wire-encoded priority (0 = unset,
+// call2 + admission-control metadata — wire-encoded priority (0 = unset,
 // 1..N = band 0..N-1), tenant, and the sender's remaining deadline
-// budget.  out->retry_after_ms carries the shed hint back on ELIMIT.
-uint64_t brpc_tpu_ici_call3(uint64_t h, const char* method,
-                            const uint8_t* req, uint64_t req_len,
-                            const uint8_t* att_host, uint64_t att_host_len,
-                            const nrpc::IciSegC* segs, uint64_t nsegs,
-                            int64_t timeout_us, int64_t priority_wire,
-                            const char* tenant, int64_t deadline_left_ms,
-                            IciCallOut* out) {
-  return ici_call_fill(h, method, req, req_len, att_host, att_host_len,
-                       segs, nsegs, timeout_us, priority_wire, tenant,
-                       deadline_left_ms, out);
-}
-
-// call3 + native att custody on the RESPONSE: device-only response
-// attachments come back as out->att_handle (+ seg0 inline; >1 segs
-// also malloc'd as metadata) instead of owned seg copies the caller
-// must walk and take.  Error-path response segs are released natively.
+// budget; out->retry_after_ms carries the shed hint back on ELIMIT — and
+// native att custody on the RESPONSE: device-only response attachments
+// come back as out->att_handle (+ seg0 inline; >1 segs also malloc'd as
+// metadata) instead of owned seg copies the caller must walk and take.
+// Error-path response segs are released natively.
 uint64_t brpc_tpu_ici_call4(uint64_t h, const char* method,
                             const uint8_t* req, uint64_t req_len,
                             const uint8_t* att_host, uint64_t att_host_len,
@@ -2905,15 +2865,6 @@ int64_t brpc_tpu_ici_att_peek(uint64_t handle, nrpc::IciSegC* out,
 uint64_t brpc_tpu_ici_att_count() {
   std::lock_guard<std::mutex> g(nrpc::g_ici_atts_mu);
   return nrpc::g_ici_atts.size();
-}
-
-// Opt a listener's batched upcall into IciReqC.att_handle delivery.
-int brpc_tpu_ici_set_att_handles(uint64_t h, int on) {
-  std::lock_guard<std::mutex> g(nrpc::g_ici_mu);
-  auto it = nrpc::g_ici_servers.find(h);
-  if (it == nrpc::g_ici_servers.end()) return -1;
-  it->second->set_att_handles(on != 0);
-  return 0;
 }
 
 // Respond to a Python-handled ici request.  Custody of `segs` keys
@@ -3068,159 +3019,6 @@ int64_t brpc_tpu_ici_echo_p50_ns(int iters, int payload_len,
   return lat[lat.size() / 2];
 }
 
-// Large-request throughput, 1 client → 1 server (the reference's headline
-// "2.3 GB/s pooled large messages" config, docs/cn/benchmark.md:104).
-// `threads` concurrent callers on separate connections keep the pipe
-// full; reported number counts request payload bytes only (matching the
-// reference, which measures request throughput).
-double brpc_tpu_native_rpc_throughput_gbps(int threads, int duration_ms,
-                                           int payload_len) {
-  uint64_t sh = brpc_tpu_nserver_start(0);
-  if (sh == 0) return -1.0;
-  brpc_tpu_nserver_register_echo(sh, "EchoService.Echo");
-  int port = brpc_tpu_nserver_port(sh);
-  std::atomic<uint64_t> bytes{0};
-  std::atomic<bool> stop{false};
-  std::vector<std::thread> ts;
-  for (int t = 0; t < threads; ++t) {
-    ts.emplace_back([&] {
-      uint64_t ch = brpc_tpu_nchannel_connect("127.0.0.1", port);
-      if (ch == 0) return;
-      auto c = nrpc::find_channel(ch);
-      std::string payload(payload_len, 'x');
-      while (!stop.load(std::memory_order_relaxed)) {
-        nrpc::CallResult out;
-        std::string err;
-        uint64_t rc = c->call("EchoService.Echo", payload.data(),
-                              payload.size(), nullptr, 0, 30 * 1000 * 1000,
-                              &out, &err);
-        if (rc == 0)
-          bytes.fetch_add(payload.size(), std::memory_order_relaxed);
-      }
-      brpc_tpu_nchannel_close(ch);
-    });
-  }
-  auto t0 = std::chrono::steady_clock::now();
-  std::this_thread::sleep_for(std::chrono::milliseconds(duration_ms));
-  stop.store(true);
-  for (auto& th : ts) th.join();
-  double secs = std::chrono::duration<double>(
-                    std::chrono::steady_clock::now() - t0)
-                    .count();
-  brpc_tpu_nserver_stop(sh);
-  return bytes.load() / secs / 1e9;
-}
-
-// Pipelined large-request throughput: ONE connection, `depth` requests
-// in flight via the async API — the KeepWrite batching shape
-// (socket.cpp:1685): the writer never waits for a response before
-// sending the next request, so there is no ping-pong bubble.
-double brpc_tpu_native_async_throughput_gbps(int depth, int duration_ms,
-                                             int payload_len) {
-  uint64_t sh = brpc_tpu_nserver_start(0);
-  if (sh == 0) return -1.0;
-  brpc_tpu_nserver_register_echo(sh, "EchoService.Echo");
-  int port = brpc_tpu_nserver_port(sh);
-  uint64_t ch = brpc_tpu_nchannel_connect("127.0.0.1", port);
-  if (ch == 0) {
-    brpc_tpu_nserver_stop(sh);
-    return -1.0;
-  }
-  auto c = nrpc::find_channel(ch);
-  struct Ctl {
-    std::mutex mu;
-    std::condition_variable cv;
-    int inflight = 0;
-    uint64_t bytes = 0;
-    uint64_t errors = 0;
-  } ctl;
-  auto cb = +[](void* user, uint64_t err, const char*, const uint8_t*,
-                uint64_t resp_len, const uint8_t*, uint64_t) {
-    Ctl* ctl = (Ctl*)user;
-    std::lock_guard<std::mutex> g(ctl->mu);
-    ctl->inflight--;
-    if (err == 0) ctl->bytes += resp_len;
-    else ctl->errors++;
-    ctl->cv.notify_all();
-  };
-  std::string payload(payload_len, 'x');
-  auto t0 = std::chrono::steady_clock::now();
-  auto stop_at = t0 + std::chrono::milliseconds(duration_ms);
-  while (std::chrono::steady_clock::now() < stop_at) {
-    {
-      std::unique_lock<std::mutex> g(ctl.mu);
-      nbase::cv_wait_for(ctl.cv, g, std::chrono::milliseconds(100),
-                         [&] { return ctl.inflight < depth; });
-      if (ctl.inflight >= depth) continue;
-      ctl.inflight++;
-    }
-    c->call_async("EchoService.Echo", payload.data(), payload.size(),
-                  nullptr, 0, 30 * 1000 * 1000, cb, &ctl);
-  }
-  {
-    std::unique_lock<std::mutex> g(ctl.mu);
-    nbase::cv_wait_for(ctl.cv, g, std::chrono::seconds(30),
-                       [&] { return ctl.inflight == 0; });
-  }
-  double secs = std::chrono::duration<double>(
-                    std::chrono::steady_clock::now() - t0)
-                    .count();
-  uint64_t bytes;
-  {
-    std::lock_guard<std::mutex> g(ctl.mu);
-    bytes = ctl.bytes;
-  }
-  brpc_tpu_nchannel_close(ch);
-  brpc_tpu_nserver_stop(sh);
-  return bytes / secs / 1e9;
-}
-
-// Pooled large-request throughput: `threads` callers sharing ONE pool of
-// `nconns` connections (round-robin per call) — the reference's pooled
-// 2.3 GB/s configuration, docs/cn/benchmark.md:104.
-double brpc_tpu_native_pooled_throughput_gbps(int nconns, int threads,
-                                              int duration_ms,
-                                              int payload_len) {
-  uint64_t sh = brpc_tpu_nserver_start(0);
-  if (sh == 0) return -1.0;
-  brpc_tpu_nserver_register_echo(sh, "EchoService.Echo");
-  int port = brpc_tpu_nserver_port(sh);
-  uint64_t ph = brpc_tpu_npool_connect("127.0.0.1", port, nconns);
-  if (ph == 0) {
-    brpc_tpu_nserver_stop(sh);
-    return -1.0;
-  }
-  auto pool = nrpc::find_pool(ph);
-  std::atomic<uint64_t> bytes{0};
-  std::atomic<bool> stop{false};
-  std::vector<std::thread> ts;
-  for (int t = 0; t < threads; ++t) {
-    ts.emplace_back([&] {
-      std::string payload(payload_len, 'x');
-      while (!stop.load(std::memory_order_relaxed)) {
-        auto c = pool->pick();
-        nrpc::CallResult out;
-        std::string err;
-        uint64_t rc = c->call("EchoService.Echo", payload.data(),
-                              payload.size(), nullptr, 0, 30 * 1000 * 1000,
-                              &out, &err);
-        if (rc == 0)
-          bytes.fetch_add(payload.size(), std::memory_order_relaxed);
-      }
-    });
-  }
-  auto t0 = std::chrono::steady_clock::now();
-  std::this_thread::sleep_for(std::chrono::milliseconds(duration_ms));
-  stop.store(true);
-  for (auto& th : ts) th.join();
-  double secs = std::chrono::duration<double>(
-                    std::chrono::steady_clock::now() - t0)
-                    .count();
-  brpc_tpu_npool_close(ph);
-  brpc_tpu_nserver_stop(sh);
-  return bytes.load() / secs / 1e9;
-}
-
 }  // extern "C"
 
 #else  // !__linux__
@@ -3248,7 +3046,6 @@ void brpc_tpu_buf_free(void* p) { free(p); }
 void brpc_tpu_nchannel_close(uint64_t) {}
 int64_t brpc_tpu_native_rpc_echo_p50_ns(int, int) { return -1; }
 double brpc_tpu_native_rpc_qps(int, int, int) { return -1.0; }
-double brpc_tpu_native_rpc_throughput_gbps(int, int, int) { return -1.0; }
 void brpc_tpu_ici_set_hooks(void*, void*) {}
 uint64_t brpc_tpu_ici_listen(int32_t, void*) { return 0; }
 int brpc_tpu_ici_register_echo(uint64_t, const char*) { return -1; }
@@ -3259,21 +3056,9 @@ void brpc_tpu_ici_unlisten(uint64_t) {}
 uint64_t brpc_tpu_ici_connect(int32_t, int32_t, int64_t) { return 0; }
 void brpc_tpu_ici_close(uint64_t) {}
 int64_t brpc_tpu_ici_window_left(uint64_t) { return -1; }
-uint64_t brpc_tpu_ici_call(uint64_t, const char*, const uint8_t*, uint64_t,
-                           const uint8_t*, uint64_t, const void*, uint64_t,
-                           int64_t, uint8_t**, uint64_t*, uint8_t**,
-                           uint64_t*, void**, uint64_t*, char**) {
-  return 1009;
-}
 uint64_t brpc_tpu_ici_call2(uint64_t, const char*, const uint8_t*,
                             uint64_t, const uint8_t*, uint64_t,
                             const void*, uint64_t, int64_t, void*) {
-  return 1009;
-}
-uint64_t brpc_tpu_ici_call3(uint64_t, const char*, const uint8_t*,
-                            uint64_t, const uint8_t*, uint64_t,
-                            const void*, uint64_t, int64_t, int64_t,
-                            const char*, int64_t, void*) {
   return 1009;
 }
 uint64_t brpc_tpu_ici_call4(uint64_t, const char*, const uint8_t*,
@@ -3286,7 +3071,6 @@ int64_t brpc_tpu_ici_att_take(uint64_t) { return -1; }
 int brpc_tpu_ici_att_dispose(uint64_t) { return -1; }
 int64_t brpc_tpu_ici_att_peek(uint64_t, void*, uint64_t) { return -1; }
 uint64_t brpc_tpu_ici_att_count() { return 0; }
-int brpc_tpu_ici_set_att_handles(uint64_t, int) { return -1; }
 int brpc_tpu_ici_respond(uint64_t, uint64_t, const char*, const uint8_t*,
                          uint64_t, const uint8_t*, uint64_t, const void*,
                          uint64_t) { return -1; }
@@ -3309,10 +3093,6 @@ uint64_t brpc_tpu_npool_call(uint64_t, const char*, const uint8_t*,
                              uint8_t**, uint64_t*, uint8_t**, uint64_t*,
                              char**) { return 1009; }
 void brpc_tpu_npool_close(uint64_t) {}
-double brpc_tpu_native_pooled_throughput_gbps(int, int, int, int) {
-  return -1.0;
-}
-double brpc_tpu_native_async_throughput_gbps(int, int, int) { return -1.0; }
 }
 
 #endif

@@ -37,7 +37,7 @@ assert len(jax.devices()) >= 8, (
 
 # ---- seeded port / UDS-path allocator ----------------------------------
 #
-# N-process tests (the chaos harness, the pod suite, the fabric bench)
+# N-process tests (the chaos harness, the pod suite, the fabric suite)
 # need coordinator ports and unix-socket paths that (a) are DETERMINISTIC
 # per test — a failure reproduces with the same addresses — and (b) can't
 # collide when several pytest processes run the same suite on one host

@@ -6,8 +6,8 @@ shed-exclusion bugfix in MethodStatus.
 
 The deterministic mini-overload test (TestMiniOverload, `overload`
 marker) drives the whole shed logic with a SIMULATED clock and an
-injectable service rate, so tier-1 exercises it without the full
-`bench.py --sub overload` adversary.
+injectable service rate, so tier-1 exercises it without a full
+10x-capacity adversary (which has no cell yet: ROADMAP Queue 2, C6).
 """
 from __future__ import annotations
 

@@ -32,7 +32,7 @@ def plane_on():
     restoring every flag after."""
     saved = {n: fl.get_flag(n) for n in
              ("ici_device_plane", "ici_device_plane_host_mesh",
-              "ici_device_plane_threshold", "ici_device_plane_kernel",
+              "ici_device_plane_threshold",
               "ici_device_plane_match_timeout_s")}
     fl.set_flag("ici_device_plane", True)
     fl.set_flag("ici_device_plane_host_mesh", True)
@@ -120,18 +120,6 @@ class TestProgramCache:
         assert t.wait(30) == 0
         assert plane.stats()["program_cache_misses"] == misses0 + 2
 
-    def test_pallas_remote_dma_kernel_variant(self, plane_on):
-        """The hand-scheduled make_async_remote_copy kernel (interpret
-        mode on this CPU mesh — the exact TPU control flow)."""
-        plane = plane_on
-        fl.set_flag("ici_device_plane_kernel", "pallas")
-        arr = _payload(2048, 2)
-        t = plane.post_send(arr, 2, 6)
-        plane.post_recv(t.uuid)
-        assert t.wait(60) == 0
-        np.testing.assert_array_equal(np.asarray(t.out), np.asarray(arr))
-        assert dp.mesh_index_of(t.out) == 6
-
 
 class TestPieceOfABlock:
     """The transfer program cuts the piece on the chip: the post carries
@@ -218,21 +206,6 @@ class TestPieceOfABlock:
             np.asarray(t.out), np.asarray(block)[25:25 + self.PIECE])
         assert plane_on.stats()["fallbacks"] == f0 + 1
         assert plane_on.stats()["sliced_in_program"] == s0
-
-    def test_pallas_kernel_is_posted_a_host_cut_piece(self, plane_on):
-        """Mosaic refuses a DMA source at an unaligned dynamic start, so
-        that kernel's piece is cut on the host and crosses as a whole
-        array — counted as a transfer, not as sliced in the program."""
-        fl.set_flag("ici_device_plane_kernel", "pallas")
-        block = _payload(self.BLOCK, 2)
-        before = plane_on.stats()
-        t = self._cross(plane_on, block, 25, 2048, src=2, dst=6)
-        assert (t.start, t.block_bytes) == (0, 2048)
-        np.testing.assert_array_equal(np.asarray(t.out),
-                                      np.asarray(block)[25:25 + 2048])
-        after = plane_on.stats()
-        assert after["transfers"] == before["transfers"] + 1
-        assert after["sliced_in_program"] == before["sliced_in_program"]
 
     @pytest.mark.parametrize("start,nbytes", [(-1, 1024), (1, 64 * 1024),
                                               (64 * 1024, 1024)])

@@ -3,8 +3,7 @@
 In-process flavor on the virtual mesh: prefill and decode workers on
 different mesh devices, the KV-cache handoff crossing the device plane,
 tokens verified bit-exact against the single-process reference.  The
-cross-process (pod) flavor is exercised by tests/test_pod.py and the
-``pod_prefill_decode`` bench tier.
+cross-process (pod) flavor is exercised by tests/test_pod.py.
 """
 import json
 

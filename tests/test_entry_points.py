@@ -1,9 +1,8 @@
 """The entry points' process discipline: one process per chip, a compile
 cache that can be placed from outside, no failure swallowed into exit 0.
 
-Everything that must not leak into the test process (a jax config change,
-``import bench``) runs in a child pinned to the CPU."""
-import json
+Everything that must not leak into the test process (a jax config
+change) runs in a child pinned to the CPU."""
 import os
 import subprocess
 import sys
@@ -54,52 +53,6 @@ def test_importing_the_library_sets_no_cache():
     proc = _child("import jax, brpc_tpu, brpc_tpu.ici, brpc_tpu.rpc\n"
                   "assert jax.config.jax_compilation_cache_dir is None\n")
     assert proc.returncode == 0, proc.stderr[-2000:]
-
-
-def test_bench_parent_stays_off_jax_and_names_a_failed_tier():
-    """bench.main() orchestrates children and never imports jax; a tier
-    that fails is named, the headline is "not measured" (no stand-in from
-    another tier) and the exit code is non-zero."""
-    code = (
-        "import json, sys\n"
-        "import bench\n"
-        "def tier(name, failed):\n"
-        "    if name == 'echo':\n"
-        "        failed.append(name)\n"
-        "        return {}\n"
-        "    if name == 'native':\n"
-        "        return {'rpc_p50_us': 9.0, 'device': 'host'}\n"
-        "    return {}\n"
-        "bench._run_tier = tier\n"
-        "rc = bench.main()\n"
-        "assert 'jax' not in sys.modules, 'the parent imported jax'\n"
-        "sys.exit(rc)\n")
-    proc = _child(code)
-    assert proc.returncode == 1, proc.stderr[-2000:]
-    out = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert out["failed_tiers"] == ["echo"]
-    assert out["value"] is None and "not measured" in out["metric"]
-    assert out["extra"]["native_tcp_echo_p50_us"] == 9.0
-    assert "FAILED tiers: echo" in proc.stderr
-
-
-def test_bench_mesh_tier_on_one_device_is_not_measured():
-    """No re-run on a virtual CPU mesh: a tier that needs two devices
-    says "not measured" on a one-device host, and names that device."""
-    proc = _run(["bench.py", "--sub", "relocation"])
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    out = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert "not_measured" in out
-    assert out["device"] == {"platform": "cpu", "kind": "cpu", "count": 1}
-
-
-def test_bench_tier_that_produces_nothing_fails_its_child():
-    code = ("import sys, bench\n"
-            "bench._TIERS['qps'] = (lambda: {}, {})\n"
-            "bench._run_sub('qps')\n")
-    proc = _child(code)
-    assert proc.returncode != 0
-    assert "produced no result" in proc.stderr
 
 
 def test_chip_smoke_refuses_to_pass_off_the_chip():

@@ -34,6 +34,7 @@ EXAMPLES = [
     "examples.session_data_and_thread_local",
     "examples.multi_threaded_echo_fns",
     "examples.rtmp_relay",
+    "examples.allreduce_performance",
 ]
 
 
@@ -42,5 +43,9 @@ def test_example_runs(mod_name, capsys):
     mod = importlib.import_module(mod_name)
     if mod_name == "examples.multi_threaded_echo":
         mod.main(threads=4, seconds=0.5)
+    elif mod_name == "examples.allreduce_performance":
+        mod.main(size_mb=1)
+        out = capsys.readouterr().out
+        assert "xla psum" in out and "explicit ring" in out
     else:
         mod.main()

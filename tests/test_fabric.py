@@ -662,7 +662,7 @@ else:
     stream.close()
     print("FABRIC_STREAM_MBPS %%.1f best_of=%%d" %% (best, PASSES),
           flush=True)
-    # which fast plane carried the DATA payloads (bench route assertion)
+    # which fast plane carried the DATA payloads (route assertion)
     from brpc_tpu.ici.fabric import FabricSocket
     from brpc_tpu.rpc.socket import list_sockets
     shm_b = sum(s.shm_bytes_sent for s in list_sockets()
@@ -827,7 +827,7 @@ def test_streaming_falls_back_inline_without_bulk_plane():
 
 
 def test_streaming_perf_child_smoke():
-    """The bench harness's measured child (STREAM_CHILD) stays runnable:
+    """The streaming child with per-pass acks (STREAM_CHILD) stays runnable:
     a short 2-pass run with per-pass consumed acks."""
     outs = _run_pair(STREAM_CHILD % {"repo": REPO, "n": 8, "passes": 2},
                      timeout=240)

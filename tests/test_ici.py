@@ -485,11 +485,10 @@ class TestRelocateCutsOnTheChip:
         from brpc_tpu.ici import device_plane as dp
         saved = {n: fl.get_flag(n) for n in
                  ("ici_device_plane", "ici_device_plane_host_mesh",
-                  "ici_device_plane_threshold", "ici_device_plane_kernel")}
+                  "ici_device_plane_threshold")}
         fl.set_flag("ici_device_plane", True)
         fl.set_flag("ici_device_plane_host_mesh", True)
         fl.set_flag("ici_device_plane_threshold", 1024)
-        fl.set_flag("ici_device_plane_kernel", "ppermute")
         yield dp.plane()
         for n, v in saved.items():
             fl.set_flag(n, v)

@@ -919,40 +919,6 @@ class TestNativeAttCustody:
             server.stop()
         assert self._drained()
 
-    def test_legacy_mode_byte_identical(self, mesh):
-        """ici_native_att_custody=False restores the PR-8 walk: plain
-        IOBuf both sides, same bytes, same drained registry."""
-        from brpc_tpu.butil import flags as _fl
-        from brpc_tpu.butil.iobuf import IOBuf
-        prev = _fl.get_flag("ici_native_att_custody")
-        _fl.set_flag("ici_native_att_custody", False)
-        try:
-            seen = {}
-
-            def body(cntl, request, response):
-                seen["type"] = type(cntl.request_attachment).__name__
-                response.message = request.message
-                cntl.response_attachment.append(cntl.request_attachment)
-
-            server, ch = self._echo_server(27, body)
-            try:
-                payload = _device_payload(mesh, dev=27)
-                cntl = rpc.Controller()
-                cntl.request_attachment.append_device_array(payload)
-                ch.call_method("EchoService.Echo", cntl,
-                               EchoRequest(message="x"), EchoResponse)
-                assert not cntl.failed(), cntl.error_text
-                assert seen["type"] == "IOBuf"
-                assert type(cntl.response_attachment) is IOBuf
-                assert cntl.response_attachment.to_bytes() == bytes(
-                    np.arange(4096, dtype=np.uint8))
-                del cntl
-            finally:
-                server.stop()
-        finally:
-            _fl.set_flag("ici_native_att_custody", prev)
-        assert self._drained()
-
     def test_proxy_forwarding_view_as_request(self, mesh):
         """Proxy shape: handler A forwards its (unmaterialized) view as
         the REQUEST attachment of a nested call to server B —

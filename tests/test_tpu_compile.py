@@ -75,30 +75,46 @@ def _row_sharded(tpu_mesh, shape, dtype):
 
 # ---- device plane: the point-to-point transfer program ------------------
 
-@pytest.mark.parametrize("block,nbytes",
-                         [(4 * KB, 4 * KB), (4 * MB, 4 * MB),
-                          (64 * MB, 64 * MB), (64 * MB, 4 * MB)],
-                         ids=["4KB", "4MB", "64MB", "4MB-of-64MB"])
-@pytest.mark.parametrize("kernel", ["ppermute", "pallas"])
-def test_device_plane_transfer_program(tpu_mesh, kernel, block, nbytes):
+# What xchip_bulk_64m compiles (callers on chip 0, a server each on chips
+# 1-3): every directed pair with chip 0 gets its own two-chip sub-mesh
+# executable (PERF.md §7 row 1b: the sub-mesh per pair is what proved
+# fragile), and the window's pieces are 4 MB less a header where a frame
+# starts — u8[4194237] and u8[4194279] in the cell's device operations
+# (PERF_LEDGER.jsonl, PR 29, breakdown.device_ops): the request's first
+# piece is cut out of the caller's 64 MiB block, a reply's pieces out of
+# the 4 MiB blocks the server received, 25 bytes in.  The start is an
+# operand, so one program serves every (unaligned) start.
+_REQ_HDR, _REPLY_SHIFT = 67, 25
+_FROM_0 = [(0, 1), (0, 2), (0, 3)]
+_TO_0 = [(1, 0), (2, 0), (3, 0)]
+
+
+def _transfer_case(name, block, nbytes, src=0, dst=1):
+    pair = "" if (src, dst) == (0, 1) else f"-{src}to{dst}"
+    return pytest.param(block, nbytes, src, dst, id=name + pair)
+
+
+@pytest.mark.parametrize(
+    "block,nbytes,src,dst",
+    [_transfer_case("4KB", 4 * KB, 4 * KB),
+     _transfer_case("4MB", 4 * MB, 4 * MB),
+     _transfer_case("64MB", 64 * MB, 64 * MB)]
+    + [_transfer_case("4MB-of-64MB", 64 * MB, 4 * MB, s, d)
+       for s, d in _FROM_0 + _TO_0]
+    + [_transfer_case("request-first-piece", 64 * MB, 4 * MB - _REQ_HDR,
+                      s, d) for s, d in _FROM_0]
+    + [_transfer_case("reply-piece", 4 * MB, 4 * MB - _REPLY_SHIFT, s, d)
+       for s, d in _TO_0])
+def test_device_plane_transfer_program(tpu_mesh, block, nbytes, src, dst):
     """A whole array (block == piece) and a window piece cut out of its
-    block on the chip are one program family."""
+    block on the chip are one program family, built per directed pair."""
     from brpc_tpu.ici.device_plane import DevicePlane
     plane = DevicePlane(mesh=tpu_mesh)
-    if kernel == "pallas" and block != nbytes:
-        # Mosaic refuses a DMA source at a start it cannot prove aligned
-        # (DevicePlane._pallas_body): for this kernel the plane cuts on the
-        # host and posts the whole-array program of the cases above
-        with pytest.raises(ValueError, match="whole arrays"):
-            plane._build(block, nbytes, 0, 1, kernel)
-        return
-    compiled = plane._build(block, nbytes, 0, 1, kernel)[0]
+    compiled, _, mesh2, s_dev, d_dev = plane._build(block, nbytes, src, dst)
+    assert [d.id for d in mesh2.devices.flat] == [s_dev.id, d_dev.id]
+    assert (s_dev, d_dev) == (tpu_mesh.device(src), tpu_mesh.device(dst))
     text = compiled.as_text()
-    if kernel == "ppermute":
-        assert "collective-permute" in text
-    else:
-        # Mosaic compiled it: the interpret branch leaves no custom call
-        assert "tpu_custom_call" in text
+    assert "collective-permute" in text
     ma = compiled.memory_analysis()
     assert ma.argument_size_in_bytes >= block
     assert ma.output_size_in_bytes >= nbytes
@@ -169,7 +185,7 @@ def test_ring_attention_seq4096(tpu_mesh, causal):
     import jax.numpy as jnp
     from brpc_tpu.ici.ring_attention import _build_ring_attention
     n = tpu_mesh.size
-    block = (4096 // n, 8, 128)                   # bench's seq x heads x dim
+    block = (4096 // n, 8, 128)                   # seq x heads x dim
     fn = _build_ring_attention(tpu_mesh, block, jnp.bfloat16, causal)
     qkv = _row_sharded(tpu_mesh, (n,) + block, jnp.bfloat16)
     compiled = fn.lower(qkv, qkv, qkv).compile()
