@@ -470,14 +470,160 @@ def test_a_windowed_echos_gates_never_leave_the_poller(mesh, monkeypatch):
         server.stop()
 
 
-class TestRelocateCutsOnTheChip:
-    """A DEVICE block that is not resident on the target and is large
-    enough for the device plane is posted WHOLE with (offset, length): the
-    transfer program cuts it, the host-side slice (``transport._cut``)
-    is not called on that branch.  The resident branch and the branch
-    under the plane's threshold slice as they did."""
+class TestBorrowedHeaderWindow:
+    """``CreditWindow._consume_window(want, lead)``: a header of ``lead``
+    bytes in front of device bytes is not charged against the cut where the
+    window covers in full what follows it; the window then stands below
+    zero by what it borrowed until the piece's credits repay it."""
 
-    WINDOW, PIECES = 64 * 1024, 16
+    W = 4096
+
+    def _window(self, left=None):
+        from brpc_tpu.ici.transport import CreditWindow
+
+        class Sink(CreditWindow):
+            failed = False
+
+        w = Sink()
+        w._init_window(self.W)
+        if left is not None:
+            w._send_window = left
+        return w
+
+    @pytest.mark.parametrize("left, want, lead, n", [
+        # a full window: the header and a whole window of what follows
+        (4096, 40 + 16 * 4096, 40, 40 + 4096),
+        # ... or all that follows, where that is less than a window
+        (4096, 40 + 4090, 40, 40 + 4090),
+        (4096, 40 + 100, 40, 140),
+        # a partly credited window covers the rest of the data in full
+        (1000, 40 + 990, 40, 40 + 990),
+        # ... and where it cannot, the cut is today's, header charged
+        (1000, 40 + 4096, 40, 1000),
+        (4095, 40 + 4096, 40, 4095),
+        (30, 40 + 4096, 40, 30),
+        # no header: byte for byte
+        (4096, 16 * 4096, 0, 4096),
+        (1000, 5000, 0, 1000),
+        (4096, 100, 0, 100),
+    ])
+    def test_piece_length(self, left, want, lead, n):
+        from brpc_tpu.ici.transport import ici_piece_stats
+        w = self._window(left)
+        before = ici_piece_stats()["borrowed_header_pieces"]
+        assert w._consume_window(want, lead) == n
+        assert n > 0
+        assert w.send_window_left() == left - n
+        # never below zero by more than the header
+        assert w.send_window_left() >= -lead
+        borrowed = ici_piece_stats()["borrowed_header_pieces"] - before
+        assert borrowed == (1 if n > left else 0)
+
+    @pytest.mark.parametrize("left", [0, -1, -40])
+    def test_a_window_at_or_under_zero_is_closed(self, left):
+        w = self._window(left)
+        assert w._consume_window(100) == -1
+        assert w._consume_window(100, 40) == -1
+        assert w.send_window_left() == left
+
+    def test_the_pieces_credits_repay_the_borrowed_header(self):
+        w = self._window()
+        n = w._consume_window(40 + 3 * self.W, 40)
+        assert n == 40 + self.W
+        assert w.send_window_left() == -40
+        assert w.unacked_send_bytes() == self.W + 40
+        assert w._consume_window(2 * self.W) == -1
+        w._on_credits(39)                   # still closed
+        assert w.send_window_left() == -1
+        assert w._consume_window(2 * self.W) == -1
+        w._on_credits(n - 39)
+        assert w.send_window_left() == self.W    # exactly, and capped
+        w._on_credits(10)
+        assert w.send_window_left() == self.W
+
+    def test_racing_pieces_and_credits_keep_the_bound(self):
+        """Writers that borrow and readers that credit, more threads than
+        cores and a short switch interval: the window never stands below
+        zero by more than one header, never above ``window_bytes``, and
+        ends where it began."""
+        import sys
+        w = self._window()
+        lead, taken, lock = 40, [], threading.Lock()
+        stop = time.monotonic() + 0.5
+        low, high, written = [0], [0], threading.Event()
+
+        def writer():
+            while time.monotonic() < stop:
+                n = w._consume_window(lead + 3 * self.W, lead)
+                left = w.send_window_left()
+                low[0], high[0] = min(low[0], left), max(high[0], left)
+                if n > 0:
+                    with lock:
+                        taken.append(n)
+
+        def reader():
+            while not written.is_set() or taken:
+                with lock:
+                    n = taken.pop() if taken else 0
+                if n:
+                    w._on_credits(n // 2)
+                    w._on_credits(n - n // 2)
+
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            writers = [threading.Thread(target=writer) for _ in range(8)]
+            readers = [threading.Thread(target=reader) for _ in range(8)]
+            for t in writers + readers:
+                t.start()
+            for t in writers:
+                t.join(20)
+            written.set()
+            for t in readers:
+                t.join(20)
+            assert not any(t.is_alive() for t in writers + readers)
+        finally:
+            sys.setswitchinterval(old)
+        assert low[0] >= -lead and high[0] <= self.W
+        assert w.send_window_left() == self.W
+
+    @pytest.mark.parametrize("refs, bound, lead", [
+        ((b"h" * 25, "dev"), 1024, 25),
+        ((b"h" * 25, b"m" * 42, "dev"), 1024, 67),
+        ((b"h" * 1023, "dev"), 1024, 1023),
+        ((b"h" * 1024, "dev"), 1024, 0),     # at the bound
+        ((b"h" * 600, b"m" * 600, "dev"), 1024, 0),
+        ((b"h" * 600, "dev"), 512, 0),       # a window under the threshold
+        ((b"h" * 25,), 1024, 0),             # no device bytes
+        (("dev",), 1024, 0),                 # nothing in front of them
+        (("dev", b"t" * 25, "dev"), 1024, 0),
+        ((), 1024, 0),
+    ])
+    def test_header_run(self, mesh, refs, bound, lead):
+        import jax.numpy as jnp
+        from brpc_tpu.butil.iobuf import IOBuf
+        from brpc_tpu.ici.transport import _header_run
+        buf = IOBuf()
+        for r in refs:
+            if r == "dev":
+                buf.append_device_array(jnp.zeros(2048, jnp.uint8))
+            else:
+                buf.append_user_data(r)      # a block of its own
+        assert _header_run(buf, bound) == lead
+
+
+class TestRelocateCutsOnTheChip:
+    """A frame's header rides with its first window piece, so the window's
+    cuts land on the device blocks' boundaries: a block of PIECES x WINDOW
+    goes in exactly PIECES pieces and blocks of WINDOW go whole, whatever
+    the header's length.  A DEVICE block that is not resident on the target
+    and is large enough for the device plane is posted WHOLE with
+    (offset, length): the transfer program cuts it, the host-side slice
+    (``transport._cut``) is not called on that branch.  The resident branch
+    and the branch under the plane's threshold slice as they did."""
+
+    WINDOW, PIECES, THRESHOLD = 64 * 1024, 16, 1024
+    HEADERS = [4, 25, 67, 1023]
 
     @pytest.fixture()
     def host_mesh_plane(self):
@@ -488,105 +634,285 @@ class TestRelocateCutsOnTheChip:
                   "ici_device_plane_threshold")}
         fl.set_flag("ici_device_plane", True)
         fl.set_flag("ici_device_plane_host_mesh", True)
-        fl.set_flag("ici_device_plane_threshold", 1024)
+        fl.set_flag("ici_device_plane_threshold", self.THRESHOLD)
         yield dp.plane()
         for n, v in saved.items():
             fl.set_flag(n, v)
 
-    def _write_and_drain(self, mesh, monkeypatch, src_dev, dst_dev):
-        """A frame of a 4-byte header and one PIECES x WINDOW device block
-        through a socket pair whose window is WINDOW: PIECES whole window
-        pieces and the header's remainder.  Returns (host cuts made, bytes
-        delivered, the payload's bytes, source releases)."""
+    def _payload(self, mesh, dev, nbytes, salt=7):
         import jax
         import jax.numpy as jnp
+        arr = jax.device_put(
+            (jnp.arange(nbytes, dtype=jnp.uint32) * salt % 251).astype(
+                jnp.uint8), mesh.device(dev))
+        jax.block_until_ready(arr)
+        return arr
+
+    def _write_and_drain(self, mesh, monkeypatch, src_dev, dst_dev,
+                         header=b"hdr:", blocks=1, window=None,
+                         unread_first=b"", nbytes=None):
+        """One frame — ``header`` and then ``blocks`` device blocks, ONE of
+        PIECES x WINDOW bytes (a request) or PIECES of WINDOW (a reply, as
+        a server echoes what it received) — through a socket pair whose
+        window is ``window``.  ``unread_first`` is written before it and
+        not read until the frame's first piece has gone (a partly credited
+        window).  Returns what happened, and holds the window's bound all
+        the way."""
+        from brpc_tpu.butil import flags as fl
         from brpc_tpu.butil.iobuf import IOBuf, IOPortal
         from brpc_tpu.ici import transport as tr
-        a = tr.IciSocket(src_dev, dst_dev, mesh, window_bytes=self.WINDOW)
-        b = tr.IciSocket(dst_dev, src_dev, mesh, window_bytes=self.WINDOW)
+        window = window or self.WINDOW
+        a = tr.IciSocket(src_dev, dst_dev, mesh, window_bytes=window)
+        b = tr.IciSocket(dst_dev, src_dev, mesh, window_bytes=window)
         a.peer, b.peer = b, a
-        cuts, released = [], []
-        real_cut = tr._cut
+        nbytes = nbytes or self.PIECES * self.WINDOW
+        arrays = [self._payload(mesh, src_dev, nbytes // blocks, 7 + k)
+                  for k in range(blocks)]     # none: a host-only frame
+        out = type("Wrote", (), {})()
+        out.cuts, out.released, out.pieces, out.posts, out.puts = \
+            [], [], [], [], []
+        out.peak_unacked = 0
+        real_cut, real_pin = tr._cut, a._pin_until_sent
+        real_write, real_post = a._do_write, tr._dp.plane().post_send
 
         def counting_cut(arr, r):
-            out = real_cut(arr, r)
-            if out is not arr:
-                cuts.append((r.offset, r.length))
-            return out
+            got = real_cut(arr, r)
+            if got is not arr:
+                out.cuts.append((r.offset, r.length))
+            return got
+
+        def counting_pin(src_block, moved):
+            # the transport's device_put route: one pin a moved array
+            out.puts.append(len(moved))
+            return real_pin(src_block, moved)
+
+        def counting_post(arr, *args, **kw):
+            out.posts.append(kw["nbytes"])
+            return real_post(arr, *args, **kw)
+
+        def counting_write(data):
+            n = real_write(data)
+            if n >= 0:
+                out.pieces.append(n)
+            out.peak_unacked = max(out.peak_unacked, a.unacked_send_bytes())
+            return n
         monkeypatch.setattr(tr, "_cut", counting_cut)
-        nbytes = self.PIECES * self.WINDOW
-        payload = jax.device_put(
-            (jnp.arange(nbytes, dtype=jnp.uint32) * 7 % 251).astype(
-                jnp.uint8), mesh.device(src_dev))
-        jax.block_until_ready(payload)
-        buf = IOBuf(b"hdr:")
-        buf.append_device_array(payload)
-        buf.backing_block(1).block.on_send_complete = \
-            lambda: released.append(1)
+        monkeypatch.setattr(a, "_pin_until_sent", counting_pin)
+        monkeypatch.setattr(tr._dp.plane(), "post_send", counting_post)
+        monkeypatch.setattr(a, "_do_write", counting_write)
+        buf = IOBuf(header)
+        for arr in arrays:
+            buf.append_device_array(arr)
+        for k in range(blocks):
+            buf.backing_block(buf.backing_block_num() - 1 - k).block \
+                .on_send_complete = lambda: out.released.append(1)
+        out.want = header + b"".join(bytes(np.asarray(x)) for x in arrays)
+        out.sent = arrays
+        stats = tr.ici_piece_stats()
         try:
+            if unread_first:
+                assert a.write(IOBuf(unread_first)) == 0
             assert a.write(buf) == 0
+            total = len(unread_first) + len(out.want)
             portal, got = IOPortal(), 0
             deadline = time.monotonic() + 30
-            while got < 4 + nbytes and time.monotonic() < deadline:
+            while got < total and time.monotonic() < deadline:
                 n = b._do_read(portal, 1 << 20)
                 if n <= 0:
                     time.sleep(0.002)
                     continue
                 got += n
-            assert got == 4 + nbytes
-            for r in portal.device_refs():
-                assert set(r.block.data.devices()) == {mesh.device(dst_dev)}
-                assert r.block.data.ndim == 1
+            assert got == total
+            out.delivered = [r.block.data for r in portal.device_refs()]
+            for arr in out.delivered:
+                assert set(arr.devices()) == {mesh.device(dst_dev)}
+                assert arr.ndim == 1
             deadline = time.monotonic() + 10
             while a.inflight_send_blocks() and time.monotonic() < deadline:
                 time.sleep(0.002)
-            return cuts, portal.to_bytes(), bytes(np.asarray(payload)), \
-                released
+            out.got = portal.to_bytes()[len(unread_first):]
+            # the window's contract: never more unconsumed bytes at the
+            # peer than the window and one header under the threshold, no
+            # empty piece, and every credit back at the end
+            bound = min(fl.get_flag("ici_device_plane_threshold"), window)
+            assert out.peak_unacked < window + bound
+            assert all(n > 0 for n in out.pieces)
+            assert sum(out.pieces) == total
+            assert a.send_window_left() == window
+            after = tr.ici_piece_stats()
+            out.borrowed = (after["borrowed_header_pieces"]
+                            - stats["borrowed_header_pieces"])
+            out.small = (after["small_relocations"]
+                         - stats["small_relocations"])
+            return out
         finally:
             a.set_failed()
             b.set_failed()
 
+    def _released(self, out, n):
+        deadline = time.monotonic() + 10
+        while len(out.released) < n and time.monotonic() < deadline:
+            time.sleep(0.005)
+        return len(out.released)
+
+    @pytest.mark.parametrize("hdr", HEADERS)
     def test_plane_branch_posts_the_block_and_never_slices(
-            self, mesh, monkeypatch, host_mesh_plane):
+            self, mesh, monkeypatch, host_mesh_plane, hdr):
         before = host_mesh_plane.stats()
-        cuts, got, want, released = self._write_and_drain(
-            mesh, monkeypatch, 2, 3)
-        assert got == b"hdr:" + want
+        out = self._write_and_drain(mesh, monkeypatch, 2, 3,
+                                    header=b"h" * hdr)
+        assert out.got == out.want
         after = host_mesh_plane.stats()
-        # sixteen window pieces through the plane, each cut by the program
+        # sixteen window pieces, the header in the first, and each a whole
+        # window of the block through the plane, cut by the program
+        assert out.pieces == [hdr + self.WINDOW] \
+            + [self.WINDOW] * (self.PIECES - 1)
         assert after["transfers"] - before["transfers"] == self.PIECES
+        assert out.posts == [self.WINDOW] * self.PIECES
         assert (after["sliced_in_program"] - before["sliced_in_program"]
                 == self.PIECES)
         assert after["fallbacks"] == before["fallbacks"]
         assert (after["bytes_sent"] - before["bytes_sent"]
-                == self.PIECES * self.WINDOW - 4)
-        # the only host-side cut is the header's 4-byte remainder, which is
-        # under the plane's threshold and goes by slice and device_put
-        assert cuts == [(self.PIECES * self.WINDOW - 4, 4)]
-        # the block's pin is released once per transfer and once for the
-        # remainder's device_put
-        deadline = time.monotonic() + 10
-        while len(released) < self.PIECES + 1 \
-                and time.monotonic() < deadline:
-            time.sleep(0.005)
-        assert len(released) == self.PIECES + 1
+                == self.PIECES * self.WINDOW)
+        # no remainder: nothing is cut on the host, nothing goes by
+        # device_put, and the block's pin is released once per transfer
+        assert out.cuts == [] and out.puts == []
+        assert (out.borrowed, out.small) == (1, 0)
+        assert self._released(out, self.PIECES) == self.PIECES
+        time.sleep(0.05)
+        assert len(out.released) == self.PIECES
 
+    @pytest.mark.parametrize("hdr", HEADERS)
     def test_resident_branch_slices_as_before(self, mesh, monkeypatch,
-                                              host_mesh_plane):
+                                              host_mesh_plane, hdr):
         before = host_mesh_plane.stats()["transfers"]
-        cuts, got, want, _ = self._write_and_drain(mesh, monkeypatch, 4, 4)
-        assert got == b"hdr:" + want
+        out = self._write_and_drain(mesh, monkeypatch, 4, 4,
+                                    header=b"h" * hdr)
+        assert out.got == out.want
         assert host_mesh_plane.stats()["transfers"] == before
-        assert len(cuts) == self.PIECES + 1
-        assert cuts[0] == (0, self.WINDOW - 4)
-        assert cuts[-1] == (self.PIECES * self.WINDOW - 4, 4)
+        assert out.pieces == [hdr + self.WINDOW] \
+            + [self.WINDOW] * (self.PIECES - 1)
+        # one static shape, on the block's own window boundaries
+        assert out.cuts == [(k * self.WINDOW, self.WINDOW)
+                            for k in range(self.PIECES)]
+        assert out.puts == [] and (out.borrowed, out.small) == (1, 0)
 
+    @pytest.mark.parametrize("hdr", HEADERS)
     def test_without_the_plane_every_piece_is_sliced_and_device_put(
-            self, mesh, monkeypatch, host_mesh_plane):
+            self, mesh, monkeypatch, host_mesh_plane, hdr):
         from brpc_tpu.butil import flags as fl
+        # the window is now under the threshold: the bound is the window
         fl.set_flag("ici_device_plane_threshold", 1 << 30)
         before = host_mesh_plane.stats()["transfers"]
-        cuts, got, want, _ = self._write_and_drain(mesh, monkeypatch, 2, 3)
-        assert got == b"hdr:" + want
+        out = self._write_and_drain(mesh, monkeypatch, 2, 3,
+                                    header=b"h" * hdr)
+        assert out.got == out.want
         assert host_mesh_plane.stats()["transfers"] == before
-        assert len(cuts) == self.PIECES + 1
+        assert out.cuts == [(k * self.WINDOW, self.WINDOW)
+                            for k in range(self.PIECES)]
+        assert out.puts == [self.WINDOW] * self.PIECES
+        # each of them a DEVICE ref that crossed under the threshold
+        assert (out.borrowed, out.small) == (1, self.PIECES)
+        assert self._released(out, self.PIECES) == self.PIECES
+
+    @pytest.mark.parametrize("hdr", HEADERS)
+    @pytest.mark.parametrize("src, dst", [(2, 3), (4, 4)],
+                             ids=["plane", "resident"])
+    def test_a_reply_passes_every_block_whole(
+            self, mesh, monkeypatch, host_mesh_plane, src, dst, hdr):
+        """What a server echoes: the PIECES blocks of WINDOW it received,
+        behind a header of another length.  Every piece is one whole
+        block: no slice on either branch, no device_put across chips, and
+        on one chip the very arrays are passed."""
+        before = host_mesh_plane.stats()
+        out = self._write_and_drain(mesh, monkeypatch, src, dst,
+                                    header=b"r" * hdr, blocks=self.PIECES)
+        assert out.got == out.want
+        after = host_mesh_plane.stats()
+        assert out.pieces == [hdr + self.WINDOW] \
+            + [self.WINDOW] * (self.PIECES - 1)
+        assert out.cuts == [] and out.puts == []
+        assert (out.borrowed, out.small) == (1, 0)
+        if src == dst:
+            assert after["transfers"] == before["transfers"]
+            assert all(x is y for x, y in zip(out.delivered, out.sent))
+        else:
+            assert out.posts == [self.WINDOW] * self.PIECES
+            # B == n: the program has nothing to cut from
+            assert after["sliced_in_program"] == before["sliced_in_program"]
+            assert self._released(out, self.PIECES) == self.PIECES
+
+    @pytest.mark.parametrize(
+        "case, hdr, window, unread, first_pieces, borrowed", [
+            # a host run at or over the bound (the plane's threshold)
+            ("long_header", 1024, None, 0, [64 * 1024, 64 * 1024], 0),
+            ("longer_header", 3000, None, 0, [64 * 1024, 64 * 1024], 0),
+            # a window under the threshold bounds the header by itself
+            ("small_window", 1024, 512, 0, [512, 512, 512], 0),
+            # ... and what such a cut leaves of the header is a short run
+            # in front of device bytes like any other
+            ("small_window_rest", 600, 512, 0, [512, 88 + 512, 512], 1),
+            # a partly credited window that cannot cover a whole piece
+            ("part_credited", 25, None, 1000, [1000, 64 * 1024 - 1000], 0),
+        ])
+    def test_cut_byte_for_byte_as_before(self, mesh, monkeypatch,
+                                         host_mesh_plane, case, hdr, window,
+                                         unread, first_pieces, borrowed):
+        out = self._write_and_drain(
+            mesh, monkeypatch, 4, 4, header=b"h" * hdr, window=window,
+            unread_first=b"u" * unread,
+            nbytes=self.PIECES * (window or self.WINDOW))
+        assert out.got == out.want
+        assert out.pieces[:len(first_pieces)] == first_pieces
+        assert out.borrowed == borrowed
+
+    def test_a_host_only_frame_is_cut_byte_for_byte(self, mesh,
+                                                    monkeypatch,
+                                                    host_mesh_plane):
+        out = self._write_and_drain(
+            mesh, monkeypatch, 4, 4, header=b"h" * (3 * self.WINDOW + 5),
+            blocks=0)
+        assert out.got == out.want
+        assert out.pieces == [self.WINDOW] * 3 + [5]
+        assert out.borrowed == 0 and out.cuts == [] and out.puts == []
+
+    def test_a_small_block_across_chips_is_counted(self, mesh, monkeypatch,
+                                                   host_mesh_plane):
+        """A DEVICE ref under the plane's threshold goes by slice and
+        device_put, and the transport counts it."""
+        import jax.numpy as jnp
+        from brpc_tpu.butil.iobuf import IOBuf, IOPortal
+        from brpc_tpu.ici import transport as tr
+        a = tr.IciSocket(2, 3, mesh)
+        b = tr.IciSocket(3, 2, mesh)
+        a.peer, b.peer = b, a
+        try:
+            before = tr.ici_piece_stats()
+            buf = IOBuf(b"hdr:")
+            buf.append_device_array(
+                self._payload(mesh, 2, self.THRESHOLD - 1))
+            assert a.write(buf) == 0
+            portal, got = IOPortal(), 0
+            deadline = time.monotonic() + 10
+            while got < len(buf) and time.monotonic() < deadline:
+                got += max(0, b._do_read(portal, 1 << 20))
+            after = tr.ici_piece_stats()
+            assert after["small_relocations"] \
+                == before["small_relocations"] + 1
+            # the frame fits the window: nothing was borrowed
+            assert after["borrowed_header_pieces"] \
+                == before["borrowed_header_pieces"]
+        finally:
+            a.set_failed()
+            b.set_failed()
+
+    def test_the_counters_are_bvars(self):
+        from brpc_tpu import bvar
+        from brpc_tpu.ici import transport as tr
+        stats = tr.ici_piece_stats()
+        for name, key in (("ici_transport_borrowed_header_pieces",
+                           "borrowed_header_pieces"),
+                          ("ici_transport_small_relocations",
+                           "small_relocations")):
+            assert bvar.find_exposed(name).get_value() == stats[key]
+        assert len(tr.ici_transport_stats()) == 2

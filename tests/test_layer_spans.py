@@ -274,7 +274,12 @@ def test_three_pieces_each_way(traced):
     assert len(pieces) == 6
     frame = BULK + 12                   # attachment and header, at least
     assert sum(p.n for p in pieces) >= 2 * frame
-    assert max(p.n for p in pieces) <= WINDOW
+    # n is the piece's real length: a frame's first piece is its header
+    # and a whole window of the attachment, every other piece the window
+    # or the attachment's end, so no piece is a header's remainder
+    sizes = sorted(p.n for p in pieces)
+    assert sizes[:4] == [BULK - 2 * WINDOW] * 2 + [WINDOW] * 2
+    assert all(WINDOW + 12 <= n < WINDOW + 1024 for n in sizes[4:])
     call = _one(spans, "brpc.call")
     assert call.call_id != 0            # the Python plane: a correlation id
     first = min(pieces, key=lambda p: p.start_ns)

@@ -78,12 +78,17 @@ def _row_sharded(tpu_mesh, shape, dtype):
 # What xchip_bulk_64m compiles (callers on chip 0, a server each on chips
 # 1-3): every directed pair with chip 0 gets its own two-chip sub-mesh
 # executable (PERF.md §7 row 1b: the sub-mesh per pair is what proved
-# fragile), and the window's pieces are 4 MB less a header where a frame
-# starts — u8[4194237] and u8[4194279] in the cell's device operations
-# (PERF_LEDGER.jsonl, PR 29, breakdown.device_ops): the request's first
-# piece is cut out of the caller's 64 MiB block, a reply's pieces out of
-# the 4 MiB blocks the server received, 25 bytes in.  The start is an
-# operand, so one program serves every (unaligned) start.
+# fragile).  Since PR 31 a frame's header rides with its first window
+# piece, so the cell's device operations are two shapes, whatever the
+# header's length: the request's pieces are u8[4194304] cut out of the
+# caller's 64 MiB block ("4MB-of-64MB", the start an operand), the reply's
+# are the 4 MiB blocks the server received, whole ("reply-whole-block":
+# block == piece, XLA folds the slice away).  The odd sizes below are what
+# the cell compiled before (u8[4194237], u8[4194279]: PERF_LEDGER.jsonl,
+# PR 29, breakdown.device_ops) and what other traffic still meets: a
+# header at or over the plane's threshold, a partly credited window, a
+# block that is no multiple of the window.  The start is an operand, so
+# one program serves every (unaligned) start.
 _REQ_HDR, _REPLY_SHIFT = 67, 25
 _FROM_0 = [(0, 1), (0, 2), (0, 3)]
 _TO_0 = [(1, 0), (2, 0), (3, 0)]
@@ -104,6 +109,8 @@ def _transfer_case(name, block, nbytes, src=0, dst=1):
     + [_transfer_case("request-first-piece", 64 * MB, 4 * MB - _REQ_HDR,
                       s, d) for s, d in _FROM_0]
     + [_transfer_case("reply-piece", 4 * MB, 4 * MB - _REPLY_SHIFT, s, d)
+       for s, d in _TO_0]
+    + [_transfer_case("reply-whole-block", 4 * MB, 4 * MB, s, d)
        for s, d in _TO_0])
 def test_device_plane_transfer_program(tpu_mesh, block, nbytes, src, dst):
     """A whole array (block == piece) and a window piece cut out of its
