@@ -3,13 +3,14 @@ against the plain reference, and the route it took against the
 configuration's guarantees.  Every number has a limit of its own; the run is
 correct when each number keeps its limit.
 
-Held on every call of the window, as it returned (``driver.Caller``): the
-call did not fail, the reply attachment is whole and all of it on the device,
-every block of it is resident on the caller's chip, and the reply's message
-answers this call.  Held on a sample drawn from the seed, once the window has
+Held on every operation of the window, as its client returned
+(``driver.Caller``, the same for every client): it did not fail, the reply
+attachment is whole and all of it on the device, every block of it is resident
+on the caller's chip, and the reply's message answers this operation's key.
+Held on a sample drawn from the seed, once the window has
 closed and the payload sets are freed: the reply's bytes and its message
-(``Transform``'s checksum) equal what ``reference/<Method>.py`` works out from
-the block that ``reference/payload.py`` regenerates on the host.
+(where a method computes one) equal what ``reference/<Method>.py`` works out
+from the block that ``reference/payload.py`` regenerates on the host.
 """
 from __future__ import annotations
 
@@ -91,7 +92,8 @@ def compare(window: Window):
 
     n_calls = sum(1 for _ in window.calls())
     out["second_route_events"] = _limit(
-        sum(window.counters[k] for k in counters.SECOND_ROUTE), "<=", 0)
+        sum(window.counters[k] for k in counters.second_route(window.cell)),
+        "<=", 0)
     for r in wl.get("route", []):
         per_call = window.counters[r["counter"]] / n_calls if n_calls else 0.0
         out[f"{r['counter']}_per_call"] = _limit(

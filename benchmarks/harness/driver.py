@@ -1,9 +1,13 @@
 """One cell, once: bring the deployment up, make the payload sets, warm up,
 drive the closed loop for the window, hand back what was seen.
 
-The window drives ``rpc.Channel.call_method`` against ``rpc.Server``s on the
-configuration's ``ici://k`` endpoints.  A call's clock runs from before
-``call_method`` until the reply attachment's device blocks are ready.
+The window drives each mix entry's client (``clients/<name>.py``, the
+loader's default where the entry names none) against ``rpc.Server``s on the
+configuration's ``ici://k`` endpoints.  What an operation does is the
+client's; what its latency is, and whether it was answered, is decided here
+alone, once for every client: the clock runs from before the client's
+``call`` until the device blocks of the attachment it returns are ready, and
+the four per-operation checks are made on what it returned.
 """
 from __future__ import annotations
 
@@ -17,7 +21,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 
 from . import counters, traffic
-from .loader import Cell, control_module, service_module
+from .loader import Cell, client_module, control_module, service_module
 
 
 class Spans:
@@ -29,6 +33,17 @@ class Spans:
 
     def stamp(self, boundary: str, key: str) -> None:
         self.at.setdefault(boundary, {})[key] = time.perf_counter_ns()
+
+
+@dataclass
+class ClientContext:
+    """What a client module's ``open`` is given, once per caller and mix
+    entry."""
+    rpc: Any                            # the program's ``brpc_tpu.rpc``
+    channel: Any                        # this caller's own ``rpc.Channel``
+    method: str                         # the full method name of the entry
+    thread: int
+    options: Dict[str, Any]             # the mix entry's ``client_options``
 
 
 @dataclass
@@ -172,70 +187,75 @@ class Caller:
 
     def __init__(self, dep: Deployment, thread: int, sample: int,
                  traced: bool):
-        from ..services.messages import Request, Response
         self.dep = dep
         self.thread = thread
-        self.channel = dep.channels[thread]
         self.schedule = traffic.schedule(dep.cell.workload, dep.seed, thread)
         self.sample = sample
         self.pick = random.Random((dep.seed << 20) ^ (thread << 8) ^ 0xC4)
         self.seen: Dict[int, int] = {}
         self.n = 0
         self.traced = traced
-        self.Request, self.Response = Request, Response
         self.log = CallerLog()
+        # one opened client per mix entry, resolved here and never again
+        self.clients: List[Any] = []
+        try:
+            for m, name in zip(dep.cell.workload["mix"], dep.cell.clients()):
+                self.clients.append(client_module(name).open(ClientContext(
+                    rpc=dep.rpc, channel=dep.channels[thread],
+                    method=dep.method_names[m["method"]],
+                    thread=thread, options=m.get("client_options", {}))))
+        except BaseException:
+            self.close()
+            raise
+        self.operations = [c.call for c in self.clients]
+
+    def close(self) -> None:
+        for c in self.clients:
+            c.close()
+        self.clients = []
 
     def one_call(self, phase: str = "w",
                  call: Optional[traffic.Call] = None) -> bool:
-        dep, rpc, log = self.dep, self.dep.rpc, self.log
+        dep, log = self.dep, self.log
         if call is None:
             call = next(self.schedule)
         key = f"{phase}{self.thread:02d}.{self.n:09d}"
         self.n += 1
         block = dep.sets[call.set_name][call.block]
-        cntl = rpc.Controller()
-        cntl.request_attachment.append_device_array(block)
-        request = self.Request(message=key)
+        operate = self.operations[call.mix]
         ok = True
         t0 = time.perf_counter_ns()
         note = jax.profiler.TraceAnnotation("bench.call." + call.method) \
             if self.traced else contextlib.nullcontext()
         try:
             with note:
-                resp = self.channel.call_method(
-                    dep.method_names[call.method], cntl, request,
-                    self.Response)
-            att = cntl.response_attachment
+                message, att = operate(key, block)
             refs = att.device_refs()
             jax.block_until_ready([r.block.data for r in refs])
-        except Exception as e:       # a call that raises is a failed call
+        except Exception as e:       # an operation that raises has failed
             t1 = time.perf_counter_ns()
             log.fault("failed_calls", f"{key}: {type(e).__name__}: {e}")
             log.calls.append((t0, t1, call.mix, call.nbytes, False, key))
             return False
         t1 = time.perf_counter_ns()
-        if cntl.failed():
-            log.fault("failed_calls", f"{key}: {cntl.error_text}")
+        if len(att) != call.nbytes or att.device_bytes() != call.nbytes:
+            log.fault("short_replies",
+                      f"{key}: reply attachment {len(att)}B of which "
+                      f"{att.device_bytes()}B device, sent {call.nbytes}B")
             ok = False
-        else:
-            if len(att) != call.nbytes or att.device_bytes() != call.nbytes:
-                log.fault("short_replies",
-                          f"{key}: reply attachment {len(att)}B of which "
-                          f"{att.device_bytes()}B device, sent {call.nbytes}B")
-                ok = False
-            want = {dep.caller_device}
-            if any(set(r.block.data.devices()) != want for r in refs):
-                log.fault("misplaced_replies",
-                          f"{key}: reply resident on "
-                          f"{[r.block.data.devices() for r in refs]}")
-                ok = False
-            if not resp.message.startswith(key):
-                log.fault("misordered_replies",
-                          f"{key}: reply says {resp.message[:40]!r}")
-                ok = False
+        want = {dep.caller_device}
+        if any(set(r.block.data.devices()) != want for r in refs):
+            log.fault("misplaced_replies",
+                      f"{key}: reply resident on "
+                      f"{[r.block.data.devices() for r in refs]}")
+            ok = False
+        if not message.startswith(key):
+            log.fault("misordered_replies",
+                      f"{key}: reply says {message[:40]!r}")
+            ok = False
         log.calls.append((t0, t1, call.mix, call.nbytes, ok, key))
         if ok and phase == "w":
-            self._maybe_keep(Sampled(key, call, resp.message, att))
+            self._maybe_keep(Sampled(key, call, message, att))
         return ok
 
     def _maybe_keep(self, s: Sampled) -> None:
@@ -359,16 +379,18 @@ def run_window(cell: Cell, seed: int, seconds: float, traced: bool,
     parts["before_deployment"] = time.perf_counter() - process_start
     dep = Deployment(cell, seed, spans, control)
     parts["deployment_and_sets"] = time.perf_counter() - process_start
+    callers: List[Caller] = []
+    more = wl.get("counters", [])
     try:
-        callers = [Caller(dep, t, wl["sample_per_thread"], traced)
-                   for t in range(wl["threads"])]
+        for t in range(wl["threads"]):
+            callers.append(Caller(dep, t, wl["sample_per_thread"], traced))
         age_call_ids(wl["threads"])
         _warm_up(dep, callers, meter, wl["warmup_seconds"])
         parts["warm_up"] = time.perf_counter() - process_start
         for c in callers:               # warm-up calls are not the window's
             c.log = CallerLog()
             c.seen = {}
-        before = counters.snapshot(dep.servers)
+        before = counters.read(dep.servers, more)
         programs_before = meter.programs
         trace_slice: List[int] = []
 
@@ -399,7 +421,7 @@ def run_window(cell: Cell, seed: int, seconds: float, traced: bool,
         _run_threads(callers, deadline, barrier, "w", meanwhile=meanwhile)
         end_ns = max([t1 for c in callers for (_, t1, *_r) in c.log.calls],
                      default=time.perf_counter_ns())
-        after = counters.snapshot(dep.servers)
+        after = counters.read(dep.servers, more)
         peak = memory_peak(dep.devices)
         return Window(
             cell=cell, seed=seed, setup_s=setup_s, setup_parts=parts,
@@ -412,6 +434,8 @@ def run_window(cell: Cell, seed: int, seconds: float, traced: bool,
             memory_peak_bytes=peak, caller_device=dep.caller_device,
             devices=dep.devices)
     finally:
+        for c in callers:
+            c.close()
         dep.close()
 
 

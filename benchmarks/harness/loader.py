@@ -6,8 +6,10 @@ A cell is ``workloads/<name>.json`` (the traffic), which names
 alone says what a metric is and which cells report it, and
 ``metrics/<name>.json`` holds nothing but its ``reader`` (where reading needs
 code, a ``metrics/<name>.py`` beside it); its services and references are
-found by the method names of the traffic's mix.  Nothing here knows a cell by
-name.
+found by the method names of the traffic's mix, its clients by the mix
+entries' ``client`` (``unary`` where an entry names none) and its further
+counters by the workload's ``counters``.  Code is found as data is: under the
+first of ``ROOTS`` that has the file.  Nothing here knows a cell by name.
 """
 from __future__ import annotations
 
@@ -16,6 +18,7 @@ import importlib
 import importlib.util
 import json
 import os
+import sys
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
@@ -65,6 +68,10 @@ class Metric:
     module: Optional[Any] = None        # metrics/<name>.py, where there is one
 
 
+# the client of a mix entry that names none: one ``Channel.call_method``
+DEFAULT_CLIENT = "unary"
+
+
 @dataclass
 class Cell:
     name: str
@@ -78,6 +85,10 @@ class Cell:
     def methods(self) -> List[str]:
         return sorted({m["method"] for m in self.workload["mix"]})
 
+    def clients(self) -> List[str]:
+        """The client of each mix entry, in the mix's order."""
+        return [m.get("client", DEFAULT_CLIENT) for m in self.workload["mix"]]
+
 
 def _deep_update(base: Dict[str, Any], over: Dict[str, Any]) -> None:
     for k, v in over.items():
@@ -87,17 +98,37 @@ def _deep_update(base: Dict[str, Any], over: Dict[str, Any]) -> None:
             base[k] = v
 
 
+_code: Dict[str, Any] = {}             # file path -> its module, loaded once
+
+
+def _load_code(path: str):
+    """The module of one ``.py`` file under a root.  A file of the
+    benchmark's own directory is imported as ``benchmarks.<kind>.<name>``
+    (the module a plain import gives; its relative imports hold); a further
+    root's file is loaded by its path and imports absolutely."""
+    if path not in _code:
+        kind = os.path.basename(os.path.dirname(path))
+        stem = os.path.basename(path)[:-len(".py")]
+        if os.path.dirname(os.path.dirname(path)) == BENCH_DIR \
+                and stem.isidentifier():
+            _code[path] = importlib.import_module(
+                f"benchmarks.{kind}.{stem}")
+        else:
+            label = "".join(c if c.isalnum() else "_" for c in stem)
+            spec = importlib.util.spec_from_file_location(
+                f"benchmarks_{kind}_{label}_{len(_code)}", path)
+            module = importlib.util.module_from_spec(spec)
+            sys.modules[spec.name] = module     # dataclasses look it up
+            spec.loader.exec_module(module)
+            _code[path] = module
+    return _code[path]
+
+
 def _metric(entry: Dict[str, Any]) -> Metric:
     name = entry["name"]
     path = _find("metrics", f"{name}.json")
-    module = None
     code = path[:-len(".json")] + ".py"
-    if os.path.exists(code):
-        spec = importlib.util.spec_from_file_location(
-            f"benchmarks_metric_{name.replace('.', '_').replace('-', '_')}",
-            code)
-        module = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(module)
+    module = _load_code(code) if os.path.exists(code) else None
     return Metric(name=name, unit=entry["unit"], source=entry["source"],
                   reader=_load(path)["reader"], module=module)
 
@@ -141,10 +172,34 @@ def load_cell(name: str, rehearse: bool = False) -> Cell:
     for spec in workload["sets"].values():      # "bytes": a key of the config
         if isinstance(spec.get("bytes"), str):
             spec["bytes"] = config[spec["bytes"]]
-    return Cell(name=name, chips=entry["chips"], workload=workload,
+    cell = Cell(name=name, chips=entry["chips"], workload=workload,
                 config=config, config_name=entry["config"],
                 end_to_end=_metrics_of(name, man["end_to_end"]),
                 per_layer=_metrics_of(name, man["per_layer"]))
+    _resolve_code(cell)
+    return cell
+
+
+def _resolve_code(cell: Cell) -> None:
+    """Every client and counter module the cell names exists, and no key a
+    counter module gives is the table's or another module's: said here,
+    before a device is touched."""
+    from .counters import TABLE_KEYS, second_route
+    for client in cell.clients():
+        client_module(client)
+    taken = {k: "harness/counters.py" for k in TABLE_KEYS}
+    for name in cell.workload.get("counters", []):
+        for key in counter_module(name).KEYS:
+            if key in taken:
+                raise BenchmarkError(
+                    f"counters/{name}.py gives {key!r}, which "
+                    f"{taken[key]} gives already")
+            taken[key] = f"counters/{name}.py"
+    for key in second_route(cell):
+        if key not in taken:
+            raise BenchmarkError(
+                f"the configuration holds {key!r} at zero and no counter "
+                f"module of the cell gives it")
 
 
 def service_module(method: str):
@@ -159,10 +214,17 @@ def control_module(name: str):
     return _by_name("controls", name)
 
 
+def client_module(name: str):
+    return _by_name("clients", name)
+
+
+def counter_module(name: str):
+    return _by_name("counters", name)
+
+
 def _by_name(kind: str, name: str):
-    if not os.path.exists(os.path.join(BENCH_DIR, kind, f"{name}.py")):
-        raise BenchmarkError(f"missing file benchmarks/{kind}/{name}.py")
-    return importlib.import_module(f"benchmarks.{kind}.{name}")
+    """``<kind>/<name>.py`` under the first root that has it."""
+    return _load_code(_find(kind, f"{name}.py"))
 
 
 def peaks(device_kind: str) -> Dict[str, Any]:

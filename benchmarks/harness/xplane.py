@@ -6,7 +6,7 @@ only ``reduce_file`` touches jax.
 """
 from __future__ import annotations
 
-import bisect
+import heapq
 import glob
 import os
 import re
@@ -95,30 +95,64 @@ def newest_trace(trace_dir: str) -> Optional[str]:
     return max(found, key=os.path.getmtime) if found else None
 
 
+# host annotations kept from a trace: the benchmark's own and the program's
+# lexical layer spans.  Only the benchmark's span the traced window.
+BENCH_SPANS = "bench."
+PROGRAM_SPANS = "brpc."
+
+
 def attribute_gaps(idle: List[Interval], host: List[Event],
                     top: int = 10) -> List[Tuple[str, float]]:
     """Idle seconds by what the host was doing: each gap goes to the
-    benchmark annotation that began last before the gap's middle and still
-    covers it."""
-    host = sorted(host, key=lambda e: e[1])
-    starts = [e[1] for e in host]
+    innermost annotation that covers the gap's middle (the one that began
+    last and is still open) among the program's ``brpc.*`` layer spans, so
+    that a gap names the layer the chip waited for; to the innermost
+    ``bench.*`` annotation only where no span of the program is open.  One
+    sweep over the sorted starts with the open spans on a heap: a cover is
+    found however many events began since."""
+    opening = sorted(host, key=lambda e: e[1])
+    open_spans: Dict[str, List[Tuple[int, int, str]]] = {
+        PROGRAM_SPANS: [], BENCH_SPANS: []}      # heaps of (-start, end, name)
     by_what: Dict[str, int] = {}
-    for a, b in idle:
+    nxt = 0
+    for a, b in sorted(idle, key=lambda g: g[0] + g[1]):
         t = (a + b) // 2
+        while nxt < len(opening) and opening[nxt][1] <= t:
+            name, s, d = opening[nxt]
+            nxt += 1
+            kind = PROGRAM_SPANS if name.startswith(PROGRAM_SPANS) \
+                else BENCH_SPANS
+            heapq.heappush(open_spans[kind], (-s, s + d, name))
         what = "no benchmark span open"
-        i = bisect.bisect_right(starts, t)
-        for name, s, d in reversed(host[max(0, i - 64):i]):
-            if s <= t < s + d:
-                what = name
+        for kind in (PROGRAM_SPANS, BENCH_SPANS):
+            heap = open_spans[kind]
+            while heap and heap[0][1] <= t:     # ended: it covers no later
+                heapq.heappop(heap)             # middle either
+            if heap:
+                what = heap[0][2]
                 break
         by_what[what] = by_what.get(what, 0) + (b - a)
     ranked = sorted(by_what.items(), key=lambda kv: (-kv[1], kv[0]))
     return [(w, ns / 1e9) for w, ns in ranked[:top]]
 
 
+def traced_window(per_chip: Dict[int, List[Event]],
+                  host: List[Event]) -> Optional[Interval]:
+    """The span from the first to the last event on any of the chips'
+    operation lines and the benchmark's own host annotations (``bench.*``);
+    the program's (``brpc.*``) are kept for the idle gaps' names and widen
+    no window, so no metric that divides by it moves with them."""
+    everything = [e for ev in per_chip.values() for e in ev] \
+        + [e for e in host if e[0].startswith(BENCH_SPANS)]
+    if not everything:
+        return None
+    return (min(e[1] for e in everything),
+            max(e[1] + e[2] for e in everything))
+
+
 def reduce_file(path: str, chips: List[int]) -> Reduction:
-    """The traced window is the span from the first to the last event on any
-    of the chips' operation lines and the benchmark's host annotations."""
+    """Busy seconds and ranked operations of each chip over the traced
+    window, and the busiest chip's idle gaps by what the host was doing."""
     from jax.profiler import ProfileData
     data = ProfileData.from_file(path)
     per_chip: Dict[int, List[Event]] = {}
@@ -135,12 +169,11 @@ def reduce_file(path: str, chips: List[int]) -> Reduction:
             for line in plane.lines:
                 host.extend((e.name, int(e.start_ns), int(e.duration_ns))
                             for e in line.events
-                            if e.name.startswith("bench."))
-    everything = [e for ev in per_chip.values() for e in ev] + host
-    if not everything:
+                            if e.name.startswith((BENCH_SPANS,
+                                                  PROGRAM_SPANS)))
+    window = traced_window(per_chip, host)
+    if window is None:
         return Reduction(window_s=0.0)
-    window = (min(e[1] for e in everything),
-              max(e[1] + e[2] for e in everything))
     red = Reduction(window_s=(window[1] - window[0]) / 1e9)
     for chip, ev in per_chip.items():
         red.busy_s[chip] = busy_ns(ev, window) / 1e9

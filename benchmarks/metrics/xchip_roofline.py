@@ -8,15 +8,15 @@ correct call carries its attachment to the server's chip and the echo back, so
 the traced slice (``readers.overlap_count``).
 
 Seconds are those of the transfer operations on the chip's operation line:
-with ``ppermute`` the ``collective-permute-start`` and ``-done`` instructions
+the transfer program's ``collective-permute-start`` and ``-done`` instructions
 (on a kept trace of the cell one name each whatever the piece's size: 0.005 s
 and 0.548 s of chip 0's 0.786 busy seconds, first and eighth of its fifteen
-names; PERF.md section 5), with the Pallas kernel its one custom call.  A
-``Reduction`` keeps the ten longest names of a chip, so a transfer operation
-may have fallen out of them; every busy second the ten names do not account
-for is therefore added to the transfer seconds.  That can only understate the
-share, by nothing where the ten names hold every transfer operation.  A trace
-in which the chip ran no transfer operation gives nothing, never 0.
+names; PERF.md section 5).  A ``Reduction`` keeps the ten longest names of a
+chip, so a transfer operation may have fallen out of them; every busy second
+the ten names do not account for is therefore added to the transfer seconds.
+That can only understate the share, by nothing where the ten names hold every
+transfer operation.  A trace in which the chip ran no transfer operation gives
+nothing, never 0.
 """
 from benchmarks.harness import readers
 

@@ -7,11 +7,15 @@ No topology is described and no chip is looked for here: the rehearsals run
 tests/conftest.py gives every test (eight, so a four-chip cell rehearses on
 four of them).
 
-``fixtures/`` holds one more cell, ``fixture_xchip`` (three servers on three
-further chips, a metric with a reader of its own), added the way a later PR
-adds one: files and manifest entries, no edit of the harness.  It keeps the
-harness's path over several chips under test while BENCHMARK.json has no
-cross-chip cell.
+``fixtures/`` holds two more cells, added the way a later PR adds one: files
+and manifest entries, no edit of the harness.  ``fixture_xchip`` (three
+servers on three further chips, a metric with a reader of its own) kept the
+harness's path over several chips under test until BENCHMARK.json had a
+cross-chip cell.  ``fixture_stream`` has a client that is not a unary call (a
+long-lived stream a caller, ``clients/fixture_stream.py``), a service, a
+reference, a counter module and a control of its own: it keeps the client
+and counter seams under test until a streaming cell is added
+(test_client_and_counter_seams.py has what only that cell shows).
 """
 import json
 import os
@@ -305,29 +309,50 @@ def test_rehearsal_prints_the_contract_line(capsys, restore_mesh, cell, trace):
 # ---- a timed path broken underneath comes out not correct -----------------
 
 def _break_replies(monkeypatch, how):
-    """The program's client returns something else than the server sent."""
+    """The program's client side hands on something else than the server
+    sent: a unary call's reply attachment as ``call_method`` returns, a
+    stream's chunk as it is delivered to the client's handler."""
     import jax
     from brpc_tpu import rpc
-    real = rpc.Channel.call_method
+    from brpc_tpu.butil.iobuf import IOBuf
+    from brpc_tpu.rpc import stream as stream_mod
+    real_call, real_on_data = rpc.Channel.call_method, \
+        stream_mod.Stream.on_data
 
-    def broken(self, method, cntl, request, response_cls, *a, **kw):
-        resp = real(self, method, cntl, request, response_cls, *a, **kw)
-        att = cntl.response_attachment
-        refs = att.device_refs()
-        if cntl.failed() or not refs:
-            return resp
-        parts = [r.block.data.reshape(-1)[r.offset:r.offset + r.length]
-                 for r in refs]
-        att.clear()
-        for i, z in enumerate(parts):
-            if how == "corrupted_byte" and i == 0:
+    def altered(buf):
+        """``buf`` with its device blocks broken, its host bytes as sent."""
+        out, first = IOBuf(), True
+        for i in range(buf.backing_block_num()):
+            r = buf.backing_block(i)
+            if not hasattr(r.block.data, "devices"):
+                out.append(bytes(r.block.host_view(r.offset, r.length)))
+                continue
+            z = r.block.data.reshape(-1)[r.offset:r.offset + r.length]
+            if how == "corrupted_byte" and first:
                 z = z.at[len(z) // 3].set(z[len(z) // 3] ^ 0x40)
             if how == "wrong_chip":
                 z = jax.device_put(z, jax.devices()[1])
-            att.append_device_array(z)
+            first = False
+            out.append_device_array(z)
+        return out
+
+    def broken_call(self, method, cntl, request, response_cls, *a, **kw):
+        resp = real_call(self, method, cntl, request, response_cls, *a, **kw)
+        att = cntl.response_attachment
+        if cntl.failed() or not att.device_refs():
+            return resp
+        broken = altered(att)
+        att.clear()
+        att.append(broken)
         return resp
 
-    monkeypatch.setattr(rpc.Channel, "call_method", broken)
+    def broken_on_data(self, data):
+        if self.is_client and data.device_refs():
+            data = altered(data)
+        return real_on_data(self, data)
+
+    monkeypatch.setattr(rpc.Channel, "call_method", broken_call)
+    monkeypatch.setattr(stream_mod.Stream, "on_data", broken_on_data)
 
 
 @pytest.mark.parametrize("fault,number", [
@@ -344,10 +369,16 @@ def test_broken_timed_path_is_not_correct(capsys, restore_mesh, monkeypatch,
     assert f"check {number}:" in err and "NOT OK" in err
 
 
+# the controls of ``benchmarks/controls/`` alter a unary reply's attachment as
+# the handler answers, which a stream's chunks never pass: a cell whose client
+# is not unary brings a control of its own
+UNARY_CONTROLS = ["flipped_byte", "stale_reply", "host_reply"]
+OWN_CONTROLS = {"fixture_xchip": UNARY_CONTROLS + ["wrong_chip"],
+                "fixture_stream": ["fixture_flipped_chunk"]}
+
+
 @pytest.mark.parametrize("cell,control", [
-    (c, k) for c in ALL_CELLS
-    for k in ["flipped_byte", "stale_reply", "host_reply"]
-    + (["wrong_chip"] if c in FIXTURE_CELLS else [])])
+    (c, k) for c in ALL_CELLS for k in OWN_CONTROLS.get(c, UNARY_CONTROLS)])
 def test_control_comes_out_not_correct(capsys, restore_mesh, cell, control):
     """The control: the cell with one stated guarantee broken."""
     mod = loader.control_module(control)

@@ -113,11 +113,15 @@ def test_echo_crosses_through_the_plane_and_equals_the_reference(
         assert all(set(r.block.data.devices()) == {jax.devices()[0]}
                    for r in refs)
     after = host_mesh_plane.stats()
-    # a frame is its header and the block: four whole windows of it each
-    # way go through the plane, the header's remainder (under the plane's
-    # threshold) by device_put
+    # a frame is its header and the block: the header rides with the first
+    # piece (host bytes, no transfer of their own), so four whole windows of
+    # the block each way go through the plane and nothing else does
     assert after["transfers"] - before["transfers"] == 3 * 2 * BLOCK // WINDOW
-    assert after["bytes_sent"] - before["bytes_sent"] > 3 * 2 * (BLOCK - 4096)
+    assert after["bytes_sent"] - before["bytes_sent"] == 3 * 2 * BLOCK
+    # the request's pieces are cut by the transfer program out of the caller's
+    # block; the reply's are the blocks the server received, whole
+    assert after["sliced_in_program"] - before["sliced_in_program"] \
+        == 3 * BLOCK // WINDOW
     for k in ("fallbacks", "build_failures", "match_timeouts"):
         assert after[k] == before[k], k
     # no profiler session, no span of the plane
@@ -230,12 +234,20 @@ PERMUTE = [("%collective-permute-done = u8[1,4194304]{1,0:T(4,128)(4,1)S(1)} "
             "{1,0:T(4,128)(4,1)S(1)} %collective-permute-done)", 0.04),
            ("%collective-permute-start = (u8[1,4194304]{1,0:T(4,128)(4,1)}, "
             "...) collective-permute-start(...", 0.01)]
-PALLAS = [("%brpc_device_plane_p2p = u8[4194304]{0} custom-call(...)", 0.2),
-          ("%copy-done = u8[67108864]{0:T(1024)(128)(4,1)S(1)} copy-done(...",
-           0.1)]
+# the two program shapes of a call (the request's cuts, the reply's whole
+# blocks) give instruction names that differ in their suffix alone
+TWO_SHAPES = [("%collective-permute-done.1 = u8[4194304]{0:T(1024)(128)(4,1)"
+               "S(1)} collective-permute-done(...", 0.1),
+              ("%collective-permute-done = u8[4194304]{0:T(1024)(128)(4,1)"
+               "S(1)} collective-permute-done(...", 0.09),
+              ("%collective-permute-start.1 = (u8[4194304]{0:T(1024)(128)"
+               "(4,1)}, ...) collective-permute-start(...", 0.01),
+              ("%dynamic_slice.3 = u8[4194304]{0:T(1024)(128)(4,1)S(1)} "
+               "dynamic-slice(...", 0.1)]
 
 
-@pytest.mark.parametrize("ops", [PERMUTE, PALLAS], ids=["ppermute", "pallas"])
+@pytest.mark.parametrize("ops", [PERMUTE, TWO_SHAPES],
+                         ids=["ppermute", "two_program_shapes"])
 def test_roofline_is_bytes_over_seconds_over_the_peak_whatever_the_kernel(
         ops):
     m = _roofline()
@@ -299,26 +311,34 @@ def test_the_configuration_states_its_source_and_its_cut():
     assert cfg["caller_device"] == 0 and cfg["chips"] == 4
     route = {r["counter"]: r["per_call_min"]
              for r in loader.load_cell(CELL).workload["route"]}
-    assert route == {"plane_transfers": 32, "ici_device_bytes": 2 << 26}
+    # the route is held in bytes through the plane, not in pieces: a larger
+    # piece or window moves the same bytes in fewer transfers
+    assert route == {"plane_bytes_sent": 2 << 26, "ici_device_bytes": 2 << 26}
+    assert "plane_transfers" not in route
+    assert "4 MB" in cfg["deployment"] and "kernel" not in cfg["deployment"]
 
 
 @pytest.mark.parametrize("blinded", [False, True],
-                         ids=["plane_taken", "plane_transfers_blinded"])
+                         ids=["plane_taken", "count_blinded"])
+@pytest.mark.parametrize("counter,a_call", [("plane_transfers", 4),
+                                            ("plane_bytes_sent", 2 << 23)])
 def test_route_check_holds_the_plane_to_its_transfers(
-        capsys, restore_mesh, host_mesh_plane, monkeypatch, blinded):
+        capsys, restore_mesh, host_mesh_plane, monkeypatch, counter, a_call,
+        blinded):
     """The rehearsal's 8 MiB blocks are two windows each way: four transfers
-    a call.  With the plane's count blinded the run is not correct."""
+    and 16 MiB through the plane a call (the cell's file holds the bytes).
+    With the plane's count blinded the run is not correct."""
     real_load, real_snapshot = loader.load_cell, counters.snapshot
 
     def with_the_planes_count(name, rehearse=False):
         cell = real_load(name, rehearse)
         cell.workload["route"] = cell.workload["route"] + [
-            {"counter": "plane_transfers", "per_call_min": 4}]
+            {"counter": counter, "per_call_min": a_call}]
         return cell
 
     def blind(servers):
         out = real_snapshot(servers)
-        out["plane_transfers"] = 0
+        out[counter] = 0
         return out
 
     monkeypatch.setattr(loader, "load_cell", with_the_planes_count)
@@ -326,13 +346,13 @@ def test_route_check_holds_the_plane_to_its_transfers(
         monkeypatch.setattr(counters, "snapshot", blind)
     rc, line, err = rehearse(capsys, CELL, "--trace", "0")
     assert line is not None, err[-2000:]
-    got = line["checks"]["plane_transfers_per_call"]
+    got = line["checks"][f"{counter}_per_call"]
     if blinded:
         assert line["correct"] is False and got["value"] == 0
-        assert "check plane_transfers_per_call: 0.0 >= 4 NOT OK" in err
+        assert f"check {counter}_per_call: 0.0 >= {a_call} NOT OK" in err
     else:
         assert line["correct"] is True, err[-2000:]
-        assert got["value"] == 4.0 and line["checks"][
+        assert got["value"] == float(a_call) and line["checks"][
             "second_route_events"]["value"] == 0
 
 

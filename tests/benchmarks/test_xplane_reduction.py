@@ -62,6 +62,62 @@ def test_gaps_go_to_the_annotation_that_covers_them():
     assert got == {"bench.handler.Echo": 4e-9}
 
 
+CALL = ("bench.call.Echo", 0, 1000)
+
+
+@pytest.mark.parametrize("host,idle,want", [
+    # a span of the program inside the benchmark's names the layer waited for
+    ([CALL, ("brpc.ici.stall", 100, 50)], [(110, 130)],
+     {"brpc.ici.stall": 20e-9}),
+    # the innermost of the program's: the one that began last and is open
+    ([CALL, ("brpc.ici.piece", 100, 200), ("brpc.plane.run", 150, 40)],
+     [(160, 180), (200, 220)],
+     {"brpc.plane.run": 20e-9, "brpc.ici.piece": 20e-9}),
+    # a span of the program wins over a benchmark annotation that began later
+    ([CALL, ("brpc.call.wait", 10, 900), ("bench.handler.Echo", 400, 100)],
+     [(420, 440)], {"brpc.call.wait": 20e-9}),
+    # under the benchmark's annotation alone a gap keeps its name
+    ([CALL, ("brpc.ici.stall", 100, 50)], [(500, 540)],
+     {"bench.call.Echo": 40e-9}),
+    # a span of the program that has ended covers nothing; nothing open
+    ([("bench.call.Echo", 0, 100), ("brpc.ici.stall", 10, 20)], [(200, 260)],
+     {"no benchmark span open": 60e-9}),
+    # gaps are taken in any order, each by its own middle
+    ([CALL, ("brpc.ici.gate", 700, 100), ("brpc.ici.stall", 100, 50)],
+     [(710, 730), (110, 130), (400, 410)],
+     {"brpc.ici.gate": 20e-9, "brpc.ici.stall": 20e-9,
+      "bench.call.Echo": 10e-9}),
+], ids=["program_span_inside_wins", "innermost_program_span",
+        "program_span_over_later_bench", "bench_alone_keeps_its_name",
+        "ended_span_covers_nothing", "gaps_in_any_order"])
+def test_gaps_name_the_layer_the_chip_waited_for(host, idle, want):
+    assert dict(xplane.attribute_gaps(idle, host)) == pytest.approx(want)
+
+
+@pytest.mark.parametrize("between", [0, 63, 64, 500, 20000])
+def test_a_cover_is_found_however_many_events_began_since(between):
+    """A bulk call records some two hundred layer spans: the enclosing
+    annotation can lie any number of events back."""
+    host = [("bench.call.Echo", 0, 10 ** 9)] + [
+        ("brpc.ici.piece", 10 + 3 * i, 2) for i in range(between)]
+    middle = 10 + 3 * between + 50
+    got = dict(xplane.attribute_gaps([(middle - 5, middle + 5)], host))
+    assert got == {"bench.call.Echo": 10e-9}
+    inside = [("brpc.call.wait", 5, 10 ** 9)] + host
+    got = dict(xplane.attribute_gaps([(middle - 5, middle + 5)], inside))
+    assert got == {"brpc.call.wait": 10e-9}
+
+
+def test_the_programs_spans_widen_no_traced_window():
+    chips = {0: [("op", 100, 10), ("op", 300, 20)], 1: [("op", 90, 5)]}
+    bench = [("bench.call.Echo", 80, 100), ("bench.handler.Echo", 310, 30)]
+    assert xplane.traced_window(chips, bench) == (80, 340)
+    program = [("brpc.poller.block", 0, 5000), ("brpc.call.wait", 335, 900)]
+    assert xplane.traced_window(chips, bench + program) == (80, 340)
+    assert xplane.traced_window({}, program) is None
+    assert xplane.traced_window({0: []}, []) is None
+
+
 def test_reduction_mean_busy_over_chips():
     red = xplane.Reduction(window_s=2.0, busy_s={0: 0.5, 1: 1.5})
     assert red.mean_busy_s() == 1.0
