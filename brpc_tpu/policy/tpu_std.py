@@ -194,6 +194,9 @@ def pack_request(payload: IOBuf, cid: int, cntl: Controller,
         meta.stream_settings.stream_id = cntl.stream_creator.sid
         meta.stream_settings.frame_type = 4
         meta.stream_settings.need_feedback = True
+        # the window this writer keeps: the far side counts an overrun
+        meta.stream_settings.write_window_bytes = \
+            cntl.stream_creator.options.max_buf_size
     meta.request.log_id = cntl.log_id
     meta.correlation_id = cid
     meta.compress_type = cntl.compress_type
@@ -256,7 +259,8 @@ def process_response(msg: StdMessage, socket) -> None:
             and cntl.stream_creator is not None):
         # handshake completion: server accepted our stream
         cntl.stream_creator.mark_connected(
-            msg.meta.stream_settings.remote_stream_id, socket)
+            msg.meta.stream_settings.remote_stream_id, socket,
+            msg.meta.stream_settings.write_window_bytes)
     cntl.handle_response(cid, msg.meta, msg.body)
 
 
@@ -322,15 +326,27 @@ def process_request(msg: StdMessage, socket, server) -> None:
         if cntl.retry_after_ms:
             # admission shed hint: how long the client should back off
             rmeta.response.retry_after_ms = cntl.retry_after_ms
-        if cntl.accepted_stream_id and not cntl.failed():
-            # complete the stream handshake: echo ids both ways
+        if cntl.accepted_stream_id:
             from ..rpc.stream import find_stream
             srv_stream = find_stream(cntl.accepted_stream_id)
             client_sid = meta.stream_settings.stream_id
-            if srv_stream is not None:
+            if srv_stream is None:
+                pass
+            elif cntl.failed():
+                # the establishing call failed after stream_accept: the
+                # client never learns the stream's id, so nothing else
+                # would ever close it
+                srv_stream.close()
+            else:
+                # complete the stream handshake: echo ids both ways, and
+                # say the window this side's writer keeps
                 rmeta.stream_settings.stream_id = client_sid
                 rmeta.stream_settings.remote_stream_id = cntl.accepted_stream_id
-                srv_stream.mark_connected(client_sid, socket)
+                rmeta.stream_settings.write_window_bytes = \
+                    srv_stream.options.max_buf_size
+                srv_stream.mark_connected(
+                    client_sid, socket,
+                    meta.stream_settings.write_window_bytes)
         payload = IOBuf()
         if resp is not None and not cntl.failed():
             data = resp.SerializeToString() if hasattr(resp, "SerializeToString") \

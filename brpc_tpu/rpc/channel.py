@@ -7,6 +7,8 @@ Controller; connection selection honors single/pooled/short types.
 """
 from __future__ import annotations
 
+import functools
+
 import threading
 from dataclasses import dataclass
 from typing import Any, Callable, Optional
@@ -91,6 +93,9 @@ class Channel:
         self.messenger = InputMessenger(server=None)
         self._native_ici = None
         self._native_ici_lock = threading.Lock()
+        # exclusive connections that streams of this channel ride
+        # (_on_call_end): out of the socket map, so close() fails them itself
+        self._stream_conns = set()
 
     # ---- init ---------------------------------------------------------
     def init(self, target: Any, lb_name: str = "",
@@ -611,6 +616,8 @@ class Channel:
                 nb.close()
             except Exception:
                 pass
+        for conn in list(self._stream_conns):
+            conn.set_failed(errors.ECLOSE, "channel closed")
         sig = self._channel_signature()
         smap = SocketMap.instance()
         if self._endpoint is not None:
@@ -669,12 +676,32 @@ class Channel:
             if dangling:
                 sock.set_failed(errors.ECLOSE,
                                 "own pipelined context still outstanding")
+        # a stream this call established rides its connection on: an
+        # exclusive connection stays the stream's until the stream closes,
+        # and is given back (or closed) then, not handed to the next call
+        stream = cntl.stream_creator
+
+        def release(conn, how) -> None:
+            def when_closed():
+                self._stream_conns.discard(conn)
+                how()
+
+            if stream is not None and stream.hold_connection(conn,
+                                                             when_closed):
+                self._stream_conns.add(conn)
+                if stream.closed:       # it closed in between: not held
+                    self._stream_conns.discard(conn)
+            else:
+                how()
+
         if ep is not None and sock is not None:
-            SocketMap.instance().return_pooled_socket(
-                ep, sock, group=self._channel_signature())
+            release(sock, functools.partial(
+                SocketMap.instance().return_pooled_socket, ep, sock,
+                group=self._channel_signature()))
         short = getattr(cntl, "_short_socket", None)
         if short is not None:
-            short.set_failed(errors.ECLOSE, "short connection done")
+            release(short, functools.partial(
+                short.set_failed, errors.ECLOSE, "short connection done"))
         sel = getattr(cntl, "_selected_endpoint", None)
         # an admission shed (retryable ELIMIT + retry_after_ms) is an
         # OVERLOADED-BUT-HEALTHY endpoint saying "not now" — it must not

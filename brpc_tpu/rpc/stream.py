@@ -16,14 +16,21 @@ Reference: src/brpc/stream.{h,cpp} + policy/streaming_rpc_protocol.cpp
 Frames are tpu_std RpcMeta envelopes with ``stream_settings.frame_type``:
 DATA / FEEDBACK / CLOSE; tpu_std routes them here from both server and
 client parse paths.
+
+Counters (``stream_stats()``, ``/vars`` ``rpc_stream_<key>``) are process-wide
+totals that survive a stream's close.  Layer spans (``butil/layer_span.py``,
+recorded only while a profiler session is on): ``brpc.stream.write``,
+``.stall``, ``.queue`` and ``.handler``; docs/OBSERVABILITY.md.
 """
 from __future__ import annotations
 
 import struct
 import time
-from typing import List, Optional
+from typing import Dict, List, Optional
 
+from .. import bvar
 from ..butil import flags as _flags
+from ..butil import layer_span as _span
 from ..butil.iobuf import IOBuf
 from ..butil import debug_sync as _dbg
 from ..butil.resource_pool import ResourcePool
@@ -61,6 +68,24 @@ FRAME_DATA_SHM_BATCH = 7
 _BULK_DESC = struct.Struct("<QQ")
 
 DEFAULT_MAX_BUF_SIZE = 2 * 1024 * 1024
+
+# process-wide totals, kept past a stream's close.  ``writer_parks``: a
+# ``write`` that really waited on its window; ``write_failures``: a DATA
+# frame the socket refused, or a ``write`` that timed out;
+# ``window_overruns``: a DATA frame that put a stream's unconsumed bytes
+# past the window its writer said it keeps (the flow-control guarantee,
+# counted on the receiving side so that a run can hold it at zero).
+_STAT_KEYS = ("data_frames_sent", "data_bytes_sent", "data_frames_received",
+              "data_bytes_received", "feedback_frames_sent",
+              "feedback_frames_received", "writer_parks",
+              "batches_delivered", "messages_delivered", "write_failures",
+              "window_overruns")
+_g = {k: bvar.Adder(f"rpc_stream_{k}") for k in _STAT_KEYS}
+
+
+def stream_stats() -> Dict[str, int]:
+    """What the streams of this process have done so far, live and closed."""
+    return {k: v.get_value() for k, v in _g.items()}
 
 # DATA frames at least this large ride the bulk fast plane when the
 # socket binds one (ici:// cross-process FabricSocket); below it the
@@ -126,6 +151,7 @@ class Stream:
         "_remote_consumed": "_flow_lock",
         "_exec": "_state_lock",
         "_sock_failed_cb": "_state_lock",
+        "_release_conn": "_state_lock",
         "_seq": "_wire_lock",
         "_pending_desc": "_wire_lock",
     }
@@ -142,13 +168,22 @@ class Stream:
         self._produced = 0
         self._remote_consumed = 0
         self._flow_lock = _dbg.make_lock("Stream._flow_lock")
+        # a generation, bumped by every feedback and by close: a writer
+        # reads it, looks at the window, and waits on the value it read
         self._writable_butex = Butex(0)
-        # receiver side
+        # receiver side.  _local_received and _n_received are the reader
+        # path's (frames arrive in cut order), _local_consumed and
+        # _n_delivered the consumer's: one writer each
+        self._local_received = 0
         self._local_consumed = 0
         self._last_feedback = 0
+        self._n_received = 0
+        self._n_delivered = 0
+        self._peer_max_buf = 0          # the writer's window; 0 = not said
         self.closed = False
         self._seq = 0
         self._sock_failed_cb = None     # registered at mark_connected
+        self._release_conn = None       # see hold_connection
         # guards the connected/closed transitions and the lazy _exec
         # creation: on_remote_close is runnable from ANY thread (socket
         # on_failed callbacks), and mark_connected has two concurrent
@@ -184,39 +219,73 @@ class Stream:
                     > self.options.max_buf_size:
                 return errors.EAGAIN
             self._produced += n
-        self._send_frame(FRAME_DATA, data)
+        try:
+            self._send_frame(FRAME_DATA, data)
+        except Exception:
+            _g["write_failures"] << 1
+            raise
+        _g["data_frames_sent"] << 1
+        _g["data_bytes_sent"] << n
         return 0
 
     def write(self, data: IOBuf, timeout: Optional[float] = None) -> int:
         """Blocking write: waits for window space (StreamWrite +
         StreamWait)."""
+        if not _span.layer_on():
+            return self._write(data, timeout)
+        ls = _span.layer_begin("brpc.stream.write", n=len(data))
+        try:
+            return self._write(data, timeout)
+        finally:
+            if ls is not None:
+                ls.end()
+
+    def _write(self, data: IOBuf, timeout: Optional[float]) -> int:
         deadline = None if timeout is None else time.monotonic() + timeout
-        while True:
-            rc = self.append_if_not_full(data)
-            if rc != errors.EAGAIN:
-                return rc
-            # about to park on a full window: the receiver can only
-            # return credits for frames it has been TOLD about — flush
-            # any coalesced shm descriptors first or the wait deadlocks
-            # until the linger timer fires
-            self._flush_pending()
-            remaining = None
-            if deadline is not None:
-                remaining = deadline - time.monotonic()
-                if remaining <= 0:
-                    return errors.ETIMEDOUT
-            self._writable_butex.set_value(0)
-            if self.writable_bytes() > len(data) or self.closed:
-                continue
-            self._writable_butex.wait(0, remaining if remaining is not None
-                                      else 1.0)
+        stall = None    # layer span brpc.stream.stall, once it really parks
+        try:
+            while True:
+                # the generation BEFORE the look at the window: a feedback
+                # that lands after the look has bumped it, and the wait
+                # below returns at once
+                gen = self._writable_butex.value
+                rc = self.append_if_not_full(data)
+                if rc != errors.EAGAIN:
+                    return rc
+                # about to park on a full window: the receiver can only
+                # return credits for frames it has been TOLD about — flush
+                # any coalesced shm descriptors first or the wait deadlocks
+                # until the linger timer fires
+                self._flush_pending()
+                remaining = None
+                if deadline is not None:
+                    remaining = deadline - time.monotonic()
+                    if remaining <= 0:
+                        _g["write_failures"] << 1
+                        return errors.ETIMEDOUT
+                if stall is None:
+                    _g["writer_parks"] << 1
+                    if _span.layer_on():
+                        stall = _span.layer_begin(
+                            "brpc.stream.stall",
+                            n=len(data) - self.writable_bytes())
+                self._writable_butex.wait(
+                    gen, remaining if remaining is not None else 1.0)
+        finally:
+            if stall is not None:
+                stall.end()
 
     def set_remote_consumed(self, consumed: int) -> None:
         """Feedback arrival: wake blocked writers (stream.cpp:307)."""
+        _g["feedback_frames_received"] << 1
         with self._flow_lock:
             if consumed > self._remote_consumed:
                 self._remote_consumed = consumed
-        self._writable_butex.wake_all_and_set(1)
+        self._wake_writers()
+
+    def _wake_writers(self) -> None:
+        self._writable_butex.fetch_add(1)
+        self._writable_butex.wake_all()
 
     # -- receiver -------------------------------------------------------
     _CLOSE_MARKER = object()
@@ -234,25 +303,53 @@ class Stream:
                 self._exec = ExecutionQueue(self._consume_batch,
                                             linger_s=0.005)
             ex = self._exec
-        ex.execute(data)
+        n = len(data)
+        self._local_received += n
+        if self._peer_max_buf and \
+                self._local_received - self._local_consumed \
+                > self._peer_max_buf:
+            _g["window_overruns"] << 1
+        _g["data_frames_received"] << 1
+        _g["data_bytes_received"] << n
+        # layer span brpc.stream.queue: from here until the message's
+        # batch enters the handler; n = messages ahead of it
+        mark = _span.layer_mark(self._n_received - self._n_delivered) \
+            if _span.layer_on() else None
+        self._n_received += 1
+        ex.execute((data, mark))
 
     def _consume_batch(self, it) -> None:
         msgs = []
+        marks = []
         fire_closed = False
         for m in it:
             if m is Stream._CLOSE_MARKER:
                 fire_closed = True
             else:
-                msgs.append(m)
+                msgs.append(m[0])
+                if m[1] is not None:
+                    marks.append(m[1])
         handler = self.options.handler
-        if msgs and handler is not None:
-            try:
-                handler.on_received_messages(self.sid, msgs)
-            except Exception:
-                from ..butil import logging as log
-                log.error("stream handler raised", exc_info=True)
         if msgs:
+            # what was delivered, taken before the handler sees it
+            # (upstream's rule): a handler may cut the buffers it is handed
             consumed = sum(len(m) for m in msgs)
+            _g["batches_delivered"] << 1
+            _g["messages_delivered"] << len(msgs)
+            for mark in marks:
+                _span.layer_waited("brpc.stream.queue", mark)
+            if handler is not None:
+                ls = _span.layer_begin("brpc.stream.handler", n=len(msgs)) \
+                    if _span.layer_on() else None
+                try:
+                    handler.on_received_messages(self.sid, msgs)
+                except Exception:
+                    from ..butil import logging as log
+                    log.error("stream handler raised", exc_info=True)
+                finally:
+                    if ls is not None:
+                        ls.end()
+            self._n_delivered += len(msgs)
             self._local_consumed += consumed
             # feedback when half a window was consumed since the last report
             if (self._local_consumed - self._last_feedback
@@ -268,6 +365,7 @@ class Stream:
         self._last_feedback = self._local_consumed
         self._send_frame(FRAME_FEEDBACK, None,
                          consumed_bytes=self._local_consumed)
+        _g["feedback_frames_sent"] << 1
 
     # -- lifecycle ------------------------------------------------------
     def wait_connected(self, timeout: float = 10.0) -> bool:
@@ -276,7 +374,12 @@ class Stream:
         self._conn_butex.wait(0, timeout)
         return self.connected
 
-    def mark_connected(self, remote_sid: int, socket) -> None:
+    def mark_connected(self, remote_sid: int, socket,
+                       peer_max_buf: int = 0) -> None:
+        """``peer_max_buf``: the window the far side's writer keeps, where
+        the handshake said it (a racing first frame does not)."""
+        if peer_max_buf:
+            self._peer_max_buf = peer_max_buf
         with self._state_lock:
             if self.connected or self.closed:
                 # connected: both the RPC-response path and a racing
@@ -303,6 +406,18 @@ class Stream:
             self.on_remote_close()
         self._conn_butex.wake_all_and_set(1)
 
+    def hold_connection(self, sock, release) -> bool:
+        """The call that established this stream ends while the stream
+        rides ``sock``, an exclusive (pooled or short) connection: it stays
+        the stream's, and ``release`` (back to the pool, or closed) runs
+        once the stream has closed.  False, and nothing kept, where the
+        stream is not riding ``sock`` (never connected, closed already)."""
+        with self._state_lock:
+            if self.closed or not self.connected or self.socket is not sock:
+                return False
+            self._release_conn = release
+            return True
+
     def close(self) -> None:
         with self._state_lock:
             if self.closed:
@@ -325,13 +440,14 @@ class Stream:
         self._flush_pending()
         with self._state_lock:
             cb, self._sock_failed_cb = self._sock_failed_cb, None
+            release, self._release_conn = self._release_conn, None
             sock = self.socket
         if cb is not None and sock is not None:
             try:
                 sock.on_failed_callbacks.remove(cb)
             except ValueError:
                 pass                     # set_failed already consumed it
-        self._writable_butex.wake_all_and_set(1)
+        self._wake_writers()
         with self._state_lock:
             # self.closed is already True (set by every caller), so no
             # NEW queue can appear after this read — on_data drops
@@ -349,6 +465,8 @@ class Stream:
                 except Exception:
                     pass
         _pool_remove(self.sid)
+        if release is not None:
+            release()
 
     def on_remote_close(self) -> None:
         with self._state_lock:

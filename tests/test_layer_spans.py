@@ -557,3 +557,132 @@ def test_a_thread_with_no_span_open_adopts_no_call(empty_store):
     by = {s.name: s.call_id for s in span.layer_spans()}
     assert by == {"brpc.before": 0, "brpc.inside": 77, "brpc.outer": 0,
                   "brpc.after": 0}
+
+
+# ---- the stream layer's four spans (ISSUE 33) --------------------------------
+
+STREAM_CHUNK = 100
+STREAM_SPANS = ("brpc.stream.write", "brpc.stream.stall",
+                "brpc.stream.queue", "brpc.stream.handler")
+_stream_names = iter(range(1 << 30))
+
+
+def _stream_exchange(chunks):
+    """An echo over one stream under a two-chunk window each way, the
+    server's handler 20 ms a message: the third write really waits.
+    Returns when every chunk is back."""
+    import threading
+    from brpc_tpu.butil.iobuf import IOBuf
+    got, lock = [], threading.Lock()
+
+    class Collect(rpc.StreamInputHandler):
+        def on_received_messages(self, sid, msgs):
+            with lock:
+                got.extend(m.to_bytes() for m in msgs)
+
+    class StreamLayerService(rpc.Service):
+        @rpc.method(EchoRequest, EchoResponse)
+        def StartStream(self, cntl, request, response, done):
+            class Back(rpc.StreamInputHandler):
+                stream = None
+
+                def on_received_messages(self, sid, msgs):
+                    for m in msgs:
+                        time.sleep(0.02)
+                        self.stream.write(m, timeout=10)
+
+            h = Back()
+            h.stream = rpc.stream_accept(cntl, rpc.StreamOptions(
+                handler=h, max_buf_size=2 * STREAM_CHUNK))
+            done()
+
+    server = rpc.Server()
+    server.add_service(StreamLayerService())
+    target = f"mem://layer-stream-{next(_stream_names)}"
+    assert server.start(target) == 0
+    try:
+        ch = rpc.Channel()
+        ch.init(target)
+        cntl = rpc.Controller()
+        stream = rpc.stream_create(cntl, rpc.StreamOptions(
+            handler=Collect(), max_buf_size=2 * STREAM_CHUNK))
+        ch.call_method("StreamLayerService.StartStream", cntl,
+                       EchoRequest(message="s"), EchoResponse)
+        assert not cntl.failed() and stream.wait_connected(5)
+        for i in range(chunks):
+            assert stream.write(IOBuf(b"x" * STREAM_CHUNK), timeout=10) == 0
+        deadline = time.monotonic() + 10
+        while len(got) < chunks and time.monotonic() < deadline:
+            time.sleep(0.005)
+        assert len(got) == chunks
+        # the client's consumer leaves its handler span after `got` grows
+        while stream._n_delivered < chunks and time.monotonic() < deadline:
+            time.sleep(0.005)
+        stream.close()
+    finally:
+        server.stop()
+
+
+@pytest.fixture
+def stream_spans(monkeypatch):
+    """The spans of a six-chunk exchange, the sites switched on without a
+    profiler session (as ``three_piece_write``)."""
+    import jax.profiler  # noqa: F401
+    layer_span.layer_on()   # binds the annotation class
+    span.layer_spans_reset()
+    monkeypatch.setattr(layer_span, "layer_on", lambda: True)
+    try:
+        _stream_exchange(6)
+        yield [s for s in span.layer_spans()
+               if s.name.startswith("brpc.stream.")]
+    finally:
+        monkeypatch.undo()
+        span.layer_spans_reset()
+
+
+def test_no_session_no_stream_span(empty_store):
+    _stream_exchange(3)
+    assert [s for s in span.layer_spans()
+            if s.name.startswith("brpc.stream.")] == []
+
+
+@pytest.mark.parametrize("name", STREAM_SPANS)
+def test_stream_span_is_recorded_with_its_n_and_its_cause(stream_spans,
+                                                         name):
+    spans = stream_spans
+    assert {s.name for s in spans} == set(STREAM_SPANS)
+    mine = _named(spans, name)
+    by_id = {s.span_id: s for s in spans}
+    handlers = _named(spans, "brpc.stream.handler")
+    writes = _named(spans, "brpc.stream.write")
+    if name == "brpc.stream.write":
+        # six by the caller, six by the server's handler writing back
+        assert len(mine) == 12 and all(s.n == STREAM_CHUNK for s in mine)
+        back = [s for s in mine if s.cause_id]
+        assert len(back) == 6
+        for s in back:                  # the handler is their cause
+            h = by_id[s.cause_id]
+            assert h.name == "brpc.stream.handler" and h.thread == s.thread
+            assert h.start_ns <= s.start_ns <= s.end_ns <= h.end_ns
+    if name == "brpc.stream.stall":
+        # only a write that really parked has one, inside it; n = the bytes
+        # the window was short of
+        assert 1 <= len(mine) < len(writes)
+        for s in mine:
+            w = by_id[s.cause_id]
+            assert w.name == "brpc.stream.write" and w.thread == s.thread
+            assert w.start_ns <= s.start_ns <= s.end_ns <= w.end_ns
+            assert 0 < s.n <= STREAM_CHUNK
+        assert max(s.end_ns - s.start_ns for s in mine) >= 10e6
+    if name == "brpc.stream.queue":
+        # one a message, each way; n = messages ahead of it
+        assert len(mine) == 12 and all(0 <= s.n < 6 for s in mine)
+        for s in mine:                  # it ends where a batch's handler
+            assert any(abs(h.start_ns - s.end_ns) < 5e6   # begins
+                       and h.start_ns >= s.end_ns for h in handlers), s
+    if name == "brpc.stream.handler":
+        # n = the batch's messages: twelve were delivered in all
+        assert sum(s.n for s in mine) == 12 and all(s.n >= 1 for s in mine)
+        assert all(s.cause_id == 0 for s in mine)
+        # the server's take 20 ms a message
+        assert max(s.end_ns - s.start_ns for s in mine) >= 20e6

@@ -458,3 +458,423 @@ class TestStreamBulkRouting:
         finally:
             send.close()
             recv.close()
+
+
+# ---- flow-control accounting, counters (ISSUE 33) -------------------------
+
+CHUNK = 100
+
+
+class CuttingEchoService(rpc.Service):
+    """Accepts a stream under a two-chunk window; its handler CUTS every
+    message it is handed to empty (as a handler that takes the bytes out
+    does) and writes them back."""
+
+    def __init__(self, delay_s=0.0):
+        self.delay_s = delay_s
+        self.server_streams = []
+
+    @rpc.method(EchoRequest, EchoResponse)
+    def StartStream(self, cntl, request, response, done):
+        outer = self
+
+        class CutBack(rpc.StreamInputHandler):
+            stream = None
+
+            def on_received_messages(self, sid, msgs):
+                for m in msgs:
+                    if outer.delay_s:
+                        time.sleep(outer.delay_s)
+                    taken = m.cut(len(m))
+                    assert len(m) == 0
+                    self.stream.write(taken, timeout=10)
+
+        h = CutBack()
+        h.stream = rpc.stream_accept(cntl, rpc.StreamOptions(
+            handler=h, max_buf_size=2 * CHUNK))
+        outer.server_streams.append(h.stream)
+        response.message = "accepted"
+        done()
+
+
+class CuttingCollector(rpc.StreamInputHandler):
+    def __init__(self):
+        self.got = []
+        self.lock = threading.Lock()
+
+    def on_received_messages(self, sid, msgs):
+        with self.lock:
+            for m in msgs:
+                self.got.append(m.cut(len(m)).to_bytes())
+
+
+def _open_cutting_stream(delay_s=0.0):
+    server = rpc.Server()
+    svc = CuttingEchoService(delay_s)
+    server.add_service(svc)
+    target = f"mem://{unique()}"
+    assert server.start(target) == 0
+    ch = rpc.Channel()
+    ch.init(target)
+    collector = CuttingCollector()
+    cntl = rpc.Controller()
+    stream = rpc.stream_create(cntl, rpc.StreamOptions(
+        handler=collector, max_buf_size=2 * CHUNK))
+    ch.call_method("CuttingEchoService.StartStream", cntl,
+                   EchoRequest(message="s"), EchoResponse)
+    assert not cntl.failed(), cntl.error_text
+    assert stream.wait_connected(5)
+    return server, svc, stream, collector
+
+
+def _wait_for(cond, seconds=10.0):
+    deadline = time.monotonic() + seconds
+    while not cond() and time.monotonic() < deadline:
+        time.sleep(0.005)
+    return cond()
+
+
+class TestFlowControlAccounting:
+    def test_handler_that_cuts_every_message_still_returns_full_credit(self):
+        """Consumed bytes are what was DELIVERED, taken before the handler
+        sees the buffers: a handler that cuts them to empty used to report
+        nothing, no feedback went out, and a two-chunk window wedged after
+        two chunks until the writer's timeout."""
+        server, svc, stream, collector = _open_cutting_stream()
+        try:
+            t0 = time.monotonic()
+            for i in range(8):
+                assert stream.write(IOBuf(bytes([65 + i]) * CHUNK),
+                                    timeout=5) == 0, i
+            assert _wait_for(lambda: len(collector.got) == 8)
+            assert time.monotonic() - t0 < 4.0      # no write sat out 5 s
+            assert collector.got == [bytes([65 + i]) * CHUNK
+                                     for i in range(8)]
+            # both receivers counted every byte they were handed
+            assert svc.server_streams[0]._local_consumed == 8 * CHUNK
+            assert _wait_for(lambda: stream._local_consumed == 8 * CHUNK)
+            stream.close()
+        finally:
+            server.stop()
+
+    def test_feedback_that_reopens_exactly_one_chunk_resumes_the_writer(self):
+        """A chunk that fits the freed window EXACTLY is written, not
+        parked; and a feedback that lands between the refusal and the park
+        is not lost.  (The server keeps the default 2 MB window, so it sends
+        no feedback of its own for these few bytes.)"""
+        server, svc, target = start_streaming_server()
+        try:
+            ch = rpc.Channel(); ch.init(target)
+            cntl = rpc.Controller()
+            stream = rpc.stream_create(cntl, rpc.StreamOptions(
+                handler=Collector(), max_buf_size=2 * CHUNK))
+            ch.call_method("StreamingEchoService.StartStream", cntl,
+                           EchoRequest(message="s"), EchoResponse)
+            assert stream.wait_connected(5)
+            assert stream.write(IOBuf(b"a" * CHUNK)) == 0
+            assert stream.write(IOBuf(b"b" * CHUNK)) == 0   # window full
+            assert stream.writable_bytes() == 0
+            # (1) the feedback arrives while the writer is parked
+            done = []
+            w = threading.Thread(target=lambda: done.append(
+                (stream.write(IOBuf(b"c" * CHUNK), timeout=10),
+                 time.monotonic())))
+            w.start()
+            time.sleep(0.1)
+            assert not done
+            t_feedback = time.monotonic()
+            stream.set_remote_consumed(CHUNK)       # room for exactly one
+            w.join(10)
+            assert done and done[0][0] == 0
+            assert done[0][1] - t_feedback < 0.5    # not the butex's 1 s
+            # (2) the feedback arrives between the refusal and the park:
+            # _flush_pending is the one step a writer takes between them
+            assert stream.writable_bytes() == 0
+            real_flush, fired = stream._flush_pending, []
+
+            def feedback_in_the_gap():
+                if not fired:
+                    fired.append(1)
+                    stream.set_remote_consumed(2 * CHUNK)
+                real_flush()
+
+            stream._flush_pending = feedback_in_the_gap
+            t0 = time.monotonic()
+            assert stream.write(IOBuf(b"d" * CHUNK), timeout=10) == 0
+            assert time.monotonic() - t0 < 0.5
+            assert fired
+            stream.close()
+        finally:
+            server.stop()
+
+    def test_stream_stats_over_a_known_exchange_and_after_close(self):
+        from brpc_tpu import bvar
+        from brpc_tpu.rpc.stream import stream_stats
+        keys = {"data_frames_sent", "data_bytes_sent",
+                "data_frames_received", "data_bytes_received",
+                "feedback_frames_sent", "feedback_frames_received",
+                "writer_parks", "batches_delivered", "messages_delivered",
+                "write_failures", "window_overruns"}
+        before = stream_stats()
+        assert set(before) == keys
+        # a server that takes 20 ms a chunk: the third write finds the
+        # two-chunk window full and really waits
+        server, svc, stream, collector = _open_cutting_stream(delay_s=0.02)
+        try:
+            for i in range(6):
+                assert stream.write(IOBuf(b"x" * CHUNK), timeout=10) == 0
+            assert _wait_for(lambda: len(collector.got) == 6)
+            assert _wait_for(lambda: stream._local_consumed == 6 * CHUNK)
+
+            def delta():
+                now = stream_stats()
+                return {k: now[k] - before[k] for k in keys}
+
+            # every feedback that was sent has arrived
+            assert _wait_for(lambda: delta()["feedback_frames_received"]
+                             == delta()["feedback_frames_sent"])
+            d = delta()
+            assert d["data_frames_sent"] == d["data_frames_received"] == 12
+            assert d["data_bytes_sent"] == d["data_bytes_received"] \
+                == 12 * CHUNK
+            assert d["messages_delivered"] == 12
+            assert 2 <= d["batches_delivered"] <= 12
+            # feedback goes out once half a window (one chunk) was consumed:
+            # at most one a message, at least one a batch of two
+            assert 6 <= d["feedback_frames_sent"] <= 12
+            assert d["writer_parks"] >= 1
+            assert d["write_failures"] == 0 and d["window_overruns"] == 0
+            stream.close()
+            assert _wait_for(lambda: svc.server_streams[0].closed)
+            # totals survive the streams
+            after = delta()
+            for k in ("data_frames_sent", "data_bytes_sent",
+                      "messages_delivered", "writer_parks"):
+                assert after[k] == d[k], k
+            # and /vars has them under rpc_stream_<key>
+            exposed = set(bvar.list_exposed())
+            assert {f"rpc_stream_{k}" for k in keys} <= exposed
+            assert int(bvar.find_exposed(
+                "rpc_stream_data_frames_sent").get_value()) \
+                == stream_stats()["data_frames_sent"]
+        finally:
+            server.stop()
+
+    def test_write_that_times_out_is_a_write_failure(self):
+        from brpc_tpu.rpc.stream import stream_stats
+        server, svc, target = start_streaming_server()
+        try:
+            ch = rpc.Channel(); ch.init(target)
+            cntl = rpc.Controller()
+            stream = rpc.stream_create(cntl, rpc.StreamOptions(
+                handler=Collector(), max_buf_size=CHUNK))
+            ch.call_method("StreamingEchoService.StartStream", cntl,
+                           EchoRequest(message="s"), EchoResponse)
+            assert stream.wait_connected(5)
+            assert stream.write(IOBuf(b"a" * CHUNK)) == 0
+            before = stream_stats()
+            assert stream.write(IOBuf(b"b" * CHUNK), timeout=0.05) \
+                == errors.ETIMEDOUT
+            now = stream_stats()
+            assert now["write_failures"] - before["write_failures"] == 1
+            assert now["writer_parks"] - before["writer_parks"] == 1
+            assert stream._produced == CHUNK     # nothing more went out
+            stream.close()
+        finally:
+            server.stop()
+
+    def test_receiver_counts_a_frame_beyond_the_writers_window(self):
+        """The handshake tells each side the window the other's writer
+        keeps; a frame that puts the unconsumed bytes past it is counted
+        (a writer that keeps its window never causes one)."""
+        from brpc_tpu.rpc.stream import stream_stats
+        gate = threading.Event()
+
+        class Held(rpc.StreamInputHandler):
+            def on_received_messages(self, sid, msgs):
+                gate.wait(5)
+
+        class HoldingService(rpc.Service):
+            streams = []
+
+            @rpc.method(EchoRequest, EchoResponse)
+            def StartStream(self, cntl, request, response, done):
+                self.streams.append(rpc.stream_accept(
+                    cntl, rpc.StreamOptions(handler=Held())))
+                done()
+
+        server = rpc.Server()
+        svc = HoldingService()
+        server.add_service(svc)
+        target = f"mem://{unique()}"
+        assert server.start(target) == 0
+        try:
+            ch = rpc.Channel(); ch.init(target)
+            cntl = rpc.Controller()
+            stream = rpc.stream_create(cntl, rpc.StreamOptions(
+                handler=Collector(), max_buf_size=2 * CHUNK))
+            ch.call_method("HoldingService.StartStream", cntl,
+                           EchoRequest(message="s"), EchoResponse)
+            assert stream.wait_connected(5)
+            assert svc.streams[0]._peer_max_buf == 2 * CHUNK
+            assert stream._peer_max_buf == svc.streams[0].options.max_buf_size
+            before = stream_stats()["window_overruns"]
+            assert stream.write(IOBuf(b"a" * CHUNK)) == 0
+            assert stream.write(IOBuf(b"b" * CHUNK)) == 0
+            assert _wait_for(lambda: svc.streams[0]._local_received
+                             == 2 * CHUNK)
+            assert stream_stats()["window_overruns"] == before
+            # a writer that breaks its word: a third chunk, unconsumed
+            # bytes 300 against the 200 it said
+            stream._send_frame(0, IOBuf(b"c" * CHUNK))
+            assert _wait_for(lambda: stream_stats()["window_overruns"]
+                             == before + 1)
+            gate.set()
+            stream.close()
+        finally:
+            gate.set()
+            server.stop()
+
+    def test_failed_establishing_call_leaves_no_accepted_stream(self):
+        """A handler that accepts a stream and then fails the call: the
+        client never learns the stream's id, so the server closes it."""
+        from brpc_tpu.rpc.stream import find_stream
+        accepted = []
+
+        class FailingService(rpc.Service):
+            @rpc.method(EchoRequest, EchoResponse)
+            def StartStream(self, cntl, request, response, done):
+                accepted.append(rpc.stream_accept(
+                    cntl, rpc.StreamOptions(handler=Collector())))
+                cntl.set_failed(errors.EINTERNAL, "no")
+                done()
+
+        server = rpc.Server()
+        server.add_service(FailingService())
+        target = f"mem://{unique()}"
+        assert server.start(target) == 0
+        try:
+            ch = rpc.Channel(); ch.init(target)
+            cntl = rpc.Controller()
+            stream = rpc.stream_create(cntl, rpc.StreamOptions(
+                handler=Collector()))
+            ch.call_method("FailingService.StartStream", cntl,
+                           EchoRequest(message="s"), EchoResponse)
+            assert cntl.failed()
+            assert _wait_for(lambda: accepted[0].closed)
+            assert find_stream(accepted[0].sid) is None
+            stream.close()
+        finally:
+            server.stop()
+
+    @pytest.mark.parametrize("ctype", ["pooled", "short"])
+    def test_stream_keeps_its_exclusive_connection_until_it_closes(self,
+                                                                   ctype):
+        """A pooled connection belongs to one call at a time; a stream that
+        the call established rides it on, so it is not handed to the next
+        call (two streams on one connection stack their windows on ONE
+        socket window) until the stream has closed.  A short connection is
+        closed then, not at the call's end under the stream."""
+        server, svc, target = start_streaming_server()
+        try:
+            streams, socks = [], []
+            for _ in range(2):          # one after the other, as callers do
+                ch = rpc.Channel()
+                ch.init(target, options=rpc.ChannelOptions(
+                    connection_type=ctype))
+                cntl = rpc.Controller()
+                s = rpc.stream_create(cntl, rpc.StreamOptions(
+                    handler=Collector()))
+                ch.call_method("StreamingEchoService.StartStream", cntl,
+                               EchoRequest(message="s"), EchoResponse)
+                assert not cntl.failed() and s.wait_connected(5)
+                streams.append(s)
+                socks.append(s.socket)
+            assert socks[0] is not socks[1]
+            assert not socks[0].failed and not socks[1].failed
+            for s in streams:           # both streams live and writable
+                assert s.write(IOBuf(b"x" * 10), timeout=5) == 0
+            streams[0].close()
+            if ctype == "short":
+                assert _wait_for(lambda: socks[0].failed)
+            else:
+                # back in the pool: the next call takes it
+                cntl = rpc.Controller()
+                third = rpc.stream_create(cntl, rpc.StreamOptions(
+                    handler=Collector()))
+                ch.call_method("StreamingEchoService.StartStream", cntl,
+                               EchoRequest(message="s"), EchoResponse)
+                assert not cntl.failed() and third.wait_connected(5)
+                assert third.socket is socks[0]
+                third.close()
+            streams[1].close()
+        finally:
+            server.stop()
+
+    def test_many_writers_on_one_window_lose_no_wake_up(self):
+        """Eight writers share one two-chunk window: every feedback bumps
+        the generation every parked writer waits on, so none sleeps through
+        an open window until the butex's 1 s re-check (a writer's
+        ``set_value(0)`` used to erase the feedback another was about to
+        wait on)."""
+        import sys
+        server, svc, stream, collector = _open_cutting_stream()
+        writers, each = 8, 25
+        failed = []
+
+        def body(k):
+            for i in range(each):
+                if stream.write(IOBuf(bytes([97 + k]) * CHUNK),
+                                timeout=20) != 0:
+                    failed.append((k, i))
+
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            t0 = time.monotonic()
+            threads = [threading.Thread(target=body, args=(k,))
+                       for k in range(writers)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(30)
+            assert not any(t.is_alive() for t in threads)
+            assert failed == []
+            assert _wait_for(lambda: len(collector.got) == writers * each)
+            took = time.monotonic() - t0
+            # 200 chunks through a window of two: a lost wake-up costs a
+            # second each, and there were dozens of chances
+            assert took < 8.0, took
+            # per-writer order is the stream's order
+            for k in range(writers):
+                assert sum(1 for m in collector.got
+                           if m[:1] == bytes([97 + k])) == each
+            stream.close()
+        finally:
+            sys.setswitchinterval(old)
+            server.stop()
+
+    def test_closing_the_channel_closes_the_streams_own_connection(self):
+        """The connection a stream holds is out of the socket map, so the
+        channel's close fails it itself, and the stream closes with it, as
+        it did when the connection lay in the pool."""
+        server, svc, target = start_streaming_server()
+        try:
+            ch = rpc.Channel()
+            ch.init(target, options=rpc.ChannelOptions(
+                connection_type="pooled"))
+            cntl = rpc.Controller()
+            collector = Collector()
+            s = rpc.stream_create(cntl, rpc.StreamOptions(handler=collector))
+            ch.call_method("StreamingEchoService.StartStream", cntl,
+                           EchoRequest(message="s"), EchoResponse)
+            assert not cntl.failed() and s.wait_connected(5)
+            sock = s.socket
+            assert ch._stream_conns == {sock}
+            ch.close()
+            assert sock.failed
+            assert _wait_for(lambda: s.closed)
+            assert collector.closed.wait(5)
+            assert ch._stream_conns == set()
+        finally:
+            server.stop()

@@ -253,3 +253,26 @@ def test_serving_compiled_step(one_chip, batch, width):
     finally:
         sched.stop()
         pool.close()
+
+
+# ---- the streaming cell's two device programs -----------------------------
+
+def test_stream_cell_programs_at_the_cells_size(one_chip):
+    """``stream_1m`` keeps to one slice shape and one xor program a run: the
+    client's ``block[a:b]`` of a 64 MiB resident block and the handler's
+    jitted xor of a 1 MiB chunk (benchmarks/services/StartStream.py), at the
+    cell's own sizes."""
+    import os
+    import sys
+    import jax
+    import jax.numpy as jnp
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if repo not in sys.path:
+        sys.path.insert(0, repo)
+    from benchmarks.services.StartStream import transform
+    chunk = jax.ShapeDtypeStruct((MB,), jnp.uint8, sharding=one_chip)
+    xor = transform.lower(chunk).compile()
+    assert xor.memory_analysis().output_size_in_bytes == MB
+    block = jax.ShapeDtypeStruct((64 * MB,), jnp.uint8, sharding=one_chip)
+    cut = jax.jit(lambda b: b[3 * MB:4 * MB]).lower(block).compile()
+    assert cut.memory_analysis().output_size_in_bytes == MB
