@@ -2,7 +2,7 @@
 recorded inside the program while a jax profiler session is on.
 
 A record is ``(name, start_ns, end_ns, call_id, span_id, cause_id, thread,
-n)`` on ``time.perf_counter_ns()`` (CLOCK_MONOTONIC, as ``monotonic_ns`` and
+n[, m])`` on ``time.perf_counter_ns()`` (CLOCK_MONOTONIC, as ``monotonic_ns`` and
 the native tier's ``recv_ns``).  ``cause_id`` is the enclosing span on the
 same thread, or, for work that crossed threads (a poller entry, a delivery
 gate), the span that was open where it was submitted.  Records go to
@@ -40,7 +40,8 @@ class LayerSpan(NamedTuple):
     span_id: int
     cause_id: int                 # enclosing or submitting span, 0 = none
     thread: str
-    n: int                        # the site's one integer (bytes, depth)
+    n: int                        # the site's integer (bytes, depth)
+    m: int = 0                    # a second one, where a site has two
 
 
 class LayerMark(NamedTuple):
@@ -167,7 +168,7 @@ class _OpenLayerSpan:
     their cause), on the profiler's host plane for the same stretch, and
     in the store from ``finish``.  ``end`` is the two together."""
     __slots__ = ("name", "start_ns", "call_id", "span_id", "cause_id", "n",
-                 "_st", "_prev_id", "_prev_call", "_note")
+                 "m", "_st", "_prev_id", "_prev_call", "_note")
 
     def leave(self) -> None:
         st = self._st
@@ -183,7 +184,7 @@ class _OpenLayerSpan:
         st.records.append((
             self.name, self.start_ns, end_ns or time.perf_counter_ns(),
             self.call_id, self.span_id, self.cause_id, self._st.name,
-            self.n))
+            self.n, self.m))
 
     def end(self) -> None:
         end_ns = time.perf_counter_ns()
@@ -192,7 +193,8 @@ class _OpenLayerSpan:
 
 
 def layer_begin(name: str, call_id: int = 0, n: int = 0,
-                mark: Optional[LayerMark] = None) -> Optional[_OpenLayerSpan]:
+                mark: Optional[LayerMark] = None,
+                m: int = 0) -> Optional[_OpenLayerSpan]:
     """Open a lexical span on this thread; ``None`` once the session has
     used its cap.  Call only under ``layer_on()`` (or with a ``mark`` taken
     under it).  ``mark`` names the submitter as the cause where the work
@@ -206,6 +208,7 @@ def layer_begin(name: str, call_id: int = 0, n: int = 0,
     ls.name = name
     ls.span_id = sid
     ls.n = n
+    ls.m = m
     if mark is not None:
         ls.cause_id = mark.span_id
         ls.call_id = call_id = call_id or mark.call_id
@@ -231,7 +234,7 @@ def layer_spans(since_ns: int = 0, until_ns: Optional[int] = None,
     thread's, by start time."""
     with _threads_lock:
         threads = list(_threads)
-    out = [LayerSpan._make(r) for st in threads for r in list(st.records)
+    out = [LayerSpan(*r) for st in threads for r in list(st.records)
            if r[2] >= since_ns and (until_ns is None or r[1] <= until_ns)
            and (name is None or r[0] == name)]
     out.sort(key=lambda r: (r.start_ns, r.span_id))

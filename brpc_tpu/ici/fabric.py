@@ -1944,7 +1944,10 @@ class FabricSocket(CreditWindow, OrderedDelivery, Socket):
             _send_frame(self._conn, ftype, body)
 
     def _do_write(self, data: IOBuf) -> int:
-        n = self._consume_window(len(data))
+        # DEVICE bytes go a whole piece at a time, as on an IciSocket; the
+        # header is charged (no borrowed window on this transport)
+        device = self._device_lead(data) is not None
+        n = self._consume_window(len(data), 0 if device else None)
         if n < 0:
             return -1
         frame = data.cut(n)
@@ -2816,12 +2819,12 @@ class FabricSocket(CreditWindow, OrderedDelivery, Socket):
         # completions rather than acking every read): parsers consume the
         # inbox in many small cuts, and a CREDIT frame per cut measured
         # ~66 tiny control sends per bulk chunk.  Deferring the return
-        # until window/8 keeps the sender pumping (7/8 of the window is
-        # still credited) at 1/66th the control traffic.
+        # until window/8 (``credit_batch``) keeps the sender pumping (7/8
+        # of the window is still credited) at 1/66th the control traffic.
         flush = 0
         with self._inbox_lock:
             self._consumed_unacked += n
-            if (self._consumed_unacked >= self.window_bytes // 8
+            if (self._consumed_unacked >= self.credit_batch
                     or self._peer_closed):
                 flush = self._consumed_unacked
                 self._consumed_unacked = 0

@@ -50,9 +50,12 @@ from .mesh import IciMesh
 _ici_stats_lock = _dbg.make_lock("ici.transport._ici_stats_lock")
 _ici_bytes_moved = 0
 _ici_device_bytes_moved = 0
-# window pieces whose header rode on borrowed window (CreditWindow), and
-# DEVICE refs that crossed under the plane's threshold by slice + device_put
+# window pieces whose header rode on borrowed window (CreditWindow), pieces
+# cut while earlier bytes of their socket were still un-consumed at the peer
+# (the window held more than one), and DEVICE refs that crossed under the
+# plane's threshold by slice + device_put
 _g_borrowed_headers = bvar.Adder("ici_transport_borrowed_header_pieces")
+_g_pipelined_pieces = bvar.Adder("ici_transport_pipelined_pieces")
 _g_small_relocations = bvar.Adder("ici_transport_small_relocations")
 
 # fablint guarded-state contract for the module-level registries
@@ -73,7 +76,16 @@ _GUARDED_BY_GLOBALS = {
 # tasklet blocks until the reader drains.  This bounds the peer inbox (a
 # slow reader exerts backpressure instead of growing memory) — the
 # flow-control VERDICT.md item #3.
-_flags.define_flag("ici_socket_window_bytes", 4 * 1024 * 1024,
+#
+# The window and the piece are two numbers.  DEVICE bytes cross in pieces
+# of PIECE_BYTES (a narrower window's piece is the window), so the slice
+# shapes and the compiled transfer programs are one set whatever the
+# window; the window is several pieces wide, so that the writer cuts and
+# posts piece k+1 while piece k is on the wire, at the poller or on its
+# credit's way back (upstream's ``_window_size`` is 128 work requests, not
+# one).  PERF.md §6 PR 34 has the stall at 2, 4 and 8 pieces.
+PIECE_BYTES = 4 * 1024 * 1024
+_flags.define_flag("ici_socket_window_bytes", 4 * PIECE_BYTES,
                    "per-ici-socket send window (unconsumed bytes at peer)",
                    _flags.positive_integer)
 
@@ -85,9 +97,12 @@ def ici_transport_stats() -> Tuple[int, int]:
 
 def ici_piece_stats() -> Dict[str, int]:
     """How the window pieces were cut and relocated, process-wide: pieces
-    that carried their header on borrowed window, and DEVICE refs that
-    crossed chips under the device plane's threshold (slice + device_put)."""
+    that carried their header on borrowed window, pieces cut while earlier
+    bytes of the same socket were still un-consumed at the peer, and DEVICE
+    refs that crossed chips under the device plane's threshold (slice +
+    device_put)."""
     return {"borrowed_header_pieces": _g_borrowed_headers.get_value(),
+            "pipelined_pieces": _g_pipelined_pieces.get_value(),
             "small_relocations": _g_small_relocations.get_value()}
 
 
@@ -98,24 +113,41 @@ class CreditWindow:
 
     Contract for the host class (a Socket subclass): call
     ``_init_window(window_bytes)`` in __init__, gate each ``_do_write``
-    through ``_consume_window(len)``, and call ``_on_credits(n)`` when the
-    peer reports n consumed bytes.  A writer stalled past the
-    ``_wait_writable`` timeout FAILS the socket — pending writes complete
-    with an error instead of silently wedging forever.
+    through ``_consume_window(len, lead)``, call ``_on_credits(n)`` when
+    the peer reports n consumed bytes, and hold back at most
+    ``credit_batch`` consumed bytes before reporting them.  A writer
+    stalled past the ``_wait_writable`` timeout FAILS the socket — pending
+    writes complete with an error instead of silently wedging forever.
 
     The bound: un-consumed bytes at the peer never pass ``window_bytes``
     plus the one header a piece may borrow (``_consume_window``'s
     ``lead``), which the caller keeps under
     ``min(ici_device_plane_threshold, window_bytes)``.  A host class that
-    passes no ``lead`` (FabricSocket) stays at ``window_bytes``."""
+    borrows no header (FabricSocket) stays at ``window_bytes``.
 
-    _GUARDED_BY = {"_send_window": "_window_lock"}
+    The piece: DEVICE bytes are cut ``piece_bytes`` at a time —
+    ``PIECE_BYTES``, or the window where that is narrower — so a window of
+    several pieces keeps several on their way at once, each of the one
+    shape."""
+
+    _GUARDED_BY = {"_send_window": "_window_lock",
+                   "_window_need": "_window_lock"}
 
     # fablint: init
     def _init_window(self, window_bytes: Optional[int]) -> None:
         self.window_bytes = (window_bytes if window_bytes is not None
                              else _flags.get_flag("ici_socket_window_bytes"))
+        self.piece_bytes = min(PIECE_BYTES, self.window_bytes)
+        # what a reader may sit on before it returns credits: an eighth of
+        # the window, and never so much that a writer parked for a whole
+        # piece could be waiting for bytes the peer holds back
+        self.credit_batch = self.window_bytes // 8
+        if self.piece_bytes < self.window_bytes:
+            self.credit_batch = min(self.credit_batch,
+                                    self.window_bytes - self.piece_bytes)
         self._send_window = self.window_bytes
+        self._window_need = 1             # what the parked writer waits for
+        self._cut_backlog = 0             # un-consumed bytes at the last cut
         self._window_lock = _dbg.make_lock("CreditWindow._window_lock")
         self._window_gen = Butex(0)       # bumped whenever credits return
 
@@ -129,26 +161,47 @@ class CreditWindow:
         with self._window_lock:
             return self.window_bytes - self._send_window
 
-    def _consume_window(self, want: int, lead: int = 0) -> int:
-        """Take up to ``want`` bytes of window; -1 when the window is
-        closed (transport not writable).  ``lead`` of them are a header in
-        front of device bytes: where the window covers in full what
-        follows it (the rest of the data, or a whole ``window_bytes``),
-        the header is not charged against the cut — the piece is the
-        header plus that, and the window stands below zero by what it
-        borrowed until the peer's credits for the piece repay it."""
+    def _device_lead(self, data: IOBuf) -> Optional[int]:
+        """``_consume_window``'s ``lead`` for ``data``: the short header in
+        front of its DEVICE bytes, None where it is cut as host bytes."""
+        return _header_run(data, min(
+            _flags.get_flag("ici_device_plane_threshold"), self.window_bytes))
+
+    def _consume_window(self, want: int, lead: Optional[int] = None) -> int:
+        """Take up to ``want`` bytes of window; -1 when the transport is
+        not writable: the window is closed, or has less left than the cut
+        needs (``_wait_writable`` then waits for that much).
+
+        ``lead`` is None for host bytes, which are cut byte for byte.
+        Else DEVICE bytes follow a header of ``lead`` bytes (0: none), and
+        the cut is a whole piece of what follows the header, or all of it
+        where that is less — never what happens to be left of a partly
+        open window, so every cut lies on the blocks' piece boundaries.
+        Where the window covers the piece the header is not charged
+        against the cut: the window stands below zero by what it borrowed
+        until the peer's credits for the piece repay it.  Where it does
+        not, the writer waits for the credit that makes it so; a window of
+        one piece or less instead cuts what is left, header charged (its
+        reader may be holding back credits for a fuller batch, and the
+        whole window might never come)."""
         with self._window_lock:
             left = self._send_window
-            if left <= 0:
+            body = 0 if lead is None else min(want - lead, self.piece_bytes)
+            need = max(1, body) if self.piece_bytes < self.window_bytes \
+                else 1
+            if left < need:
+                self._window_need = need
                 return -1
-            body = want - lead
-            if lead and left >= min(body, self.window_bytes):
-                n = lead + min(body, left)
+            if lead is not None and left >= body:
+                n = lead + body
             else:
                 n = min(want, left)
             self._send_window = left - n
+            self._cut_backlog = backlog = self.window_bytes - left
         if n > left:
             _g_borrowed_headers << 1
+        if backlog > 0:
+            _g_pipelined_pieces << 1
         return n
 
     def _on_credits(self, n: int) -> None:
@@ -173,7 +226,10 @@ class CreditWindow:
             while not self.failed:
                 gen = self._window_gen.value
                 with self._window_lock:
-                    if self._send_window > 0:
+                    # what the head of the queue needs, not any byte at
+                    # all: a writer woken short of a piece would find
+                    # _do_write not writable again, and spin
+                    if self._send_window >= self._window_need:
                         return True
                 if self._peer_gone():
                     self.set_failed(errors.EFAILEDSOCKET,
@@ -323,15 +379,14 @@ class IciSocket(CreditWindow, OrderedDelivery, Socket):
             raise ConnectionError("ici peer closed")
         # a short header in front of device bytes rides with its first
         # window piece, so the cuts land on the device blocks' boundaries
-        bound = min(_flags.get_flag("ici_device_plane_threshold"),
-                    self.window_bytes)
-        n = self._consume_window(len(data), _header_run(data, bound))
+        n = self._consume_window(len(data), self._device_lead(data))
         if n < 0:
             return -1                     # window full: not writable now
-        # layer spans: one window piece, and as its child (stamped, in the
-        # store only) the cut and the slice / device_put / plane post
-        # dispatches
-        piece = _span.layer_begin("brpc.ici.piece", n=n) \
+        # layer spans: one window piece (m: the bytes un-consumed at the
+        # peer when it was cut), and as its child (stamped, in the store
+        # only) the cut and the slice / device_put / plane post dispatches
+        piece = _span.layer_begin("brpc.ici.piece", n=n,
+                                  m=self._cut_backlog) \
             if _span.layer_on() else None
         try:
             frame = data.cut(n)
@@ -545,10 +600,11 @@ class IciSocket(CreditWindow, OrderedDelivery, Socket):
         self._wake_window()
 
 
-def _header_run(data: IOBuf, bound: int) -> int:
+def _header_run(data: IOBuf, bound: int) -> Optional[int]:
     """The host bytes in front of ``data``'s first DEVICE ref where they
-    are fewer than ``bound``; 0 for a longer run and for data with no
-    DEVICE bytes (both are cut byte for byte)."""
+    are fewer than ``bound`` (0 where it begins with one); None for a
+    longer run and for data with no DEVICE bytes: both are cut byte for
+    byte."""
     lead = 0
     for i in range(data.backing_block_num()):
         r = data.backing_block(i)
@@ -557,7 +613,7 @@ def _header_run(data: IOBuf, bound: int) -> int:
         lead += r.length
         if lead >= bound:
             break
-    return 0
+    return None
 
 
 def _cut(arr, r):

@@ -535,6 +535,11 @@ class Channel:
         if published:
             prev_span = _sched.local_get("rpcz_client_span")
             set_client_span_local(cntl.span)
+        # BEFORE the write: an in-process reply can end the call while this
+        # thread is still inside sock.write (a usercode_inline handler; a
+        # bulk frame whose last window piece this thread cut itself), and
+        # _on_call_end gives back only the pooled connection it finds here
+        cntl._last_socket = sock
         try:
             rc = sock.write(packet, notify_cid=cid)
         finally:
@@ -542,7 +547,6 @@ class Channel:
                 set_client_span_local(prev_span)
         if rc != 0:
             raise ConnectionError(f"write failed: {rc}")
-        cntl._last_socket = sock
 
     def _select_socket(self, cntl: Controller):
         ctype = self.options.connection_type

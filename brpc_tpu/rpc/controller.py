@@ -114,6 +114,7 @@ class Controller:
     _cid: int = 0
     _timeout_timer = None
     _backup_timer = None
+    _ending = False                 # _end_rpc has begun (timers are off)
     _channel = None                 # issuing channel (for re-issues)
     _method_full_name: str = ""
     _request_buf: Optional[IOBuf] = None
@@ -278,6 +279,12 @@ class Controller:
         ver = self.current_try
         self._timeout_timer = TimerThread.instance().schedule_after(
             lambda: self._handle_timeout(ver), remaining / 1000.0)
+        if self._ending:
+            # the reply ended the call on another thread between
+            # _start_call's look at _ended and here: _end_rpc has passed
+            # its own unschedule, and a timer left armed would hold the
+            # Controller and its attachments until the deadline
+            TimerThread.instance().unschedule(self._timeout_timer)
 
     def current_cid(self) -> int:
         return bthread_id.with_version(self._cid, self.current_try)
@@ -496,6 +503,7 @@ class Controller:
         self._end_rpc(cid)
 
     def _end_rpc(self, cid: int) -> None:
+        self._ending = True     # before the look: _schedule_try_timer's rule
         if self._timeout_timer is not None:
             TimerThread.instance().unschedule(self._timeout_timer)
         if self._backup_timer is not None:

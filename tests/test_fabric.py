@@ -122,8 +122,8 @@ from echo_pb2 import EchoRequest, EchoResponse
 mesh = ici.IciMesh()
 ici.IciMesh.set_default(mesh)
 
-CHUNK = 2 * 1024 * 1024      # 2MB payloads vs the 4MB window: 3 threads
-THREADS, CALLS = 3, 3        # saturate it (9 x 2MB each way)
+CHUNK = 6 * 1024 * 1024      # 6MB payloads (a 4MB piece and the rest) vs
+THREADS, CALLS = 3, 3        # the 16MB window: 3 threads saturate it
 
 if pid == 0:
     total = [0]
@@ -180,6 +180,10 @@ else:
     for t in threads: t.start()
     for t in threads: t.join()
     assert not errs, errs
+    # the window held several pieces at once: cuts were made while earlier
+    # bytes of the socket were still un-consumed at the peer
+    from brpc_tpu.ici.transport import ici_piece_stats
+    assert ici_piece_stats()["pipelined_pieces"] > 0, ici_piece_stats()
     kv.wait_at_barrier("stress_done", 300000)
     print("STRESS1_OK", flush=True)
 """

@@ -502,10 +502,13 @@ class TestBorrowedHeaderWindow:
         (1000, 40 + 4096, 40, 1000),
         (4095, 40 + 4096, 40, 4095),
         (30, 40 + 4096, 40, 30),
-        # no header: byte for byte
+        # device bytes with no header in front (a frame's later pieces)
         (4096, 16 * 4096, 0, 4096),
         (1000, 5000, 0, 1000),
         (4096, 100, 0, 100),
+        # host bytes: byte for byte
+        (4096, 16 * 4096, None, 4096),
+        (1000, 5000, None, 1000),
     ])
     def test_piece_length(self, left, want, lead, n):
         from brpc_tpu.ici.transport import ici_piece_stats
@@ -515,9 +518,102 @@ class TestBorrowedHeaderWindow:
         assert n > 0
         assert w.send_window_left() == left - n
         # never below zero by more than the header
-        assert w.send_window_left() >= -lead
+        assert w.send_window_left() >= -(lead or 0)
         borrowed = ici_piece_stats()["borrowed_header_pieces"] - before
         assert borrowed == (1 if n > left else 0)
+
+    # a window of four pieces (PIECE_BYTES at the tests' size)
+    P = 4096
+
+    def _wide(self, monkeypatch, left=None, pieces=4):
+        from brpc_tpu.ici import transport as tr
+        monkeypatch.setattr(tr, "PIECE_BYTES", self.P)
+        monkeypatch.setattr(self, "W", pieces * self.P)
+        w = self._window(left)
+        assert (w.piece_bytes, w.window_bytes) == (self.P, pieces * self.P)
+        return w
+
+    @pytest.mark.parametrize("left, want, lead, n", [
+        # the whole window: the header and ONE piece of what follows it
+        (4 * P, 40 + 16 * P, 40, 40 + P),
+        # ... then a piece at a time while a whole one is left
+        (3 * P - 40, 15 * P, 0, P),
+        (2 * P - 40, 14 * P, 0, P),
+        # exactly a piece left: the header rides on borrowed window
+        (P, 40 + 16 * P, 40, 40 + P),
+        # the data's end is a piece of its own length
+        (100, 90, 0, 90),
+        (100, 40 + 60, 40, 100),
+        (60, 40 + 60, 40, 100),
+        (4 * P, 40 + 100, 40, 140),
+        # host bytes: byte for byte, whatever the piece
+        (P - 40, 5000, None, P - 40),
+        (4 * P, 10 * P, None, 4 * P),
+        (1, 5000, None, 1),
+    ])
+    def test_a_cut_in_device_bytes_is_a_whole_piece_or_the_end(
+            self, monkeypatch, left, want, lead, n):
+        from brpc_tpu.ici.transport import ici_piece_stats
+        w = self._wide(monkeypatch, left)
+        before = ici_piece_stats()
+        assert w._consume_window(want, lead) == n
+        assert w.send_window_left() == left - n >= -(lead or 0)
+        after = ici_piece_stats()
+        assert after["borrowed_header_pieces"] \
+            - before["borrowed_header_pieces"] == (1 if n > left else 0)
+        assert after["pipelined_pieces"] - before["pipelined_pieces"] \
+            == (1 if left < 4 * self.P else 0)
+
+    @pytest.mark.parametrize("left, want, lead, need", [
+        # less left than the next whole piece needs: never what is left
+        (P - 40, 13 * P, 0, P),
+        (P - 1, 40 + 16 * P, 40, P),
+        (1, 40 + 16 * P, 40, P),
+        (50, 40 + 60, 40, 60),
+        # closed
+        (0, 13 * P, 0, P),
+        (-40, 13 * P, 0, P),
+        (0, 5000, None, 1),
+        (-1, 5000, None, 1),
+    ])
+    def test_a_partly_open_window_is_not_writable_for_device_bytes(
+            self, monkeypatch, left, want, lead, need):
+        w = self._wide(monkeypatch, left)
+        assert w._consume_window(want, lead) == -1
+        assert w.send_window_left() == left
+        # ... and the wait is for what the cut needs, not for any byte
+        got = []
+        t = threading.Thread(
+            target=lambda: got.append(w._wait_writable(timeout=20)))
+        t.start()
+        if left + 1 < need:
+            w._on_credits(need - left - 1)      # one byte short
+            time.sleep(0.7)                     # past the 0.5 s re-check
+            assert got == [] and t.is_alive()
+            w._on_credits(1)
+        else:
+            w._on_credits(need - left)
+        t.join(10)
+        assert got == [True]
+        assert w._consume_window(want, lead) > 0
+
+    @pytest.mark.parametrize("window, batch", [
+        (4 * P, 4 * P // 8),         # an eighth of the window, as before
+        (P, P // 8), (1000, 125),    # ... and of one piece or less
+        (P + 100, 100),              # never what a parked writer needs
+        (2 * P, 2 * P // 8),
+    ])
+    def test_a_reader_never_holds_back_what_a_parked_writer_needs(
+            self, monkeypatch, window, batch):
+        """``credit_batch`` (FabricSocket returns credits in such steps):
+        with all but one batch returned, a whole piece is writable."""
+        from brpc_tpu.ici import transport as tr
+        monkeypatch.setattr(tr, "PIECE_BYTES", self.P)
+        monkeypatch.setattr(self, "W", window)
+        w = self._window()
+        assert w.credit_batch == batch
+        w._send_window = window - (batch - 1)   # the peer sits on the rest
+        assert w._consume_window(16 * self.P, 0) > 0
 
     @pytest.mark.parametrize("left", [0, -1, -40])
     def test_a_window_at_or_under_zero_is_closed(self, left):
@@ -591,13 +687,14 @@ class TestBorrowedHeaderWindow:
         ((b"h" * 25, "dev"), 1024, 25),
         ((b"h" * 25, b"m" * 42, "dev"), 1024, 67),
         ((b"h" * 1023, "dev"), 1024, 1023),
-        ((b"h" * 1024, "dev"), 1024, 0),     # at the bound
-        ((b"h" * 600, b"m" * 600, "dev"), 1024, 0),
-        ((b"h" * 600, "dev"), 512, 0),       # a window under the threshold
-        ((b"h" * 25,), 1024, 0),             # no device bytes
+        # cut as host bytes (None): a run at the bound or over it
+        ((b"h" * 1024, "dev"), 1024, None),
+        ((b"h" * 600, b"m" * 600, "dev"), 1024, None),
+        ((b"h" * 600, "dev"), 512, None),    # a window under the threshold
+        ((b"h" * 25,), 1024, None),          # no device bytes
         (("dev",), 1024, 0),                 # nothing in front of them
         (("dev", b"t" * 25, "dev"), 1024, 0),
-        ((), 1024, 0),
+        ((), 1024, None),
     ])
     def test_header_run(self, mesh, refs, bound, lead):
         import jax.numpy as jnp
@@ -650,14 +747,17 @@ class TestRelocateCutsOnTheChip:
 
     def _write_and_drain(self, mesh, monkeypatch, src_dev, dst_dev,
                          header=b"hdr:", blocks=1, window=None,
-                         unread_first=b"", nbytes=None):
+                         unread_first=b"", nbytes=None, reads=None,
+                         reader=None):
         """One frame — ``header`` and then ``blocks`` device blocks, ONE of
         PIECES x WINDOW bytes (a request) or PIECES of WINDOW (a reply, as
         a server echoes what it received) — through a socket pair whose
         window is ``window``.  ``unread_first`` is written before it and
         not read until the frame's first piece has gone (a partly credited
-        window).  Returns what happened, and holds the window's bound all
-        the way."""
+        window).  ``reads`` gives the most each read takes, one after
+        another (1 MiB each by default); ``reader(b, portal)`` instead
+        reads from inside each delivery and returns what it took.  Returns
+        what happened, and holds the window's bound all the way."""
         from brpc_tpu.butil import flags as fl
         from brpc_tpu.butil.iobuf import IOBuf, IOPortal
         from brpc_tpu.ici import transport as tr
@@ -671,7 +771,7 @@ class TestRelocateCutsOnTheChip:
         out = type("Wrote", (), {})()
         out.cuts, out.released, out.pieces, out.posts, out.puts = \
             [], [], [], [], []
-        out.peak_unacked = 0
+        out.peak_unacked = out.refused = 0
         real_cut, real_pin = tr._cut, a._pin_until_sent
         real_write, real_post = a._do_write, tr._dp.plane().post_send
 
@@ -694,6 +794,8 @@ class TestRelocateCutsOnTheChip:
             n = real_write(data)
             if n >= 0:
                 out.pieces.append(n)
+            else:
+                out.refused += 1
             out.peak_unacked = max(out.peak_unacked, a.unacked_send_bytes())
             return n
         monkeypatch.setattr(tr, "_cut", counting_cut)
@@ -710,19 +812,28 @@ class TestRelocateCutsOnTheChip:
         out.sent = arrays
         stats = tr.ici_piece_stats()
         try:
+            total = len(unread_first) + len(out.want)
+            portal, got, got_lock = IOPortal(), [0], threading.Lock()
+            if reader is not None:
+                def input_event(inline=False):
+                    with got_lock:
+                        got[0] += reader(b, portal)
+                monkeypatch.setattr(b, "start_input_event", input_event)
             if unread_first:
                 assert a.write(IOBuf(unread_first)) == 0
-            assert a.write(buf) == 0
-            total = len(unread_first) + len(out.want)
-            portal, got = IOPortal(), 0
+            written = []
+            assert a.write(buf, on_done=written.append) == 0
+            reads = iter(reads(out) if callable(reads) else reads or ())
             deadline = time.monotonic() + 30
-            while got < total and time.monotonic() < deadline:
-                n = b._do_read(portal, 1 << 20)
+            while got[0] < total and time.monotonic() < deadline:
+                with got_lock:
+                    # beside a reader: only what it left behind at the end
+                    n = b._do_read(portal, next(reads, 1 << 20)) \
+                        if reader is None or written else 0
+                    got[0] += max(n, 0)
                 if n <= 0:
                     time.sleep(0.002)
-                    continue
-                got += n
-            assert got == total
+            assert got[0] == total and written == [0]
             out.delivered = [r.block.data for r in portal.device_refs()]
             for arr in out.delivered:
                 assert set(arr.devices()) == {mesh.device(dst_dev)}
@@ -744,6 +855,8 @@ class TestRelocateCutsOnTheChip:
                             - stats["borrowed_header_pieces"])
             out.small = (after["small_relocations"]
                          - stats["small_relocations"])
+            out.pipelined = (after["pipelined_pieces"]
+                             - stats["pipelined_pieces"])
             return out
         finally:
             a.set_failed()
@@ -866,6 +979,157 @@ class TestRelocateCutsOnTheChip:
         assert out.pieces[:len(first_pieces)] == first_pieces
         assert out.borrowed == borrowed
 
+    # ---- the window as several pieces: the default geometry --------------
+    HDR = 40
+
+    def _default_geometry(self):
+        from brpc_tpu.butil import flags as fl
+        from brpc_tpu.ici import transport as tr
+        window = fl.get_flag("ici_socket_window_bytes")
+        assert window % tr.PIECE_BYTES == 0 and window > tr.PIECE_BYTES
+        return window, tr.PIECE_BYTES
+
+    @pytest.mark.parametrize("case", [
+        "a_piece_at_a_time", "the_first_pieces_credit_before_the_fourth_cut",
+        "the_header_first", "all_at_once", "odd_amounts"])
+    def test_sixty_four_mib_go_in_sixteen_whole_pieces(
+            self, mesh, monkeypatch, host_mesh_plane, case):
+        """The reader is withheld until the writer has met a window with
+        less than a piece left, and then returns credits in the case's
+        steps: whatever part of the window they open, a cut is a whole
+        piece, on the block's piece boundaries, the header in the first."""
+        import itertools
+        window, piece = self._default_geometry()
+        hdr = self.HDR
+        steps = {
+            # the first read leaves the header's 40 bytes: 2 pieces less
+            # 40 open, one cut, and the writer parks again
+            "a_piece_at_a_time": itertools.repeat(piece),
+            # header included: exactly two pieces open
+            "the_first_pieces_credit_before_the_fourth_cut":
+                itertools.chain([hdr + piece], itertools.repeat(piece)),
+            "the_header_first":
+                itertools.chain([hdr], itertools.repeat(piece)),
+            "all_at_once": itertools.repeat(1 << 26),
+            "odd_amounts": itertools.repeat((1 << 20) + 7),
+        }[case]
+
+        def reads(out):
+            time.sleep(0.2)             # the writer has parked
+            assert out.pieces == [hdr + piece, piece, piece]
+            yield from steps
+
+        out = self._write_and_drain(
+            mesh, monkeypatch, 4, 4, header=b"h" * hdr, window=window,
+            nbytes=16 * piece, reads=reads)
+        assert out.got == out.want
+        assert out.pieces == [hdr + piece] + [piece] * 15
+        assert out.cuts == [(k * piece, piece) for k in range(16)]
+        # more than one piece really was in flight, under the bound
+        assert piece < out.peak_unacked <= window + hdr
+        assert out.pipelined >= 2 and out.small == 0
+
+    def test_a_writer_short_of_a_piece_parks_and_does_not_spin(
+            self, mesh, monkeypatch, host_mesh_plane):
+        """``_do_write`` is not called again until the credit that makes a
+        whole piece writable: not over the wait's 0.5 s re-check, and not
+        for a credit that leaves the window short of a piece."""
+        import itertools
+        window, piece = self._default_geometry()
+        hdr = self.HDR
+
+        def reads(out):
+            time.sleep(0.3)
+            tries = out.refused         # write()'s own, and keep_write's
+            assert len(out.pieces) == 3 and 1 <= tries <= 2
+            time.sleep(0.7)
+            assert out.refused == tries
+            yield hdr - 10              # 10 bytes short of a whole piece
+            time.sleep(0.3)
+            assert (len(out.pieces), out.refused) == (3, tries)
+            yield 10
+            time.sleep(0.3)             # one piece, and parked again
+            assert (len(out.pieces), out.refused) == (4, tries + 1)
+            yield from itertools.repeat(1 << 26)
+
+        out = self._write_and_drain(
+            mesh, monkeypatch, 4, 4, header=b"h" * hdr, window=window,
+            nbytes=16 * piece, reads=reads)
+        assert out.got == out.want
+        assert out.pieces == [hdr + piece] + [piece] * 15
+
+    @pytest.mark.parametrize("case, pipelined", [
+        ("a_window_of_one_piece", 0),
+        ("consumed_inside_the_delivering_write", 0),
+        ("each_credit_one_piece_late", 15)])
+    def test_pipelined_pieces(self, mesh, monkeypatch, host_mesh_plane,
+                              case, pipelined):
+        """``ici_piece_stats()["pipelined_pieces"]``: pieces cut while
+        earlier bytes of their socket were un-consumed at the peer."""
+        window, piece = self._default_geometry()
+        hdr = self.HDR
+
+        def read_all(b, portal):
+            return max(0, b._do_read(portal, 1 << 26))
+
+        def all_but_the_newest_piece(b, portal):
+            with b._inbox_lock:
+                avail = len(b._inbox)
+            if avail <= hdr + piece:
+                return 0
+            return b._do_read(portal, avail - piece)
+
+        kw = {
+            # the parent's geometry, and its cuts exactly
+            "a_window_of_one_piece": dict(window=piece,
+                                          reads=[1 << 26] * 64),
+            # a local reply: sixteen whole blocks, each read by the
+            # client's input event inside the write that delivers it
+            "consumed_inside_the_delivering_write": dict(
+                window=window, blocks=16, reader=read_all),
+            "each_credit_one_piece_late": dict(
+                window=window, reader=all_but_the_newest_piece),
+        }[case]
+        out = self._write_and_drain(mesh, monkeypatch, 4, 4,
+                                    header=b"h" * hdr, nbytes=16 * piece,
+                                    **kw)
+        assert out.got == out.want
+        assert out.pieces == [hdr + piece] + [piece] * 15
+        assert out.pipelined == pipelined
+        if case == "a_window_of_one_piece":
+            assert out.borrowed == 1 and out.peak_unacked == hdr + piece
+        elif case == "consumed_inside_the_delivering_write":
+            assert out.refused == 0 and out.cuts == []
+
+    def test_the_wider_window_asks_for_no_new_transfer_program(
+            self, mesh, monkeypatch, host_mesh_plane):
+        """A 64 MiB echo through the device plane: under the default
+        window the plane is asked for the (block, piece) pairs a window of
+        one piece asks for, and none is built that was not built then."""
+        window, piece = self._default_geometry()
+        asked, real = [], host_mesh_plane._program
+
+        def spy(block_bytes, nbytes, *args, **kw):
+            asked.append((block_bytes, nbytes))
+            return real(block_bytes, nbytes, *args, **kw)
+        monkeypatch.setattr(host_mesh_plane, "_program", spy)
+        seen = {}
+        for w in (piece, window):       # the narrow one first: it builds
+            del asked[:]
+            misses = host_mesh_plane.stats()["program_cache_misses"]
+            for src, dst, blocks in ((2, 3, 1), (3, 2, 16)):
+                out = self._write_and_drain(
+                    mesh, monkeypatch, src, dst, header=b"h" * self.HDR,
+                    window=w, nbytes=16 * piece, blocks=blocks,
+                    reads=[1 << 26] * 64)
+                assert out.got == out.want
+                assert out.posts == [piece] * 16 and out.cuts == []
+            seen[w] = (set(asked), host_mesh_plane.stats()
+                       ["program_cache_misses"] - misses)
+        assert seen[window][0] == seen[piece][0] \
+            == {(16 * piece, piece), (piece, piece)}
+        assert seen[window][1] == 0
+
     def test_a_host_only_frame_is_cut_byte_for_byte(self, mesh,
                                                     monkeypatch,
                                                     host_mesh_plane):
@@ -912,6 +1176,8 @@ class TestRelocateCutsOnTheChip:
         stats = tr.ici_piece_stats()
         for name, key in (("ici_transport_borrowed_header_pieces",
                            "borrowed_header_pieces"),
+                          ("ici_transport_pipelined_pieces",
+                           "pipelined_pieces"),
                           ("ici_transport_small_relocations",
                            "small_relocations")):
             assert bvar.find_exposed(name).get_value() == stats[key]

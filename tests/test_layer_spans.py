@@ -138,10 +138,13 @@ def traced(mesh, tmp_path_factory):
                 since = span.layer_mark().ns
                 cntl = _call(dep.channel, method, payload)
                 # the server stamps its write stage once the respond call
-                # has returned, which the caller's wake-up may beat
+                # has returned, which the caller's wake-up may beat; a
+                # parked handler's completion ends its callback span after
+                # that again
+                last = "brpc.server.write" if name == "bulk" \
+                    else "brpc.poller.callback"
                 deadline = time.monotonic() + 10
-                while not _named(span.layer_spans(since),
-                                 "brpc.server.write") \
+                while not _named(span.layer_spans(since), last) \
                         and time.monotonic() < deadline:
                     time.sleep(0.005)
                 out[name] = span.layer_spans(since)
@@ -346,6 +349,8 @@ def test_three_piece_write_stalls_on_the_closed_window(three_piece_write):
     spans = three_piece_write
     pieces = _named(spans, "brpc.ici.piece")
     assert [p.n for p in pieces] == [4096] * 3
+    # a window of one piece: each was cut once the one before was consumed
+    assert [p.m for p in pieces] == [0] * 3
     for child in ("brpc.ici.relocate", "brpc.ici.gate"):
         assert sorted(k.cause_id for k in _named(spans, child)) == \
             sorted(p.span_id for p in pieces)
@@ -354,6 +359,36 @@ def test_three_piece_write_stalls_on_the_closed_window(three_piece_write):
     assert max(s.end_ns - s.start_ns for s in stalls) >= 40e6
     # a stalled writer resumes with the next piece
     assert all(any(p.start_ns >= s.end_ns for p in pieces) for s in stalls)
+
+
+def test_a_piece_says_what_was_unconsumed_when_it_was_cut(mesh, monkeypatch):
+    """``brpc.ici.piece``'s second integer: the bytes of its socket still
+    un-consumed at the peer at the cut (0: nothing was ahead of it)."""
+    from brpc_tpu.butil.iobuf import IOBuf, IOPortal
+    from brpc_tpu.ici.transport import IciSocket, ici_piece_stats
+    import jax.profiler  # noqa: F401
+    layer_span.layer_on()   # binds the annotation class
+    span.layer_spans_reset()
+    monkeypatch.setattr(layer_span, "layer_on", lambda: True)
+    a, b = IciSocket(0, 0, mesh), IciSocket(0, 0, mesh)
+    a.peer, b.peer = b, a
+    before = ici_piece_stats()["pipelined_pieces"]
+    try:
+        for n in (1000, 500, 250):      # nobody reads
+            assert a.write(IOBuf(b"x" * n)) == 0
+        pieces = _named(span.layer_spans(), "brpc.ici.piece")
+        assert [(p.n, p.m) for p in pieces] == [
+            (1000, 0), (500, 1000), (250, 1500)]
+        assert ici_piece_stats()["pipelined_pieces"] == before + 2
+        assert b._do_read(IOPortal(), 1 << 20) == 1750
+        # every other span's second integer is 0
+        assert all(s.m == 0 for s in span.layer_spans()
+                   if s.name != "brpc.ici.piece")
+    finally:
+        a.set_failed()
+        b.set_failed()
+        monkeypatch.undo()
+        span.layer_spans_reset()
 
 
 def test_bulk_call_also_records_server_stages(traced):

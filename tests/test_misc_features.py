@@ -139,6 +139,96 @@ class TestConnectionTypes:
             server.stop()
 
 
+class TestReplyBeatsTheWritesReturn:
+    """An in-process reply can end a call while the caller's thread is
+    still inside ``sock.write`` or just out of it (a ``usercode_inline``
+    handler on the Python ici plane; on the chip a bulk frame whose last
+    window piece the caller cut itself, PERF.md §6 PR 34).  What the caller
+    does after the write must not assume the call is still in flight."""
+
+    @pytest.fixture
+    def python_plane(self, monkeypatch):
+        """call(attachment=None) -> controller, over ici://6 with the native
+        tier's binding off; gives the server too."""
+        opts = rpc.ServerOptions()
+        opts.usercode_inline = True
+        server = rpc.Server(opts)
+        server.add_service(EchoService())
+        assert server.start("ici://6") == 0
+        ch = rpc.Channel()
+        ch.init("ici://6", options=rpc.ChannelOptions(
+            connection_type="pooled", timeout_ms=60000, ici_local_device=6))
+        monkeypatch.setattr(ch, "_native_ici_binding", lambda cntl: None)
+
+        def call(message, attachment=None, cntl=None):
+            cntl = cntl or rpc.Controller()
+            if attachment is not None:
+                cntl.request_attachment.append_device_array(attachment)
+            resp = ch.call_method("EchoService.Echo", cntl,
+                                  EchoRequest(message=message), EchoResponse)
+            assert not cntl.failed(), cntl.error_text
+            assert resp.message == message
+            return cntl
+        try:
+            yield call, server
+        finally:
+            server.stop()
+
+    def test_the_pooled_connection_goes_back(self, python_plane,
+                                             monkeypatch):
+        from brpc_tpu.rpc.socket_map import SocketMap
+        call, server = python_plane
+        connects, smap = [], SocketMap.instance()
+        real = smap._checked_connect
+        monkeypatch.setattr(
+            smap, "_checked_connect",
+            lambda *a, **kw: (connects.append(1), real(*a, **kw))[1])
+        for i in range(10):
+            call(f"p{i}")
+        # the next call takes the connection from the pool
+        assert len(connects) == 1
+        assert len([s for s in server._connections if not s.failed]) == 1
+
+    def test_no_deadline_timer_is_left_armed(self, python_plane,
+                                             monkeypatch):
+        """The reply ends the call on the device poller's thread while the
+        caller is between ``_issue_rpc`` and arming the deadline:
+        ``_end_rpc`` has passed its own unschedule, so the timer armed
+        after it is taken back — left live it would hold the Controller
+        and its attachments for the whole timeout."""
+        import threading
+        import jax
+        import jax.numpy as jnp
+        from brpc_tpu.bthread.timer_thread import TimerThread
+        from brpc_tpu.ici import transport as tr
+        call, _server = python_plane
+        # every delivery through the poller: the reply ends the call there
+        monkeypatch.setattr(tr, "_all_ready", lambda arrays: False)
+        ending, armed = threading.Event(), threading.Event()
+        cntl = rpc.Controller()
+        real_arm = cntl._schedule_try_timer
+
+        def arm_once_the_end_has_begun():
+            assert ending.wait(10)
+            real_arm()
+            armed.set()
+        monkeypatch.setattr(cntl, "_schedule_try_timer",
+                            arm_once_the_end_has_begun)
+        real_end = rpc.Channel._on_call_end
+
+        def end_slowly(chan, c):
+            if c is cntl:               # inside _end_rpc, past its unschedule
+                ending.set()
+                assert armed.wait(10)
+            return real_end(chan, c)
+        monkeypatch.setattr(rpc.Channel, "_on_call_end", end_slowly)
+        block = jax.device_put(jnp.arange(4096, dtype=jnp.uint8),
+                               jax.devices()[6])
+        call("late", attachment=block, cntl=cntl)
+        assert armed.is_set() and cntl._timeout_timer is not None
+        assert not TimerThread.instance()._entries.get(cntl._timeout_timer)
+
+
 class TestServerOptionsLifecycle:
     """idle_timeout_s / internal_port / server_info_name (server.h parity:
     these options must DO something, not just exist)."""
