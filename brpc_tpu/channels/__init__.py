@@ -1,7 +1,7 @@
 """Combo channels (reference: SURVEY.md §2.6) — host-side composition plus
 the TPU-native collective lowering."""
 from .parallel_channel import (ParallelChannel, CallMapper, ResponseMerger,
-                               SubCall)
+                               SubCall, fanout_stats)
 from .partition_channel import (PartitionChannel, DynamicPartitionChannel,
                                 PartitionParser)
 from .selective_channel import SelectiveChannel

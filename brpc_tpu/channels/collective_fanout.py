@@ -75,6 +75,8 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 from ..butil import debug_sync as _dbg
 from ..butil import flags as _flags
 from ..butil import logging as log
+from ..butil.iobuf import IOBuf
+from . import parallel_channel as _pc
 from .collective_lowering import (MERGE_SUM, MERGE_GATHER, MERGE_CONCAT,
                                   MERGE_NONE, MAP_REPLICATE, MAP_SHARD)
 
@@ -311,20 +313,128 @@ class FanoutSequencer:
 # of the same semantics: scatter by per-sub attachments, merge by index).
 # ---------------------------------------------------------------------------
 
+def _is_device(x) -> bool:
+    """A jax array (anything that says which devices hold it) or an
+    ``IOBuf``, whose DEVICE refs are passed on as they are."""
+    return isinstance(x, IOBuf) or (hasattr(x, "devices")
+                                    and hasattr(x, "dtype"))
+
+
+def _carrier(dt):
+    """The unsigned integer type of a float ``dt``'s width (``dt`` itself
+    where it is no float, or too wide to have one).  A device program that
+    only MOVES floats carries them as this type and bitcasts last: the
+    TPU's ``concatenate`` and ``stack`` of float32 quiet every NaN and flush
+    every denormal (chip run, PR 35: 8,178 of 8,178 such values of 2**20
+    random bit patterns changed; views and bitcasts changed none), and an
+    RPC's bytes arrive as they were sent."""
+    import jax.numpy as jnp
+    if dt.kind == "f" and dt.itemsize <= 4:
+        return jnp.dtype(f"uint{8 * dt.itemsize}")
+    return dt
+
+
+_flat_program = None
+
+
+def _flat_bytes(x):
+    """A device array's bytes as ONE flat uint8 device array, in the order
+    ``np.asarray(x).tobytes()`` gives them: ``x`` itself where it is flat
+    uint8 already (no program), else one jitted ``brpc_fanout_flat``."""
+    global _flat_program
+    if x.ndim == 1 and x.dtype.kind == "u" and x.dtype.itemsize == 1:
+        return x
+    if _flat_program is None:
+        import jax
+        import jax.numpy as jnp
+
+        def brpc_fanout_flat(a):
+            with jax.named_scope("brpc_fanout_flat"):
+                carrier = _carrier(a.dtype)
+                if carrier != a.dtype:  # before the ravel, which may move
+                    a = jax.lax.bitcast_convert_type(a, carrier)
+                return jnp.ravel(a).view(jnp.uint8)
+
+        _flat_program = jax.jit(brpc_fanout_flat)
+    return _flat_program(x)
+
+
+def _whole_block(arr) -> IOBuf:
+    out = IOBuf()
+    out.append_device_array(_flat_bytes(arr))
+    return out
+
+
+def _device_attachment(parent_cntl, op, index: Optional[int]) \
+        -> Optional[IOBuf]:
+    """The DEVICE refs sub-call ``index`` carries (the whole operand where
+    ``index`` is None), or None for an operand that lives on the host.
+
+    A row that is an ``IOBuf`` already is passed as it is.  A jax array's
+    rows are PARTIAL refs into one flat block of the parent — at most one
+    device program a fan-out (``_flat_bytes``; none for flat uint8), where
+    ``op[i]`` would run one a sub-call; the transport cuts its window
+    pieces out of that block as it cuts any DEVICE ref.  An array spread
+    over several devices has no one block: it keeps the host path, and
+    ``host_operand_bytes`` counts it.
+    """
+    d = parent_cntl.__dict__
+    if isinstance(op, (list, tuple)):
+        if index is None or not _is_device(op[index]):
+            return None
+        row = op[index]
+        out = row if isinstance(row, IOBuf) else _whole_block(row)
+    elif isinstance(op, IOBuf):
+        if index is not None:
+            return None                 # rows of one buffer: give a list
+        out = op
+    elif not _is_device(op) or len(op.devices()) > 1:
+        return None
+    else:
+        whole = d.get("_fanout_whole")
+        if whole is None:
+            whole = d["_fanout_whole"] = _whole_block(op)
+        out = IOBuf(whole)              # the refs copied, the block shared
+        if index is not None:
+            n = len(whole) // op.shape[0]
+            out.pop_front(index * n)
+            out.pop_back(len(out) - n)
+    device_bytes = out.device_bytes()
+    if "_fanout_device" not in d:
+        # the fan-out is device-resident from here on; its home is where
+        # the merger gathers ONE array, should the caller ask for one
+        refs = out.device_refs()
+        d["_fanout_device"] = next(iter(refs[0].block.data.devices())) \
+            if refs else None
+    _pc._g["device_operand_bytes"] << device_bytes
+    if len(out) != device_bytes:
+        _pc._g["host_operand_bytes"] << len(out) - device_bytes
+    return out
+
+
 class ShardingCallMapper:
     """CallMapper whose scatter is row ``i`` of the parent's fan-out
     operand (``cntl.fanout_operand``) as sub-call ``i``'s request
-    attachment — the wire-path half of MAP_SHARD."""
+    attachment — the wire-path half of MAP_SHARD.  What rides is decided
+    by what the operand IS: a host (numpy) operand's row rides as its
+    bytes; a device array's row, or a row that is itself a device array or
+    an ``IOBuf`` of DEVICE refs (``fanout_operand`` a list of them), rides
+    as DEVICE refs of an ``IOBuf`` — by reference, with no copy and no
+    host (``_device_attachment``)."""
 
     collective_mapping = MAP_SHARD
 
     def map_fanout(self, index: int, method_full_name: str, request: Any,
                    parent_cntl) -> "SubCall":
         from .parallel_channel import SubCall
-        import numpy as np
         op = parent_cntl.fanout_operand
-        row = np.asarray(op[index])
-        return SubCall(request, attachment=row.tobytes())
+        refs = _device_attachment(parent_cntl, op, index)
+        if refs is not None:
+            return SubCall(request, attachment=refs)
+        import numpy as np
+        row = np.asarray(op[index]).tobytes()
+        _pc._g["host_operand_bytes"] << len(row)
+        return SubCall(request, attachment=row)
 
     def map(self, index: int, method_full_name: str, request: Any):
         from .parallel_channel import SubCall
@@ -332,19 +442,26 @@ class ShardingCallMapper:
 
 
 class ReplicateFanoutMapper:
-    """MAP_REPLICATE with the operand bytes riding every sub-call's
-    request attachment (serialized once per fan-out, not per sub)."""
+    """MAP_REPLICATE with the operand riding every sub-call's request
+    attachment: a host operand as its bytes (serialized once per fan-out,
+    not per sub), a device array or an ``IOBuf`` of DEVICE refs as refs to
+    ONE block (made flat once per fan-out where it is not flat uint8)."""
 
     collective_mapping = MAP_REPLICATE
 
     def map_fanout(self, index: int, method_full_name: str, request: Any,
                    parent_cntl) -> "SubCall":
         from .parallel_channel import SubCall
+        refs = _device_attachment(parent_cntl, parent_cntl.fanout_operand,
+                                  None)
+        if refs is not None:
+            return SubCall(request, attachment=refs)
         import numpy as np
         blob = parent_cntl.__dict__.get("_fanout_replica_bytes")
         if blob is None:
             blob = np.asarray(parent_cntl.fanout_operand).tobytes()
             parent_cntl.__dict__["_fanout_replica_bytes"] = blob
+        _pc._g["host_operand_bytes"] << len(blob)
         return SubCall(request, attachment=blob)
 
     def map(self, index: int, method_full_name: str, request: Any):
@@ -352,14 +469,87 @@ class ReplicateFanoutMapper:
         return SubCall(request)
 
 
+_gather_program = None
+
+
+def _gather_jit():
+    """The one jitted ``brpc_fanout_gather``: each part's blocks joined,
+    viewed as ``dtype`` / ``shard_shape`` (a float as the integer of its
+    width until the end, ``_carrier``), then stacked, concatenated or
+    summed.  Built on first use (this module imports no jax)."""
+    global _gather_program
+    if _gather_program is None:
+        import functools
+        import jax
+        import jax.numpy as jnp
+
+        @functools.partial(jax.jit, static_argnums=(1, 2, 3))
+        def brpc_fanout_gather(blocks, merge, dtype, shard_shape):
+            with jax.named_scope("brpc_fanout_gather"):
+                dt = jnp.dtype(dtype)
+                carrier = _carrier(dt)
+
+                def typed(a):
+                    return a if carrier == dt \
+                        else jax.lax.bitcast_convert_type(a, dt)
+
+                arrs = []
+                for bs in blocks:
+                    a = bs[0] if len(bs) == 1 else jnp.concatenate(bs)
+                    a = a.view(carrier)
+                    if shard_shape is not None:
+                        a = a.reshape(shard_shape)
+                    arrs.append(a)
+                if merge == MERGE_SUM:
+                    return functools.reduce(jnp.add, map(typed, arrs))
+                # gathered as integers: a float's bits arrive as sent
+                if merge == MERGE_CONCAT:
+                    return typed(jnp.concatenate(arrs, axis=0))
+                return typed(jnp.stack(arrs))
+
+        _gather_program = brpc_fanout_gather
+    return _gather_program
+
+
+def _gather(parts, merge: str, dtype: str, shard_shape, home):
+    """ONE device array out of the sub-replies' DEVICE refs, in the order
+    given, on ``home``: one ``brpc_fanout_gather`` program."""
+    import jax
+    blocks = []
+    for part in parts:
+        bs = []
+        for r in part.device_refs():
+            a = r.block.data
+            a = a if a.ndim == 1 else a.reshape(-1)
+            if r.offset or r.length != a.shape[0]:
+                a = a[r.offset:r.offset + r.length]
+            if home is not None and home not in a.devices():
+                a = jax.device_put(a, home)
+            bs.append(a)
+        blocks.append(tuple(bs))
+    return _gather_jit()(tuple(blocks), merge, dtype,
+                         tuple(shard_shape) if shard_shape is not None
+                         else None)
+
+
 class CollectiveMerger:
     """ResponseMerger whose merge is the typed collective the compiled
-    program runs — reproduced host-side on the RPC loop: sub-response
-    attachments are parsed as ``dtype``/``shard_shape`` arrays, ordered
-    by sub-channel INDEX (never arrival), and stacked (gather), summed
-    (sum) or concatenated (concat) into ``cntl.fanout_result``.  The
-    same instance may serve every sub-channel (per-call state lives on
-    the parent controller, not the merger)."""
+    program runs — reproduced on the RPC loop: sub-response attachments
+    are kept by sub-channel INDEX (never arrival), parsed as
+    ``dtype``/``shard_shape`` arrays and stacked (gather), summed (sum)
+    or concatenated (concat) into ``cntl.fanout_result``.  The same
+    instance may serve every sub-channel (per-call state lives on the
+    parent controller, not the merger).
+
+    A host operand's fan-out merges on the host (a numpy
+    ``fanout_result``).  A DEVICE operand's sub-replies are kept as their
+    DEVICE refs and gathered without the host: ``cntl.fanout_attachment``
+    is the index-ordered refs as one ``IOBuf`` (no copy, no program) — for
+    gather and concat that IS the result, and ``cntl.fanout_result``
+    becomes ONE device array on the operand's device only when the caller
+    reads it (one ``brpc_fanout_gather`` program); a sum is a device add,
+    made at once.  A sub-reply that came back as host bytes is merged as
+    the host path merges (and counted in ``host_operand_bytes``)."""
 
     def __init__(self, merge: str = MERGE_GATHER, dtype: str = "uint8",
                  shard_shape: Optional[Tuple[int, ...]] = None):
@@ -369,19 +559,51 @@ class CollectiveMerger:
 
     def merge_sub(self, parent_cntl, index: int, sub_cntl,
                   response: Any) -> int:
-        parts = parent_cntl.__dict__.setdefault("_fanout_parts", {})
+        d = parent_cntl.__dict__
+        parts = d.setdefault("_fanout_parts", {})
         att = sub_cntl._peek_response_attachment()
-        parts[index] = att.to_bytes() if att is not None else b""
+        if "_fanout_device" in d:
+            parts[index] = IOBuf(att) if att is not None else IOBuf()
+        else:
+            parts[index] = att.to_bytes() if att is not None else b""
+            _pc._g["host_operand_bytes"] << len(parts[index])
         return 0                         # MERGED
 
     def finalize_fanout(self, parent_cntl) -> None:
-        import numpy as np
-        parts = parent_cntl.__dict__.get("_fanout_parts")
+        d = parent_cntl.__dict__
+        parts = d.get("_fanout_parts")
         if not parts:
             return
+        ordered = [parts[i] for i in sorted(parts)]
+        if "_fanout_device" not in d:
+            parent_cntl.fanout_result = self._merge_host(ordered)
+            return
+        gathered = IOBuf()
+        for p in ordered:
+            gathered.append(p)
+        parent_cntl.fanout_attachment = gathered
+        if gathered.device_bytes() != len(gathered):
+            # a member answered from the host: nothing to gather on a chip
+            _pc._g["host_operand_bytes"] << len(gathered)
+            parent_cntl.fanout_result = self._merge_host(
+                [p.to_bytes() for p in ordered])
+            return
+        home = d["_fanout_device"]
+
+        def one_array():
+            return _gather(ordered, self.collective_merge, self.dtype,
+                           self.shard_shape, home)
+
+        if self.collective_merge == MERGE_SUM:
+            parent_cntl.fanout_result = one_array()
+        else:
+            d["_fanout_result_lazy"] = one_array
+
+    def _merge_host(self, blobs):
+        import numpy as np
         arrs = []
-        for i in sorted(parts):
-            a = np.frombuffer(parts[i], dtype=self.dtype)
+        for blob in blobs:
+            a = np.frombuffer(blob, dtype=self.dtype)
             if self.shard_shape is not None:
                 a = a.reshape(self.shard_shape)
             arrs.append(a)
@@ -393,7 +615,7 @@ class CollectiveMerger:
             out = np.concatenate(arrs, axis=0)
         else:                            # gather (and the none fallback)
             out = np.stack(arrs)
-        parent_cntl.fanout_result = out
+        return out
 
 
 # ---------------------------------------------------------------------------
@@ -1320,6 +1542,7 @@ def maybe_call(pchan, method_full_name: str, cntl, request,
 
         def _bg():
             if _try_execute(plane, low, cntl):
+                _pc._g["route_collective"] << 1
                 cntl.response = response
                 done(cntl)
             else:
@@ -1331,6 +1554,7 @@ def maybe_call(pchan, method_full_name: str, cntl, request,
         return True
     if not _try_execute(plane, low, cntl):
         return False
+    _pc._g["route_collective"] << 1
     cntl.response = response
     return True
 

@@ -116,7 +116,12 @@ class _SelectiveCall:
             self.cntl.remote_side = sub_cntl.remote_side
             if sub_cntl.__dict__.get("fanout_route"):
                 self.cntl.fanout_route = sub_cntl.fanout_route
-                self.cntl.fanout_result = sub_cntl.fanout_result
+                # by key, not by attribute: reading a device fan-out's
+                # ``fanout_result`` would make its one array here
+                for k in ("fanout_result", "_fanout_result_lazy",
+                          "fanout_attachment"):
+                    if k in sub_cntl.__dict__:
+                        self.cntl.__dict__[k] = sub_cntl.__dict__[k]
             self._finish()
             return
         # retry on a different sub-channel

@@ -51,6 +51,23 @@ class _LazyField:
         return val
 
 
+class _FanoutResult:
+    """``fanout_result``, non-data as ``_LazyField``: what a merger stored,
+    or — where it left only the recipe (a device fan-out's index-ordered
+    refs ARE the result until the caller asks for ONE array,
+    channels/collective_fanout.py) — that array, made on the first read."""
+    __slots__ = ()
+
+    def __get__(self, obj, owner=None):
+        if obj is None:
+            return None
+        make = obj.__dict__.pop("_fanout_result_lazy", None)
+        if make is None:
+            return None
+        val = obj.__dict__["fanout_result"] = make()
+        return val
+
+
 class Controller:
     # Every scalar default lives on the CLASS: __init__ sets nothing, so
     # construction is an empty-dict object and a pooled reset is one
@@ -79,9 +96,12 @@ class Controller:
     # Partition fan-out, the merged result, and which route actually
     # carried the call ("collective" = one compiled SPMD program,
     # "rpc" = the per-member loop, "" = not an operand fan-out) — the
-    # route assertion surface for tools and tests
+    # route assertion surface for tools and tests.  A DEVICE operand's
+    # gather on the per-member loop is ``fanout_attachment``: the
+    # sub-replies' refs in sub-channel order, one IOBuf, nothing copied
     fanout_operand: Any = None
-    fanout_result: Any = None
+    fanout_result = _FanoutResult()
+    fanout_attachment: Optional[IOBuf] = None
     fanout_route: str = ""
     request_attachment = _LazyField("request_attachment", IOBuf)
     # the response factory is swapped to ici/native_plane.py's

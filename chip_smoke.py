@@ -6,8 +6,9 @@ and runs on the TPU.
                                        attachment echo sweep over ici://0,
                                        a handler that computes on the chip
                                        (+ tcp host->HBM ingest), streaming
-                                       with device chunks, the compiled
-                                       serving step
+                                       with device chunks, an operand
+                                       fan-out whose operand is a device
+                                       array, the compiled serving step
     python chip_smoke.py --multichip   four chips: ONLY the cross-chip path
                                        and what it is compared with (device
                                        plane relocation, mesh collectives,
@@ -48,6 +49,7 @@ KB, MB, GB = 1 << 10, 1 << 20, 1 << 30
 ECHO_SWEEP = ((4 * KB, 200), (1 * MB, 100), (64 * MB, 6), (1 * GB, 2))
 STREAM_SWEEP = ((64 * KB, 24), (1 * MB, 24))     # (chunk bytes, frames)
 HANDLER_BYTES = 1 * MB                           # HBM attachment, phase 3
+FANOUT_WIDTH, FANOUT_SHARD = 4, 16 * MB          # fanout_4x16m's operation
 TCP_INGEST_BYTES = 4 * MB                        # host attachment over tcp
 XCHIP_SWEEP = ((4 * KB, 20), (64 * MB, 4))       # --multichip echo
 ALLREDUCE_BYTES_PER_CHIP = 256 * MB              # 1 GiB over four chips
@@ -439,6 +441,108 @@ def phase_streaming(rng, dev) -> None:
     assert_clean_counters("streaming")
 
 
+def phase_operand_fanout(rng, dev) -> None:
+    """An operand fan-out on the per-member loop whose ``fanout_operand`` is
+    a jax array on the chip: the mapper's rows are refs into one block, the
+    merger gathers by index, ``cntl.fanout_result`` is ONE device array
+    (a gather, a device add) — against numpy bit for bit (random bytes as
+    float32 hold NaNs and denormals, which the TPU's own concatenate does
+    not keep), with no byte on the host."""
+    import functools
+    import jax.numpy as jnp
+    rpc, EchoRequest, EchoResponse = echo_types()
+    from brpc_tpu import channels
+    width, shard = FANOUT_WIDTH, FANOUT_SHARD
+
+    @functools.partial(jax.jit, static_argnums=1)
+    def xored(blocks, cuts):
+        rows = [b.reshape(-1)[at:at + n] for b, (at, n) in zip(blocks, cuts)]
+        return (rows[0] if len(rows) == 1 else jnp.concatenate(rows)) \
+            ^ jnp.uint8(0x5A)
+
+    class ShardService(rpc.Service):
+        @rpc.method(EchoRequest, EchoResponse)
+        def Xor(self, cntl, request, response, done):
+            att = cntl.request_attachment
+            check(len(att) and att.device_bytes() == len(att),
+                  "a shard came by the host")
+            refs = att.device_refs()
+            cntl.response_attachment.append_device_array(xored(
+                tuple(r.block.data for r in refs),
+                tuple((r.offset, r.length) for r in refs)))
+            response.message = request.message
+            done()
+
+    server = rpc.Server()
+    server.add_service(ShardService())
+    check(server.start("ici://0") == 0, "server start on ici://0")
+    try:
+        ch = rpc.Channel()
+        ch.init("ici://0", options=rpc.ChannelOptions(
+            timeout_ms=120000, max_retry=0, ici_local_device=0,
+            connection_type="pooled"))
+        host = rng.integers(0, 256, size=(width, shard), dtype=np.uint8)
+        answers = host ^ np.uint8(0x5A)
+        cases = (
+            ("shard+concat uint8", channels.MAP_SHARD,
+             channels.MERGE_CONCAT, "uint8", host, answers.reshape(-1)),
+            ("shard+sum uint32", channels.MAP_SHARD, channels.MERGE_SUM,
+             "uint32", host.view(np.uint32),
+             answers.view(np.uint32).sum(axis=0, dtype=np.uint32)),
+            ("shard+gather float32", channels.MAP_SHARD,
+             channels.MERGE_GATHER, "float32", host.view(np.float32),
+             answers.view(np.float32)),
+            ("replicate+gather float32", channels.MAP_REPLICATE,
+             channels.MERGE_GATHER, "float32", host[0].view(np.float32),
+             np.stack([answers[0].view(np.float32)] * width)))
+        for label, mapping, merge, dtype, operand, want in cases:
+            pc = channels.ParallelChannel(fail_limit=1)
+            mapper = channels.ShardingCallMapper() \
+                if mapping == channels.MAP_SHARD \
+                else channels.ReplicateFanoutMapper()
+            merger = channels.CollectiveMerger(merge=merge, dtype=dtype)
+            for _ in range(width):
+                pc.add_channel(ch, mapper=mapper, merger=merger)
+            arr = jax.block_until_ready(jax.device_put(operand, dev))
+            before = channels.fanout_stats()
+            cntl = rpc.Controller()
+            cntl.fanout_operand = arr
+            t0 = time.monotonic()
+            pc.call_method("ShardService.Xor", cntl,
+                           EchoRequest(message=label), EchoResponse())
+            check(not cntl.failed(), f"fan-out {label}: {cntl.error_text}")
+            check(cntl.fanout_route == "rpc", f"route {cntl.fanout_route}")
+            got = jax.block_until_ready(cntl.fanout_result)
+            took = time.monotonic() - t0
+            after = channels.fanout_stats()
+            check(hasattr(got, "devices") and set(got.devices()) == {dev},
+                  f"fan-out {label}: result not one array on {dev}")
+            got = np.asarray(got)
+            check(got.dtype == want.dtype and got.shape == want.shape,
+                  f"fan-out {label}: {got.dtype}{got.shape}, numpy gives "
+                  f"{want.dtype}{want.shape}")
+            differ = int(np.count_nonzero(
+                got.reshape(-1).view(np.uint8)
+                != want.reshape(-1).view(np.uint8)))
+            check(differ == 0, f"fan-out {label}: {differ} bytes of "
+                               f"{want.nbytes} differ from numpy")
+            att = cntl.fanout_attachment
+            check(att.device_bytes() == len(att) == width * shard,
+                  f"fan-out {label}: gathered refs are not device memory")
+            delta = {k: after[k] - before[k] for k in after}
+            check(delta["host_operand_bytes"] == 0
+                  and delta["partial_results"] == 0
+                  and delta["sub_calls"] == delta["merges"] == width
+                  and delta["device_operand_bytes"] == width * shard,
+                  f"fan-out {label}: counters {delta}")
+            say(f"[fanout] {label}: {width} x {fmt_bytes(shard)} from a "
+                f"device operand, result on {dev} == numpy, 0 host bytes, "
+                f"{took * 1e3:.0f} ms (smoke timing)")
+    finally:
+        server.stop()
+    assert_clean_counters("operand fan-out")
+
+
 def phase_serving_step(ticks: int = 10) -> None:
     """ContinuousBatchScheduler with the step as ONE compiled program,
     tokens equal to the numpy step and to the model's reference decode."""
@@ -503,6 +607,7 @@ def run_one_chip(rng) -> None:
     phase_echo(rng, dev)
     phase_device_handler(rng, dev)
     phase_streaming(rng, dev)
+    phase_operand_fanout(rng, dev)
     phase_serving_step()
 
 

@@ -276,3 +276,43 @@ def test_stream_cell_programs_at_the_cells_size(one_chip):
     block = jax.ShapeDtypeStruct((64 * MB,), jnp.uint8, sharding=one_chip)
     cut = jax.jit(lambda b: b[3 * MB:4 * MB]).lower(block).compile()
     assert cut.memory_analysis().output_size_in_bytes == MB
+
+
+# ---- the fan-out cell's device programs ------------------------------------
+
+def test_fanout_cell_programs_at_the_cells_size(one_chip):
+    """``fanout_4x16m``'s device programs: the transport's cut of a 4 MiB
+    window piece out of the block a ref points into (the request's out of
+    the caller's 64 MiB block, the reply's out of the handler's 16 MiB
+    array), and the handler's ONE program a shard, which joins the four
+    pieces the shard crossed in as and xors them into one 16 MiB array
+    (benchmarks/services/EchoShard.py).  The gather the cell never asks for
+    (``cntl.fanout_result``: ``brpc_fanout_gather`` over sixteen pieces)
+    compiles at the cell's size too."""
+    import os
+    import sys
+    import jax
+    import jax.numpy as jnp
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if repo not in sys.path:
+        sys.path.insert(0, repo)
+    from benchmarks.services.EchoShard import transform
+    from brpc_tpu.channels import collective_fanout as cf
+    piece = jax.ShapeDtypeStruct((4 * MB,), jnp.uint8, sharding=one_chip)
+    xor = transform.lower((piece,) * 4, ((0, 4 * MB),) * 4).compile()
+    assert xor.memory_analysis().output_size_in_bytes == 16 * MB
+    for size in (64 * MB, 16 * MB):     # the request's block, the reply's
+        block = jax.ShapeDtypeStruct((size,), jnp.uint8, sharding=one_chip)
+        cut = jax.jit(
+            lambda b, at: jax.lax.dynamic_slice(b, (at,), (4 * MB,))) \
+            .lower(block, jax.ShapeDtypeStruct((), jnp.int32,
+                                               sharding=one_chip)).compile()
+        assert cut.memory_analysis().output_size_in_bytes == 4 * MB
+    parts = tuple((piece,) * 4 for _ in range(4))
+    whole = cf._gather_jit().lower(parts, cf.MERGE_CONCAT, "uint8",
+                                   None).compile()
+    assert whole.memory_analysis().output_size_in_bytes == 64 * MB
+    # a float result is gathered as the integer of its width and bitcast last
+    stacked = cf._gather_jit().lower(parts, cf.MERGE_GATHER, "float32",
+                                     None).compile()
+    assert stacked.memory_analysis().output_size_in_bytes == 64 * MB
