@@ -31,7 +31,7 @@ from __future__ import annotations
 
 import threading
 import time as _time
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from .. import bvar
 from ..butil.endpoint import EndPoint
@@ -57,12 +57,25 @@ _ici_device_bytes_moved = 0
 _g_borrowed_headers = bvar.Adder("ici_transport_borrowed_header_pieces")
 _g_pipelined_pieces = bvar.Adder("ici_transport_pipelined_pieces")
 _g_small_relocations = bvar.Adder("ici_transport_small_relocations")
+# host-side cuts of a DEVICE ref out of a device array (``_cut``): by the
+# compiled slicer, and by ``arr[a:b]`` where the slicer does not apply (an
+# array spread over devices, a block an int32 start cannot index)
+_g_compiled_cuts = bvar.Adder("ici_transport_compiled_cuts")
+_g_eager_cuts = bvar.Adder("ici_transport_eager_cuts")
+
+# the compiled cut's caches (``piece_slicer``, ``_start_operand``)
+_cut_lock = _dbg.make_lock("ici.transport._cut_lock")
+_cut_program = None
+_cut_starts: Dict[tuple, Any] = {}
+MAX_CUT_STARTS = 512
 
 # fablint guarded-state contract for the module-level registries
 _GUARDED_BY_GLOBALS = {
     "_ici_bytes_moved": "_ici_stats_lock",
     "_ici_device_bytes_moved": "_ici_stats_lock",
     "_listeners": "_listeners_lock",
+    "_cut_program": "_cut_lock",
+    "_cut_starts": "_cut_lock",
 }
 
 # Transport-level sliding window (reference: the RDMA explicit-ACK window,
@@ -98,12 +111,16 @@ def ici_transport_stats() -> Tuple[int, int]:
 def ici_piece_stats() -> Dict[str, int]:
     """How the window pieces were cut and relocated, process-wide: pieces
     that carried their header on borrowed window, pieces cut while earlier
-    bytes of the same socket were still un-consumed at the peer, and DEVICE
+    bytes of the same socket were still un-consumed at the peer, DEVICE
     refs that crossed chips under the device plane's threshold (slice +
-    device_put)."""
+    device_put), and the host-side cuts out of a device array: by the
+    compiled slicer, and by the eager ``arr[a:b]`` left for an array the
+    slicer does not take."""
     return {"borrowed_header_pieces": _g_borrowed_headers.get_value(),
             "pipelined_pieces": _g_pipelined_pieces.get_value(),
-            "small_relocations": _g_small_relocations.get_value()}
+            "small_relocations": _g_small_relocations.get_value(),
+            "compiled_cuts": _g_compiled_cuts.get_value(),
+            "eager_cuts": _g_eager_cuts.get_value()}
 
 
 class CreditWindow:
@@ -616,13 +633,71 @@ def _header_run(data: IOBuf, bound: int) -> Optional[int]:
     return None
 
 
+def piece_slicer():
+    """The one jitted ``brpc_ici_cut``: ``dynamic_slice(block, (start,),
+    (length,))`` with ``length`` static and ``start`` an operand, so one
+    executable serves every offset of a (block bytes, length) shape.  Built
+    on first use (this module imports no jax)."""
+    global _cut_program
+    with _cut_lock:
+        fn = _cut_program
+    if fn is None:
+        import functools
+        import jax
+
+        @functools.partial(jax.jit, static_argnums=2)
+        def brpc_ici_cut(block, start, length):
+            return jax.lax.dynamic_slice(block, (start,), (length,))
+
+        with _cut_lock:
+            fn = _cut_program = _cut_program or brpc_ici_cut
+    return fn
+
+
+def _start_operand(device, offset: int):
+    """A cut's start as a device-resident int32 scalar, kept per (device,
+    offset): a frame layout cuts at the same offsets again, and a host
+    scalar handed to the program is a host-to-device copy a call
+    (``DevicePlane._start_operand`` is the pattern).  A few bytes an entry,
+    so a full table is simply dropped."""
+    key = (device, offset)
+    with _cut_lock:
+        op = _cut_starts.get(key)
+    if op is None:
+        import jax
+        import numpy as np
+        op = jax.device_put(np.int32(offset), device)
+        with _cut_lock:
+            if len(_cut_starts) >= MAX_CUT_STARTS:
+                _cut_starts.clear()
+            _cut_starts[key] = op
+    return op
+
+
 def _cut(arr, r):
-    """The host-side cut of a block ref: one ``arr[a:b]`` (on a device
-    array a jnp ``__getitem__`` and a program dispatch), or the block
-    itself where the ref covers all of it."""
-    if r.offset or r.length != len(arr):
-        return arr[r.offset:r.offset + r.length]
-    return arr
+    """The host-side cut of a block ref, chosen on what ``arr`` is: the
+    block itself where the ref covers all of it (no dispatch); out of a
+    single-device array one dispatch of the compiled slicer
+    (``piece_slicer``) with a cached start operand; else ``arr[a:b]`` — a
+    host numpy block, an array spread over devices, a block whose offsets
+    an int32 cannot hold.  A ref that is not inside its block raises:
+    ``dynamic_slice`` would clamp the start and deliver other bytes."""
+    size = len(arr)
+    if not r.offset and r.length == size:
+        return arr
+    if r.offset < 0 or r.length < 0 or r.offset + r.length > size:
+        raise ValueError(f"ref [{r.offset}, {r.offset + r.length}) is not "
+                         f"inside its block of {size} bytes")
+    sharding = getattr(arr, "sharding", None)
+    if sharding is not None:
+        devices = sharding.device_set
+        if len(devices) == 1 and size < 1 << 31:
+            _g_compiled_cuts << 1
+            (device,) = devices
+            return piece_slicer()(arr, _start_operand(device, r.offset),
+                                  r.length)
+        _g_eager_cuts << 1
+    return arr[r.offset:r.offset + r.length]
 
 
 class _PlaneDesc:

@@ -1,4 +1,5 @@
 """ici:// transport + collectives tests on the 8-device virtual CPU mesh."""
+import sys
 import threading
 import time
 
@@ -1179,6 +1180,214 @@ class TestRelocateCutsOnTheChip:
                           ("ici_transport_pipelined_pieces",
                            "pipelined_pieces"),
                           ("ici_transport_small_relocations",
-                           "small_relocations")):
+                           "small_relocations"),
+                          ("ici_transport_compiled_cuts", "compiled_cuts"),
+                          ("ici_transport_eager_cuts", "eager_cuts")):
             assert bvar.find_exposed(name).get_value() == stats[key]
         assert len(tr.ici_transport_stats()) == 2
+
+
+class TestCompiledCut:
+    """``transport._cut``: the host-side cut of a block ref.  Three
+    outcomes, chosen on what the block is: the block itself for a ref that
+    covers it, one dispatch of the compiled slicer (``piece_slicer``) out
+    of a single-device array, ``arr[a:b]`` for anything else."""
+
+    BLOCK, PIECE = 64 * 1024, 4 * 1024
+
+    @staticmethod
+    def _ref(offset, length):
+        return type("Ref", (), {"offset": offset, "length": length})()
+
+    @staticmethod
+    def _stats():
+        from brpc_tpu.ici import transport as tr
+        s = tr.ici_piece_stats()
+        return s["compiled_cuts"], s["eager_cuts"]
+
+    def _host(self, nbytes=None, salt=7):
+        return (np.arange(nbytes or self.BLOCK, dtype=np.uint32) * salt
+                % 251).astype(np.uint8)
+
+    def _block(self, mesh, dev=1, nbytes=None, salt=7):
+        import jax
+        host = self._host(nbytes, salt)
+        return host, jax.block_until_ready(
+            jax.device_put(host, mesh.device(dev)))
+
+    def test_a_whole_ref_is_the_block_itself(self, mesh):
+        from brpc_tpu.ici import transport as tr
+        host, arr = self._block(mesh)
+        before = self._stats()
+        assert tr._cut(arr, self._ref(0, self.BLOCK)) is arr
+        assert tr._cut(host, self._ref(0, self.BLOCK)) is host
+        assert self._stats() == before
+
+    @pytest.mark.parametrize("case, offset, length", [
+        ("aligned_piece", 5 * 4096, 4096),
+        ("first_piece", 0, 4096),
+        ("ragged_offset_and_length", 4097, 1234),
+        ("one_byte", 65535, 1),
+        ("short_last_piece", 15 * 4096 + 1000, 4096 - 1000),
+        ("all_but_the_first_byte", 1, 64 * 1024 - 1),
+    ])
+    def test_a_device_piece_byte_for_byte(self, mesh, case, offset, length):
+        from brpc_tpu.ici import transport as tr
+        host, arr = self._block(mesh, dev=2)
+        before = self._stats()
+        got = tr._cut(arr, self._ref(offset, length))
+        assert set(got.devices()) == {mesh.device(2)}
+        assert got.dtype == np.uint8 and got.shape == (length,)
+        assert bytes(np.asarray(got)) == bytes(host[offset:offset + length])
+        assert self._stats() == (before[0] + 1, before[1])
+
+    def test_a_host_block_is_sliced_by_numpy_and_not_counted(self, mesh):
+        from brpc_tpu.ici import transport as tr
+        host = self._host()
+        before = self._stats()
+        got = tr._cut(host, self._ref(4097, 1234))
+        assert isinstance(got, np.ndarray) and got.base is host
+        assert bytes(got) == bytes(host[4097:4097 + 1234])
+        assert self._stats() == before
+
+    def test_an_array_spread_over_devices_keeps_the_eager_slice(self, mesh):
+        import jax
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        from brpc_tpu.ici import transport as tr
+        host = self._host()
+        spread = jax.device_put(host, NamedSharding(
+            Mesh(np.array(jax.devices()[:2]), ("x",)), P("x")))
+        before = self._stats()
+        got = tr._cut(spread, self._ref(3 * 4096, 4096))
+        assert bytes(np.asarray(got)) == bytes(host[3 * 4096:4 * 4096])
+        assert self._stats() == (before[0], before[1] + 1)
+
+    @pytest.mark.parametrize("on", ["device", "host"])
+    @pytest.mark.parametrize("case, offset, length", [
+        ("runs_past_the_end", 15 * 4096 + 1, 4096),
+        ("starts_past_the_end", 64 * 1024, 1),
+        ("longer_than_the_block", 0, 64 * 1024 + 1),
+        ("negative_offset", -4096, 4096),
+        ("negative_length", 4096, -1),
+    ])
+    def test_a_ref_outside_its_block_raises(self, mesh, on, case, offset,
+                                            length):
+        """``dynamic_slice`` clamps such a start and would deliver other
+        bytes; ``arr[a:b]`` would deliver fewer."""
+        from brpc_tpu.ici import transport as tr
+        host, arr = self._block(mesh)
+        before = self._stats()
+        with pytest.raises(ValueError, match="not inside its block"):
+            tr._cut(arr if on == "device" else host,
+                    self._ref(offset, length))
+        assert self._stats() == before
+
+    def test_sixteen_offsets_of_one_shape_build_one_program(self, mesh):
+        from brpc_tpu.ici import transport as tr
+        # a (block, piece) pair no other test cuts, on two devices
+        nbytes, piece = 16 * 3 * 1024, 3 * 1024
+        slicer = tr.piece_slicer()
+        assert tr.piece_slicer() is slicer
+        programs = slicer._cache_size()
+        for dev in (1, 2):
+            host, arr = self._block(mesh, dev=dev, nbytes=nbytes, salt=11)
+            for k in range(16):
+                got = tr._cut(arr, self._ref(k * piece, piece))
+                assert bytes(np.asarray(got)) \
+                    == bytes(host[k * piece:(k + 1) * piece])
+        # one executable a (shape, device), whatever the offset ...
+        assert slicer._cache_size() == programs + 2
+        # ... and the sixteen starts of each device, uploaded once
+        for dev in (1, 2):
+            starts = [tr._start_operand(mesh.device(dev), k * piece)
+                      for k in range(16)]
+            assert all(s is tr._start_operand(mesh.device(dev), k * piece)
+                       for k, s in enumerate(starts))
+            assert all(set(s.devices()) == {mesh.device(dev)}
+                       and s.dtype == np.int32 and int(s) == k * piece
+                       for k, s in enumerate(starts))
+
+    def test_a_full_table_of_starts_is_dropped_whole(self, mesh, monkeypatch):
+        from brpc_tpu.ici import transport as tr
+        monkeypatch.setattr(tr, "MAX_CUT_STARTS", 4)
+        with tr._cut_lock:
+            tr._cut_starts.clear()
+        host, arr = self._block(mesh)
+        for k in range(11):
+            got = tr._cut(arr, self._ref(k * 100 + 1, 50))
+            assert bytes(np.asarray(got)) \
+                == bytes(host[k * 100 + 1:k * 100 + 51])
+            with tr._cut_lock:
+                assert 1 <= len(tr._cut_starts) <= 4
+        with tr._cut_lock:
+            assert len(tr._cut_starts) == 3      # 4 + 4 + 3
+
+    @pytest.mark.parametrize("threads", [2, 8])
+    def test_threads_cutting_at_once(self, mesh, threads):
+        from brpc_tpu.ici import transport as tr
+        host, arr = self._block(mesh, dev=3, salt=13)
+        with tr._cut_lock:
+            tr._cut_starts.clear()
+        before = self._stats()
+        wrong, go = [], threading.Barrier(threads)
+
+        def cutter(i):
+            go.wait()
+            for n in range(4):
+                for k in range(16):
+                    at = ((k + i) % 16) * self.PIECE
+                    got = tr._cut(arr, self._ref(at, self.PIECE))
+                    if bytes(np.asarray(got)) \
+                            != bytes(host[at:at + self.PIECE]):
+                        wrong.append((i, n, at))
+        ts = [threading.Thread(target=cutter, args=(i,))
+              for i in range(threads)]
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)     # the look-ups' races, if any, show
+        try:
+            for t in ts:
+                t.start()
+            for t in ts:
+                t.join(60)
+        finally:
+            sys.setswitchinterval(interval)
+        assert not wrong and not any(t.is_alive() for t in ts)
+        assert self._stats() == (before[0] + threads * 64, before[1])
+        with tr._cut_lock:
+            assert len(tr._cut_starts) == 16
+
+    def test_a_windowed_echo_cuts_every_piece_with_the_slicer(
+            self, mesh, monkeypatch):
+        """One echo of a device attachment sixteen pieces long over an
+        ``ici://`` connection on one chip (5 MiB: above the native tier's
+        window, so the Python ici plane carries it): the request's sixteen
+        pieces are cut by the compiled slicer, the reply's are whole
+        blocks."""
+        from brpc_tpu.ici import transport as tr
+        piece = 320 * 1024
+        monkeypatch.setattr(tr, "PIECE_BYTES", piece)
+        host, arr = self._block(mesh, dev=5, nbytes=16 * piece, salt=17)
+        options = rpc.ServerOptions()
+        options.usercode_inline = True
+        server = rpc.Server(options)
+        server.add_service(DeviceEchoService())
+        assert server.start("ici://5") == 0
+        try:
+            ch = rpc.Channel()
+            assert ch.init("ici://5", options=rpc.ChannelOptions(
+                ici_local_device=5)) == 0
+            before = self._stats()
+            cntl = rpc.Controller()
+            cntl.request_attachment.append_device_array(arr)
+            resp = ch.call_method("EchoService.Echo", cntl,
+                                  EchoRequest(message="cut"), EchoResponse)
+            assert not cntl.failed(), cntl.error_text
+            assert resp.message == "cut"
+            assert cntl.response_attachment.to_bytes() == bytes(host)
+            refs = cntl.response_attachment.device_refs()
+            assert [r.length for r in refs] == [piece] * 16
+            assert all(set(r.block.data.devices()) == {mesh.device(5)}
+                       for r in refs)
+            assert self._stats() == (before[0] + 16, before[1])
+        finally:
+            server.stop()

@@ -298,15 +298,15 @@ def test_fanout_cell_programs_at_the_cells_size(one_chip):
         sys.path.insert(0, repo)
     from benchmarks.services.EchoShard import transform
     from brpc_tpu.channels import collective_fanout as cf
+    from brpc_tpu.ici.transport import piece_slicer
     piece = jax.ShapeDtypeStruct((4 * MB,), jnp.uint8, sharding=one_chip)
     xor = transform.lower((piece,) * 4, ((0, 4 * MB),) * 4).compile()
     assert xor.memory_analysis().output_size_in_bytes == 16 * MB
+    start = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     for size in (64 * MB, 16 * MB):     # the request's block, the reply's
         block = jax.ShapeDtypeStruct((size,), jnp.uint8, sharding=one_chip)
-        cut = jax.jit(
-            lambda b, at: jax.lax.dynamic_slice(b, (at,), (4 * MB,))) \
-            .lower(block, jax.ShapeDtypeStruct((), jnp.int32,
-                                               sharding=one_chip)).compile()
+        # the transport's own slicer (ici.transport._cut), not a copy
+        cut = piece_slicer().lower(block, start, 4 * MB).compile()
         assert cut.memory_analysis().output_size_in_bytes == 4 * MB
     parts = tuple((piece,) * 4 for _ in range(4))
     whole = cf._gather_jit().lower(parts, cf.MERGE_CONCAT, "uint8",
