@@ -107,7 +107,7 @@ def _call(on_ready: Callable[[], None], mark: Any) -> bool:
     """Run the callback; False when it raised.  Layer span
     brpc.poller.callback, caused by the submitter's span, so that what the
     callback starts (a response's encode and write) keeps that cause."""
-    ls = _span.layer_begin("brpc.poller.callback", mark=mark) \
+    ls = _span.layer_begin("brpc.poller.callback", mark=mark, cpu=True) \
         if mark is not None else None
     ok = True
     try:

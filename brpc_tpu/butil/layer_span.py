@@ -2,10 +2,19 @@
 recorded inside the program while a jax profiler session is on.
 
 A record is ``(name, start_ns, end_ns, call_id, span_id, cause_id, thread,
-n[, m])`` on ``time.perf_counter_ns()`` (CLOCK_MONOTONIC, as ``monotonic_ns`` and
-the native tier's ``recv_ns``).  ``cause_id`` is the enclosing span on the
-same thread, or, for work that crossed threads (a poller entry, a delivery
-gate), the span that was open where it was submitted.  Records go to
+n[, m[, cpu_ns]])`` on ``time.perf_counter_ns()`` (CLOCK_MONOTONIC, as
+``monotonic_ns`` and the native tier's ``recv_ns``).  ``cause_id`` is the
+enclosing span on the same thread, or, for work that crossed threads (a
+poller entry, a delivery gate), the span that was open where it was
+submitted.  ``cpu_ns`` is what the span's thread RAN between the span's two
+ends (``time.thread_time_ns()``, CLOCK_THREAD_CPUTIME_ID): a thread that
+waits for the interpreter lock or for a device sleeps and accrues none, so
+the span's length less it is what the thread waited.  Only a lexical span
+begun with ``cpu=True`` and ended on the thread that began it has one — the
+read is a system call (6 us on the chip machine's host, where the clock
+also moves in 10 ms steps: sums and means read it, a median cannot), so only
+the sites a reader asks for pay it, and a CPU clock is one thread's —; every
+other record says -1.  Records go to
 per-thread lists, ``LAYER_SPAN_CAP`` in all and then dropped and counted, and
 stay in memory until the next session starts: ``layer_spans()`` reads them
 after the window.  Nothing is written out and nothing rides the wire.
@@ -42,6 +51,7 @@ class LayerSpan(NamedTuple):
     thread: str
     n: int                        # the site's integer (bytes, depth)
     m: int = 0                    # a second one, where a site has two
+    cpu_ns: int = -1              # its thread's CPU time inside; -1 = none
 
 
 class LayerMark(NamedTuple):
@@ -168,7 +178,7 @@ class _OpenLayerSpan:
     their cause), on the profiler's host plane for the same stretch, and
     in the store from ``finish``.  ``end`` is the two together."""
     __slots__ = ("name", "start_ns", "call_id", "span_id", "cause_id", "n",
-                 "m", "_st", "_prev_id", "_prev_call", "_note")
+                 "m", "_st", "_prev_id", "_prev_call", "_note", "_cpu0")
 
     def leave(self) -> None:
         st = self._st
@@ -176,29 +186,35 @@ class _OpenLayerSpan:
         st.cur_call = self._prev_call
         self._note.__exit__(None, None, None)
 
-    def finish(self, end_ns: int = 0) -> None:
+    def finish(self, end_ns: int = 0, cpu_ns: int = -1) -> None:
         """May run on another thread than the one that began the span (a
         handler's ``done``): the record goes to the finishing thread's
-        list, under the opening thread's name."""
+        list, under the opening thread's name, and with no CPU time (only
+        ``end`` knows that it is on the opening thread)."""
         st = getattr(_tls, "st", None) or _thread()
         st.records.append((
             self.name, self.start_ns, end_ns or time.perf_counter_ns(),
             self.call_id, self.span_id, self.cause_id, self._st.name,
-            self.n, self.m))
+            self.n, self.m, cpu_ns))
 
     def end(self) -> None:
+        # on the thread that began the span, by contract (``leave`` restores
+        # that thread's innermost span); the CPU clock is read inside the
+        # wall clock's two reads, so that cpu_ns never passes the length
+        cpu_ns = -1 if self._cpu0 < 0 else time.thread_time_ns() - self._cpu0
         end_ns = time.perf_counter_ns()
         self.leave()
-        self.finish(end_ns)
+        self.finish(end_ns, cpu_ns)
 
 
 def layer_begin(name: str, call_id: int = 0, n: int = 0,
                 mark: Optional[LayerMark] = None,
-                m: int = 0) -> Optional[_OpenLayerSpan]:
+                m: int = 0, cpu: bool = False) -> Optional[_OpenLayerSpan]:
     """Open a lexical span on this thread; ``None`` once the session has
     used its cap.  Call only under ``layer_on()`` (or with a ``mark`` taken
     under it).  ``mark`` names the submitter as the cause where the work
-    crossed threads."""
+    crossed threads; ``cpu`` also records the thread's CPU time inside the
+    span (two system calls: for the spans whose ``cpu_ns`` is read)."""
     st = getattr(_tls, "st", None) or _thread()
     sid = next(_ids)
     if sid - _base > LAYER_SPAN_CAP:
@@ -225,6 +241,7 @@ def layer_begin(name: str, call_id: int = 0, n: int = 0,
     ls._note = note = _annotation(name)
     note.__enter__()
     ls.start_ns = time.perf_counter_ns()
+    ls._cpu0 = time.thread_time_ns() if cpu else -1
     return ls
 
 

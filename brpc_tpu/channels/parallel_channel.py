@@ -168,7 +168,7 @@ class ParallelChannel:
 
         cntl._start_us = time.monotonic_ns() // 1000
         for i in range(n):
-            issue = _span.layer_begin("brpc.fanout.issue") \
+            issue = _span.layer_begin("brpc.fanout.issue", cpu=True) \
                 if ls is not None else None
             try:
                 self._issue(i, method_full_name, request, state, issue)

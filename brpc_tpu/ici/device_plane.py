@@ -657,7 +657,7 @@ class DevicePlane:
         import jax
         # layer span brpc.plane.run: the dummy block, the global operand's
         # assembly, the program's dispatch and the pick of dst's shard
-        run = _layer.layer_begin("brpc.plane.run", n=t.nbytes) \
+        run = _layer.layer_begin("brpc.plane.run", n=t.nbytes, cpu=True) \
             if _layer.layer_on() else None
         try:
             fn, sharding, mesh2, src, dst = self._program(

@@ -403,7 +403,7 @@ class IciSocket(CreditWindow, OrderedDelivery, Socket):
         # peer when it was cut), and as its child (stamped, in the store
         # only) the cut and the slice / device_put / plane post dispatches
         piece = _span.layer_begin("brpc.ici.piece", n=n,
-                                  m=self._cut_backlog) \
+                                  m=self._cut_backlog, cpu=True) \
             if _span.layer_on() else None
         try:
             frame = data.cut(n)
@@ -689,15 +689,25 @@ def _cut(arr, r):
         raise ValueError(f"ref [{r.offset}, {r.offset + r.length}) is not "
                          f"inside its block of {size} bytes")
     sharding = getattr(arr, "sharding", None)
-    if sharding is not None:
-        devices = sharding.device_set
-        if len(devices) == 1 and size < 1 << 31:
-            _g_compiled_cuts << 1
+    if sharding is None:
+        return arr[r.offset:r.offset + r.length]
+    devices = sharding.device_set
+    compiled = len(devices) == 1 and size < 1 << 31
+    (_g_compiled_cuts if compiled else _g_eager_cuts) << 1
+    # layer span brpc.ici.cut: the dispatch alone, inside its piece's
+    # brpc.ici.relocate (n: the cut's bytes, m: 1 for the eager fall-back)
+    cut = _span.layer_begin("brpc.ici.cut", n=r.length,
+                            m=0 if compiled else 1, cpu=True) \
+        if _span.layer_on() else None
+    try:
+        if compiled:
             (device,) = devices
             return piece_slicer()(arr, _start_operand(device, r.offset),
                                   r.length)
-        _g_eager_cuts << 1
-    return arr[r.offset:r.offset + r.length]
+        return arr[r.offset:r.offset + r.length]
+    finally:
+        if cut is not None:
+            cut.end()
 
 
 class _PlaneDesc:

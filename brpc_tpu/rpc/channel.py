@@ -167,7 +167,8 @@ class Channel:
         """Sync when done is None (returns the response); async otherwise."""
         # layer span brpc.call: entry -> return, under the correlation id
         # where this plane has one (the native tier correlates in C++)
-        ls = _span.layer_begin("brpc.call") if _span.layer_on() else None
+        ls = _span.layer_begin("brpc.call", cpu=True) \
+            if _span.layer_on() else None
         try:
             # fused native fast path (ISSUE 13): a cached in-process ici
             # binding bound with ici_fused_dispatch serves sync calls

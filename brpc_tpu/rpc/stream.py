@@ -339,7 +339,8 @@ class Stream:
             for mark in marks:
                 _span.layer_waited("brpc.stream.queue", mark)
             if handler is not None:
-                ls = _span.layer_begin("brpc.stream.handler", n=len(msgs)) \
+                ls = _span.layer_begin("brpc.stream.handler", n=len(msgs),
+                                       cpu=True) \
                     if _span.layer_on() else None
                 try:
                     handler.on_received_messages(self.sid, msgs)

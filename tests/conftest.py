@@ -201,6 +201,10 @@ def _resource_census(request):
 # seven_metrics`` holds that the streaming cell is the manifest's LAST entry
 # in seven lists, which no cell appended after it can leave true
 # (test_fanout_cell.py holds what of it still can be: the entries themselves).
+# Likewise two cases of test_fanout_cell.py hold that the fan-out's five
+# metrics are the LAST of ``per_layer``, which the driver's rule that a PR
+# appends its entries ends with the next metric (PR 37's nine;
+# test_span_cpu_metrics.py holds every other clause of the two, by entry).
 # Those files are the accepted benchmark's, and only a ``benchmark`` PR edits
 # them (``CELLS`` of the first narrowed to the unary cells, ``OWN_CONTROLS``
 # entries for both cells, the position test by entry and not by place); until
@@ -218,11 +222,15 @@ _BREAKS_AT_THE_CALLS_RETURN = ("corrupted_byte-byte_mismatches",
                                "wrong_chip-misplaced_replies")
 
 
-def _manifest_cells():
+def _manifest():
     import json
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(repo, "BENCHMARK.json"), encoding="utf-8") as f:
-        return [w["name"] for w in json.load(f)["workloads"]]
+        return json.load(f)
+
+
+def _manifest_cells():
+    return [w["name"] for w in _manifest()["workloads"]]
 
 
 def _cells_with_a_client_of_their_own(of_unary_calls=None):
@@ -285,6 +293,14 @@ def pytest_collection_modifyitems(config, items):
             "holds that stream_1m is the manifest's last entry, which a " \
             "cell appended after it ends; test_fanout_cell.py holds the " \
             "entries themselves"
+    if _manifest()["per_layer"][-1]["name"] != "fanout_overlap":
+        for name in ("test_the_manifest_gains_one_configuration_one_cell_"
+                     "five_metrics",
+                     "test_the_streaming_cells_entries_are_as_they_were"):
+            skipped[name] = \
+                "holds that the fan-out's five metrics END per_layer, " \
+                "which an entry appended after them ends; " \
+                "test_span_cpu_metrics.py holds every other clause, by entry"
     for item in items:
         reason = skipped.get(item.name)
         if reason and "tests/benchmarks/" in item.nodeid:

@@ -1356,26 +1356,25 @@ class TestCompiledCut:
         with tr._cut_lock:
             assert len(tr._cut_starts) == 16
 
-    def test_a_windowed_echo_cuts_every_piece_with_the_slicer(
-            self, mesh, monkeypatch):
+    def _sixteen_piece_echo(self, mesh, monkeypatch, dev):
         """One echo of a device attachment sixteen pieces long over an
         ``ici://`` connection on one chip (5 MiB: above the native tier's
-        window, so the Python ici plane carries it): the request's sixteen
-        pieces are cut by the compiled slicer, the reply's are whole
-        blocks."""
+        window, so the Python ici plane carries it); the reply is held to
+        the request byte for byte and to sixteen whole blocks on the chip.
+        Returns the two cut counters' growth."""
         from brpc_tpu.ici import transport as tr
         piece = 320 * 1024
         monkeypatch.setattr(tr, "PIECE_BYTES", piece)
-        host, arr = self._block(mesh, dev=5, nbytes=16 * piece, salt=17)
+        host, arr = self._block(mesh, dev=dev, nbytes=16 * piece, salt=17)
         options = rpc.ServerOptions()
         options.usercode_inline = True
         server = rpc.Server(options)
         server.add_service(DeviceEchoService())
-        assert server.start("ici://5") == 0
+        assert server.start(f"ici://{dev}") == 0
         try:
             ch = rpc.Channel()
-            assert ch.init("ici://5", options=rpc.ChannelOptions(
-                ici_local_device=5)) == 0
+            assert ch.init(f"ici://{dev}", options=rpc.ChannelOptions(
+                ici_local_device=dev)) == 0
             before = self._stats()
             cntl = rpc.Controller()
             cntl.request_attachment.append_device_array(arr)
@@ -1386,8 +1385,89 @@ class TestCompiledCut:
             assert cntl.response_attachment.to_bytes() == bytes(host)
             refs = cntl.response_attachment.device_refs()
             assert [r.length for r in refs] == [piece] * 16
-            assert all(set(r.block.data.devices()) == {mesh.device(5)}
+            assert all(set(r.block.data.devices()) == {mesh.device(dev)}
                        for r in refs)
-            assert self._stats() == (before[0] + 16, before[1])
+            after = self._stats()
+            return after[0] - before[0], after[1] - before[1]
         finally:
             server.stop()
+
+    def test_a_windowed_echo_cuts_every_piece_with_the_slicer(
+            self, mesh, monkeypatch):
+        """The request's sixteen pieces are cut by the compiled slicer, the
+        reply's are whole blocks."""
+        assert self._sixteen_piece_echo(mesh, monkeypatch, 5) == (16, 0)
+
+    # -- layer span brpc.ici.cut: the dispatch alone (ISSUE 37) ----------
+
+    @pytest.fixture
+    def session(self, tmp_path):
+        import jax
+        from brpc_tpu.rpc import span
+        span.layer_spans_reset()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            yield span
+        finally:
+            jax.profiler.stop_trace()
+            span.layer_spans_reset()
+
+    def test_in_a_session_each_compiled_cut_has_a_span_inside_its_piece(
+            self, mesh, monkeypatch, session):
+        """Sixteen ``brpc.ici.cut`` for the request's sixteen compiled cuts
+        and none for the reply's whole blocks; each lies inside the
+        ``brpc.ici.relocate`` of the ``brpc.ici.piece`` that caused it, on
+        that piece's thread, and carries its thread's CPU time."""
+        piece = 320 * 1024
+        assert self._sixteen_piece_echo(mesh, monkeypatch, 6) == (16, 0)
+        spans = session.layer_spans()
+        cuts = [s for s in spans if s.name == "brpc.ici.cut"]
+        pieces = {s.span_id: s for s in spans if s.name == "brpc.ici.piece"}
+        relocates = {s.cause_id: s for s in spans
+                     if s.name == "brpc.ici.relocate"}
+        assert len(cuts) == 16 and len(pieces) >= 32
+        assert len({c.cause_id for c in cuts}) == 16    # one a piece
+        for c in cuts:
+            p, r = pieces[c.cause_id], relocates[c.cause_id]
+            assert (c.n, c.m, c.thread) == (piece, 0, p.thread)
+            assert c.call_id == p.call_id
+            assert p.start_ns == r.start_ns <= c.start_ns
+            assert c.end_ns <= r.end_ns <= p.end_ns
+            assert 0 <= c.cpu_ns <= c.end_ns - c.start_ns
+            assert 0 <= p.cpu_ns <= p.end_ns - p.start_ns
+
+    def test_in_a_session_the_eager_fall_back_says_m_1_and_a_whole_ref_nothing(
+            self, mesh, session):
+        import jax
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        from brpc_tpu.ici import transport as tr
+        host, arr = self._block(mesh)
+        spread = jax.device_put(host, NamedSharding(
+            Mesh(np.array(jax.devices()[:2]), ("x",)), P("x")))
+        assert tr._cut(arr, self._ref(0, self.BLOCK)) is arr
+        assert tr._cut(host, self._ref(4096, 4096)).base is host
+        assert session.layer_spans() == []
+        got = tr._cut(spread, self._ref(3 * 4096, 4096))
+        assert bytes(np.asarray(got)) == bytes(host[3 * 4096:4 * 4096])
+        tr._cut(arr, self._ref(4096, 100))
+        eager, compiled = session.layer_spans()
+        assert (eager.name, eager.n, eager.m) == ("brpc.ici.cut", 4096, 1)
+        assert (compiled.name, compiled.n, compiled.m) \
+            == ("brpc.ici.cut", 100, 0)
+        assert eager.cause_id == compiled.cause_id == 0   # no piece open
+
+    def test_a_cut_that_raises_still_ends_its_span(self, mesh, monkeypatch,
+                                                   session):
+        from brpc_tpu.ici import transport as tr
+        host, arr = self._block(mesh)
+
+        def refused(*a):
+            raise RuntimeError("the runtime refused the dispatch")
+        monkeypatch.setattr(tr, "piece_slicer", lambda: refused)
+        with pytest.raises(RuntimeError, match="refused"):
+            tr._cut(arr, self._ref(4096, 4096))
+        cut, = session.layer_spans()
+        assert cut.name == "brpc.ici.cut"
+        after = session.layer_begin("brpc.after")   # the innermost again
+        after.end()
+        assert session.layer_spans()[-1].cause_id == 0

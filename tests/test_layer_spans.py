@@ -594,6 +594,115 @@ def test_a_thread_with_no_span_open_adopts_no_call(empty_store):
                   "brpc.after": 0}
 
 
+# ---- a lexical span's CPU time (ISSUE 37) ------------------------------------
+
+def _spent(how, ms=20):
+    """One lexical span around ``ms`` of sleeping or of spinning."""
+    opened = span.layer_begin(f"brpc.{how}", cpu=True)
+    until = time.perf_counter_ns() + ms * 1_000_000
+    if how == "sleeps":
+        time.sleep(ms / 1e3)
+    else:
+        while time.perf_counter_ns() < until:
+            pass
+    opened.end()
+    got = span.layer_spans(name=f"brpc.{how}")[-1]
+    return got.cpu_ns, got.end_ns - got.start_ns
+
+
+def test_a_span_that_sleeps_ran_little_and_one_that_spins_ran_it_all(
+        empty_store):
+    cpu, wall = _spent("sleeps")
+    assert wall >= 20_000_000 and 0 <= cpu < 5_000_000
+    # a spinning thread can lose its core on a shared host: the best of a
+    # few tries is the thread's own; every try holds 0 <= cpu <= wall
+    tries = [_spent("spins") for _ in range(5)]
+    assert all(0 <= c <= w and w >= 20_000_000 for c, w in tries)
+    assert max(c / w for c, w in tries) > 0.8
+
+
+def test_only_a_span_that_asks_and_ends_where_it_began_has_cpu_time(
+        empty_store):
+    import threading
+    crossing = span.layer_begin("brpc.server.handler", cpu=True)
+    crossing.leave()
+    t = threading.Thread(target=crossing.finish)
+    t.start()
+    t.join(10)
+    assert not t.is_alive()
+    left_then_finished = span.layer_begin("brpc.fanout", cpu=True)
+    left_then_finished.leave()
+    left_then_finished.finish()         # this thread, but not by end()
+    span.layer_record("brpc.stamped", 10, 20)
+    span.layer_waited("brpc.waited", span.layer_mark(3))
+    unasked = span.layer_begin("brpc.unasked")   # the read is a system call
+    unasked.end()
+    lexical = span.layer_begin("brpc.lexical", cpu=True)
+    lexical.end()
+    by = {s.name: s.cpu_ns for s in span.layer_spans()}
+    assert by.pop("brpc.lexical") >= 0
+    assert by == {"brpc.server.handler": -1, "brpc.fanout": -1,
+                  "brpc.stamped": -1, "brpc.waited": -1,
+                  "brpc.unasked": -1}
+
+
+def test_a_span_that_does_not_ask_reads_no_cpu_clock(monkeypatch,
+                                                     empty_store):
+    reads = []
+    real = time.thread_time_ns
+
+    class Clock:
+        perf_counter_ns = staticmethod(time.perf_counter_ns)
+
+        @staticmethod
+        def thread_time_ns():
+            reads.append(1)
+            return real()
+    monkeypatch.setattr(layer_span, "time", Clock)
+    span.layer_begin("brpc.unasked").end()
+    assert reads == []
+    span.layer_begin("brpc.asked", cpu=True).end()
+    assert len(reads) == 2
+
+
+@pytest.mark.parametrize("fields, m", [(8, 0), (9, 5)])
+def test_a_record_of_the_older_shape_still_reads(empty_store, fields, m):
+    """Eight fields (stamped spans, waits) or nine (a lexical span before
+    ``cpu_ns``): ``m`` and ``cpu_ns`` take their defaults."""
+    st = layer_span._thread()
+    st.records.append(("brpc.old", 1, 2, 3, 4, 5, "t", 6, 5)[:fields])
+    got, = span.layer_spans()
+    assert got == span.LayerSpan("brpc.old", 1, 2, 3, 4, 5, "t", 6, m, -1)
+    assert got.cpu_ns == -1 and got.m == m
+
+
+def test_outside_a_session_a_cut_asks_the_predicate_and_reads_no_clock(
+        mesh, monkeypatch, empty_store):
+    import jax
+    from brpc_tpu.ici import transport as tr
+    host = np.arange(64 * 1024, dtype=np.uint32).astype(np.uint8)
+    arr = jax.block_until_ready(jax.device_put(host, mesh.device(1)))
+    ref = type("Ref", (), {"offset": 4096, "length": 4096})()
+    tr._cut(arr, ref)                   # the program and the start exist
+    asked = []
+
+    class NoClock:
+        def __getattr__(self, name):
+            raise AssertionError(f"time.{name} read outside a session")
+    monkeypatch.setattr(layer_span, "time", NoClock())
+    monkeypatch.setattr(layer_span, "_thread",
+                        lambda: pytest.fail("a record outside a session"))
+    really_on = tr._span.layer_on
+
+    def on():
+        asked.append(really_on())
+        return asked[-1]
+    monkeypatch.setattr(tr._span, "layer_on", on)
+    got = tr._cut(arr, ref)
+    assert asked == [False]
+    assert bytes(np.asarray(got)) == bytes(host[4096:8192])
+
+
 # ---- the stream layer's four spans (ISSUE 33) --------------------------------
 
 STREAM_CHUNK = 100
