@@ -17,9 +17,19 @@ Wire model (single-controller JAX):
     IOBuf has the same layout with device refs now resident on the target
     chip.
   * Delivery order per socket is preserved by a per-socket ExecutionQueue;
-    the payload transfer is awaited through DeviceEventDispatcher before
-    the peer's input path runs — "read event fires when the data is in
-    local HBM", exactly the RDMA completion contract.
+    a payload that MOVED (a device-plane transfer, a ``device_put``) is
+    awaited through DeviceEventDispatcher before the peer's input path runs
+    — "the read event fires after what moved has landed in local HBM", the
+    RDMA completion contract.  A ref that is in the target chip's HBM
+    already moves nothing and is delivered as written, by the thread that
+    wrote it: the receiver is handed a ``jax.Array`` whose producer may not
+    have finished, as every jax program is, and what it dispatches on it
+    the runtime orders behind that producer (docs/ICI_DATAPATH.md).
+  * The thread that commits a frame runs the peer's reader too, on the
+    server's side as on the client's: the frame is cut, and a stream's
+    frame consumed, with no hop to a reader tasklet.  A handler runs there
+    only where the server asked for it (``usercode_inline``); otherwise a
+    request gets a tasklet of its own (``queue_last_message``).
 
 In a future multi-controller deployment the relocation step becomes paired
 XLA Send/Recv (the handshake already exchanges device ids, mirroring the
@@ -62,6 +72,11 @@ _g_small_relocations = bvar.Adder("ici_transport_small_relocations")
 # array spread over devices, a block an int32 start cannot index)
 _g_compiled_cuts = bvar.Adder("ici_transport_compiled_cuts")
 _g_eager_cuts = bvar.Adder("ici_transport_eager_cuts")
+# what a delivery waited for: DEVICE refs that were in the target chip's HBM
+# already and were committed with no gate, and arrays that a delivery handed
+# to the device poller (what moved by ``device_put``, a fabric socket's waits)
+_g_resident_ungated = bvar.Adder("ici_transport_resident_refs_ungated")
+_g_gated_arrays = bvar.Adder("ici_transport_gated_arrays")
 
 # the compiled cut's caches (``piece_slicer``, ``_start_operand``)
 _cut_lock = _dbg.make_lock("ici.transport._cut_lock")
@@ -113,14 +128,18 @@ def ici_piece_stats() -> Dict[str, int]:
     that carried their header on borrowed window, pieces cut while earlier
     bytes of the same socket were still un-consumed at the peer, DEVICE
     refs that crossed chips under the device plane's threshold (slice +
-    device_put), and the host-side cuts out of a device array: by the
+    device_put), the host-side cuts out of a device array: by the
     compiled slicer, and by the eager ``arr[a:b]`` left for an array the
-    slicer does not take."""
+    slicer does not take, and what the deliveries waited for: DEVICE refs
+    resident on the target that were committed with no gate, and arrays
+    handed to the device poller."""
     return {"borrowed_header_pieces": _g_borrowed_headers.get_value(),
             "pipelined_pieces": _g_pipelined_pieces.get_value(),
             "small_relocations": _g_small_relocations.get_value(),
             "compiled_cuts": _g_compiled_cuts.get_value(),
-            "eager_cuts": _g_eager_cuts.get_value()}
+            "eager_cuts": _g_eager_cuts.get_value(),
+            "resident_refs_ungated": _g_resident_ungated.get_value(),
+            "gated_arrays": _g_gated_arrays.get_value()}
 
 
 class CreditWindow:
@@ -278,13 +297,17 @@ class CreditWindow:
 
 class OrderedDelivery:
     """Mixin: per-socket in-order commit of received frames whose device
-    payloads become ready asynchronously.  A host-only frame arriving
-    after a device-bearing one must not jump the queue (byte-stream
-    ordering is the transport contract the parsers rely on).
+    payloads land asynchronously.  The contract: the read event fires
+    after what MOVED has landed, and never out of arrival order — a
+    host-only frame arriving after a gated one must not jump the queue
+    (byte-stream ordering is the transport contract the parsers rely on).
 
-    Waits may be plain device arrays (gated through the per-device
-    completion poller) or device-plane transfers / any object exposing
-    ``add_done_callback`` (gated on its completion — the CQ entry)."""
+    Waits are what moved: plain device arrays (gated through the
+    per-device completion poller) or device-plane transfers / any object
+    exposing ``add_done_callback`` (gated on its completion — the CQ
+    entry).  What did not move is not a wait: a ref pass on one chip is
+    delivered as written, and an entry with no waits commits on the thread
+    that enqueued it, behind the entries ahead of it."""
 
     _GUARDED_BY = {"_dq": "_dq_lock", "_dq_draining": "_dq_lock"}
 
@@ -330,6 +353,7 @@ class OrderedDelivery:
             self._drain_deliveries()
 
         if poll_arrays:
+            _g_gated_arrays << len(arrays)
             DeviceEventDispatcher.instance().on_ready(arrays, one_gate)
         for h in handles:
             h.add_done_callback(one_gate)
@@ -384,6 +408,16 @@ class IciSocket(CreditWindow, OrderedDelivery, Socket):
         self._inflight_seq = 0
         self._inflight_lock = _dbg.make_lock("IciSocket._inflight_lock")
 
+    @property
+    def queue_last_message(self) -> bool:
+        """The reader runs on the delivering thread (``commit``): frames
+        are cut, and a stream's consumed in order, with no hop; a handler
+        must not run there unless the server asked for it
+        (``usercode_inline``), so a server side's last message goes to a
+        tasklet of its own like the ones before it."""
+        return self.is_server_side and not getattr(
+            self, "usercode_inline", False)
+
     def inflight_send_blocks(self) -> int:
         """Device source blocks pinned awaiting transfer completion."""
         with self._inflight_lock:
@@ -424,7 +458,9 @@ class IciSocket(CreditWindow, OrderedDelivery, Socket):
         """Move DEVICE refs to the peer's chip (HBM→HBM over ICI); host
         refs pass through as bytes.  Three routes, chosen on the ref's
         BLOCK before anything is cut: resident on the target, the ref is
-        passed (sliced where it is not the whole block); not resident and
+        passed (sliced where it is not the whole block) and nothing moved
+        — the chunk ``(array, length, moved)`` says so, and ``_deliver``
+        waits only for what did; not resident and
         at/above ``ici_device_plane_threshold``, a send WR is posted on the
         device plane with the whole block and ``(offset, length)`` — the
         payload then crosses through a COMPILED transfer program
@@ -459,7 +495,7 @@ class IciSocket(CreditWindow, OrderedDelivery, Socket):
                 # already in the target chip's HBM: pure ref pass — the
                 # zero-copy case the block_pool discipline exists for
                 if resident:
-                    chunks.append((_cut(arr, r), r.length))
+                    chunks.append((_cut(arr, r), r.length, False))
                     with _ici_stats_lock:
                         _ici_device_bytes_moved += r.length
                     continue
@@ -494,7 +530,7 @@ class IciSocket(CreditWindow, OrderedDelivery, Socket):
                 if r.length < _flags.get_flag("ici_device_plane_threshold"):
                     _g_small_relocations << 1
                 self._pin_until_sent(r.block, moved)
-                chunks.append((moved, r.length))
+                chunks.append((moved, r.length, True))
                 with _ici_stats_lock:
                     _ici_device_bytes_moved += r.length
             else:
@@ -505,6 +541,7 @@ class IciSocket(CreditWindow, OrderedDelivery, Socket):
 
     def _deliver(self, peer: "IciSocket", chunks: List) -> None:
         waits: List = []
+        resident = 0
         for c in chunks:
             if isinstance(c, _PlaneDesc):
                 # the matching recv: rendezvous with the posted send —
@@ -512,7 +549,15 @@ class IciSocket(CreditWindow, OrderedDelivery, Socket):
                 c.transfer = _dp.plane().post_recv(c.transfer.uuid)
                 waits.append(c.transfer)
             elif isinstance(c, tuple):
-                waits.append(c[0])
+                # a ref that was on the target chip already is delivered as
+                # written: no transfer to wait for, and what the receiver
+                # dispatches on it the runtime orders behind its producer
+                if c[2]:
+                    waits.append(c[0])
+                else:
+                    resident += 1
+        if resident:
+            _g_resident_ungated << resident
 
         def commit() -> None:
             buf = IOBuf()
@@ -525,12 +570,11 @@ class IciSocket(CreditWindow, OrderedDelivery, Socket):
                     buf.append(c)
             with peer._inbox_lock:
                 peer._inbox.append(buf)
-            ok_inline = (not peer.is_server_side
-                         or getattr(peer, "usercode_inline", False))
-            peer.start_input_event(inline=ok_inline)
+            peer.start_input_event(inline=True)
 
-        # ordered per-socket commit: the read event fires only after the
-        # payload landed in peer HBM, and never out of arrival order
+        # ordered per-socket commit: the read event fires only after what
+        # moved has landed in peer HBM, and never out of arrival order; with
+        # no waits it fires here, on the writer's thread
         peer._enqueue_delivery(waits, commit)
 
     def _pin_until_sent(self, src_block, moved) -> None:

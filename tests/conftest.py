@@ -301,6 +301,13 @@ def pytest_collection_modifyitems(config, items):
                 "holds that the fan-out's five metrics END per_layer, " \
                 "which an entry appended after them ends; " \
                 "test_span_cpu_metrics.py holds every other clause, by entry"
+    # since PR 38 a stream's chunks, resident on the server's chip, meet no
+    # delivery gate: the cell's window holds no span of the device poller
+    skipped["test_traced_rehearsal_has_every_metric_of_the_stream_layer"] = \
+        "holds that stream_1m's traced line lacks none of the metrics it " \
+        "lists; the four that read the device poller's spans have nothing " \
+        "to read there; tests/test_ungated_rehearsal.py holds every " \
+        "other clause"
     for item in items:
         reason = skipped.get(item.name)
         if reason and "tests/benchmarks/" in item.nodeid:

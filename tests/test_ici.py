@@ -428,21 +428,20 @@ def test_delivery_gate_samples_readiness_once(monkeypatch):
     assert committed.wait(10), "the delivery entry never opened"
 
 
-def test_a_windowed_echos_gates_never_leave_the_poller(mesh, monkeypatch):
-    """Every piece of an echo cut by a small send window is gated through
-    the device poller's inline entry: its commit runs on the poller thread
-    and the hand-off of ``device_on_ready`` is not taken once."""
+def _windowed_echo(mesh, monkeypatch, client_dev, message):
+    """An echo of 5,000,000 DEVICE bytes to ici://5 through a 2 MiB send
+    window — above the native tier's 4 MB window, so the Python ici plane
+    carries it: three pieces each way — with ``_all_ready`` saying no, as
+    the chip does of a block that was just cut.  Returns what the device
+    poller and the transport counted over the call."""
     import jax
     import jax.numpy as jnp
     from brpc_tpu.bthread.device_waiter import DeviceEventDispatcher
     from brpc_tpu.butil import flags as _flags
     from brpc_tpu.ici import transport as tr
-    # above the native tier's 4 MB window, so the Python ici plane carries
-    # it: three pieces each way
     window, nbytes = 2 << 20, 5_000_000
     monkeypatch.setattr(_flags.flag_object("ici_socket_window_bytes"),
                         "value", window)
-    # on the CPU every slice is ready at once: send each through the poller
     monkeypatch.setattr(tr, "_all_ready", lambda arrays: False)
     disp = DeviceEventDispatcher.instance()
     options = rpc.ServerOptions()
@@ -452,23 +451,252 @@ def test_a_windowed_echos_gates_never_leave_the_poller(mesh, monkeypatch):
     assert server.start("ici://5") == 0
     try:
         payload = jax.device_put(
-            jnp.arange(nbytes, dtype=jnp.uint8), mesh.device(5))
+            jnp.arange(nbytes, dtype=jnp.uint8), mesh.device(client_dev))
         ch = rpc.Channel()
-        assert ch.init("ici://5",
-                       options=rpc.ChannelOptions(ici_local_device=5)) == 0
-        handed, completed = disp.handoffs(), sum(disp.stats().values())
+        assert ch.init("ici://5", options=rpc.ChannelOptions(
+            ici_local_device=client_dev)) == 0
+        before = dict(tr.ici_piece_stats(), handoffs=disp.handoffs(),
+                      completions=sum(disp.stats().values()))
         cntl = rpc.Controller()
         cntl.request_attachment.append_device_array(payload)
         resp = ch.call_method("EchoService.Echo", cntl,
-                              EchoRequest(message="windowed"), EchoResponse)
+                              EchoRequest(message=message), EchoResponse)
         assert not cntl.failed(), cntl.error_text
-        assert resp.message == "windowed"
+        assert resp.message == message
         assert cntl.response_attachment.to_bytes() == bytes(
             np.asarray(payload))
-        assert sum(disp.stats().values()) >= completed + 6
-        assert disp.handoffs() == handed
+        after = dict(tr.ici_piece_stats(), handoffs=disp.handoffs(),
+                     completions=sum(disp.stats().values()))
+        return {k: after[k] - before[k] for k in after}
     finally:
         server.stop()
+
+
+def test_a_windowed_echos_gates_never_leave_the_poller(mesh, monkeypatch):
+    """The route that still gates: an echo between two devices of the mesh
+    under the device plane (on the CPU mesh every relocation is slice +
+    ``device_put``).  Every piece MOVED, so every piece is gated through
+    the device poller's inline entry: its commit runs on the poller thread
+    and the hand-off of ``device_on_ready`` is not taken once."""
+    moved = _windowed_echo(mesh, monkeypatch, 4, "windowed")
+    assert moved["gated_arrays"] == 6 and moved["resident_refs_ungated"] == 0
+    assert moved["completions"] >= 6
+    assert moved["handoffs"] == 0
+
+
+def test_a_windowed_echo_on_one_chip_meets_no_gate(mesh, monkeypatch):
+    """Both ends on chip 5: nothing moves, so no piece waits for the device
+    poller although none of them says it is ready; the two counters add up
+    to the echo's six DEVICE refs either way."""
+    passed = _windowed_echo(mesh, monkeypatch, 5, "resident")
+    assert passed["resident_refs_ungated"] == 6
+    assert passed["gated_arrays"] == 0
+    assert passed["completions"] == 0 and passed["handoffs"] == 0
+    # the request's three were cut out of the caller's block, the reply's
+    # three passed whole
+    assert passed["compiled_cuts"] == 3 and passed["small_relocations"] == 0
+
+
+def _socket_pair(mesh, local, remote, window=1 << 20):
+    """Two connected in-process sockets, ``local`` -> ``remote`` and back."""
+    from brpc_tpu.ici.transport import IciSocket
+    a = IciSocket(local, remote, mesh, window_bytes=window)
+    b = IciSocket(remote, local, mesh, window_bytes=window)
+    a.peer, b.peer = b, a
+    return a, b
+
+
+class TestResidentRefsAreNotGated:
+    """``IciSocket._deliver`` waits for what MOVED.  A DEVICE ref whose
+    block is in the target chip's HBM already is delivered as written, by
+    the thread that wrote it, whether or not its producer has finished."""
+
+    @pytest.fixture
+    def no_array_is_ready(self, monkeypatch):
+        """``_all_ready`` says no (the chip's answer for a block just cut)
+        and the device poller only collects what it is handed."""
+        from brpc_tpu.ici import transport as T
+        handed = []
+
+        class FakeDisp:
+            def on_ready(self, arrays, cb):
+                handed.append((list(arrays), cb))
+
+        monkeypatch.setattr(T, "_all_ready", lambda arrays: False)
+        monkeypatch.setattr(T.DeviceEventDispatcher, "instance",
+                            classmethod(lambda cls: FakeDisp()))
+        return handed
+
+    def _pair(self, mesh, local, remote):
+        a, b = _socket_pair(mesh, local, remote)
+        events = []
+        b.start_input_event = lambda inline=False: events.append(
+            threading.get_ident())
+        return a, b, events
+
+    @pytest.mark.parametrize("offset,length", [(0, 4096), (1024, 2048)],
+                             ids=["whole", "cut"])
+    def test_it_commits_on_the_writing_thread(self, mesh, no_array_is_ready,
+                                              offset, length):
+        import jax
+        import jax.numpy as jnp
+        from brpc_tpu.butil.iobuf import IOBuf
+        from brpc_tpu.ici import transport as T
+        a, b, events = self._pair(mesh, 0, 0)
+        try:
+            arr = jax.device_put(jnp.arange(4096, dtype=jnp.uint8),
+                                 mesh.device(0))
+            whole = IOBuf()
+            whole.append_device_array(arr)
+            whole.pop_front(offset)
+            buf = whole.cut(length)
+            before = T.ici_piece_stats()
+            assert a.write(buf) == 0
+            # committed before write() returned, by this thread, and the
+            # poller was not asked
+            assert events == [threading.get_ident()]
+            assert no_array_is_ready == []
+            refs = b._inbox.device_refs()
+            assert len(refs) == 1 and len(b._inbox) == length
+            if length == 4096:          # delivered as written: the same array
+                assert refs[0].block.data is arr
+            assert b._inbox.to_bytes() == bytes(
+                np.arange(4096, dtype=np.uint8)[offset:offset + length])
+            after = T.ici_piece_stats()
+            assert after["resident_refs_ungated"] == \
+                before["resident_refs_ungated"] + 1
+            assert after["gated_arrays"] == before["gated_arrays"]
+        finally:
+            a.set_failed()
+            b.set_failed()
+
+    def test_a_host_frame_behind_a_moved_ref_still_waits(
+            self, mesh, no_array_is_ready):
+        """What moved keeps its gate, and the byte stream its order: the
+        host frame written after it is committed behind it."""
+        import jax
+        import jax.numpy as jnp
+        from brpc_tpu.butil.iobuf import IOBuf
+        from brpc_tpu.ici import transport as T
+        if mesh.size < 2:
+            pytest.skip("needs 2 devices")
+        a, b, events = self._pair(mesh, 0, 1)
+        try:
+            arr = jax.device_put(jnp.arange(1024, dtype=jnp.uint8),
+                                 mesh.device(0))
+            buf = IOBuf()
+            buf.append_device_array(arr)
+            before = T.ici_piece_stats()
+            assert a.write(buf) == 0
+            assert a.write(IOBuf(b"after")) == 0
+            assert events == [] and len(b._inbox) == 0
+            after = T.ici_piece_stats()
+            assert after["gated_arrays"] == before["gated_arrays"] + 1
+            assert after["resident_refs_ungated"] == \
+                before["resident_refs_ungated"]
+            # the source block's pin and the delivery's gate
+            assert len(no_array_is_ready) == 2
+            for _arrays, cb in no_array_is_ready:
+                cb()
+            assert len(events) == 2
+            assert b._inbox.to_bytes() == bytes(
+                np.arange(1024, dtype=np.uint8) & 0xFF) + b"after"
+            moved = b._inbox.device_refs()[0].block.data
+            assert mesh.device(1) in moved.devices()
+        finally:
+            a.set_failed()
+            b.set_failed()
+
+
+class TestTheDeliveringThreadReads:
+    """``commit`` runs the peer's reader where it is — the writer's thread
+    for a frame with no waits, the poller's for a gated one — on the server
+    side as on the client's: frames are cut, and a stream's consumed in
+    order, with no hop to a reader tasklet.  What must not run there is a
+    handler the server did not ask to have inline."""
+
+    class Messenger:
+        def __init__(self):
+            self.seen = []
+
+        def on_new_messages(self, sock):
+            from brpc_tpu.butil.iobuf import IOPortal
+            assert sock._do_read(IOPortal(), 1 << 20) > 0
+            self.seen.append(("read", threading.get_ident()))
+            return ("proto", "last")
+
+        def process_in_place(self, last, sock):
+            self.seen.append(("in_place", threading.get_ident()))
+
+        def _queue_message(self, proto, msg, sock):
+            self.seen.append(("queued", threading.get_ident()))
+
+    @pytest.mark.parametrize("server_side,usercode_inline,last", [
+        (False, False, "in_place"),     # a client's socket: the response
+        (True, True, "in_place"),       # the server asked for it
+        (True, False, "queued"),        # a handler gets a tasklet of its own
+    ])
+    def test_where_the_last_message_runs(self, mesh, server_side,
+                                         usercode_inline, last):
+        from brpc_tpu.butil.iobuf import IOBuf
+        a, b = _socket_pair(mesh, 0, 0)
+        b.is_server_side = server_side
+        b.usercode_inline = usercode_inline     # what Server._on_accept sets
+        b.messenger = self.Messenger()
+        try:
+            assert b.queue_last_message is (last == "queued")
+            assert a.write(IOBuf(b"frame")) == 0
+            me = threading.get_ident()
+            # read before write() returned, by this thread, whoever's side
+            assert b.messenger.seen == [("read", me), (last, me)]
+        finally:
+            a.set_failed()
+            b.set_failed()
+
+    @pytest.mark.parametrize("usercode_inline", [False, True])
+    def test_a_handler_runs_inline_only_where_the_server_asked(
+            self, mesh, monkeypatch, usercode_inline):
+        """End to end over the Python plane on one chip: the request is cut
+        and parsed on the caller's thread either way, the handler runs there
+        only under ``usercode_inline``."""
+        import jax
+        import jax.numpy as jnp
+        ran = []
+
+        class Where(rpc.Service):
+            SERVICE_NAME = "EchoService"
+
+            @rpc.method(EchoRequest, EchoResponse)
+            def Echo(self, cntl, request, response, done):
+                ran.append(threading.get_ident())
+                response.message = request.message
+                cntl.response_attachment.append(cntl.request_attachment)
+                done()
+
+        options = rpc.ServerOptions()
+        options.usercode_inline = usercode_inline
+        server = rpc.Server(options)
+        server.add_service(Where())
+        assert server.start("ici://7") == 0
+        try:
+            ch = rpc.Channel()
+            assert ch.init("ici://7", options=rpc.ChannelOptions(
+                ici_local_device=7)) == 0
+            monkeypatch.setattr(ch, "_native_ici_binding", lambda cntl: None)
+            payload = jax.device_put(jnp.arange(4096, dtype=jnp.uint8),
+                                     mesh.device(7))
+            cntl = rpc.Controller()
+            cntl.request_attachment.append_device_array(payload)
+            resp = ch.call_method("EchoService.Echo", cntl,
+                                  EchoRequest(message="where"), EchoResponse)
+            assert not cntl.failed(), cntl.error_text
+            assert resp.message == "where"
+            assert cntl.response_attachment.to_bytes() == bytes(
+                np.asarray(payload))
+            assert len(ran) == 1
+            assert (ran[0] == threading.get_ident()) is usercode_inline
+        finally:
+            server.stop()
 
 
 class TestBorrowedHeaderWindow:

@@ -391,6 +391,40 @@ def test_a_piece_says_what_was_unconsumed_when_it_was_cut(mesh, monkeypatch):
         span.layer_spans_reset()
 
 
+def test_an_ungated_delivery_still_records_its_gate(mesh, monkeypatch):
+    """A DEVICE ref that is on the target chip already waits for nothing,
+    ready or not; its entry still records ``brpc.ici.gate`` — no waits
+    (``n`` 0), opened and closed by the writer's thread inside the piece —
+    so ``delivery_gate_ms_per_call`` reads about 0 there, and not nothing.
+    No span of the poller is left."""
+    import jax.profiler  # noqa: F401
+    from brpc_tpu.butil.iobuf import IOBuf
+    from brpc_tpu.ici import transport
+    layer_span.layer_on()   # binds the annotation class
+    span.layer_spans_reset()
+    monkeypatch.setattr(layer_span, "layer_on", lambda: True)
+    monkeypatch.setattr(transport, "_all_ready", lambda arrays: False)
+    a, b = transport.IciSocket(0, 0, mesh), transport.IciSocket(0, 0, mesh)
+    a.peer, b.peer = b, a
+    b.start_input_event = lambda inline=False: None
+    try:
+        buf = IOBuf()
+        buf.append_device_array(_payload(mesh, 0, 4096))
+        assert a.write(buf) == 0
+        spans = span.layer_spans()
+        piece, gate = _one(spans, "brpc.ici.piece"), _one(spans,
+                                                          "brpc.ici.gate")
+        assert gate.cause_id == piece.span_id and gate.n == 0
+        assert gate.thread == piece.thread
+        assert piece.start_ns <= gate.start_ns <= gate.end_ns <= piece.end_ns
+        assert not [s for s in spans if s.name.startswith("brpc.poller.")]
+    finally:
+        a.set_failed()
+        b.set_failed()
+        monkeypatch.undo()
+        span.layer_spans_reset()
+
+
 def test_bulk_call_also_records_server_stages(traced):
     spans = traced["bulk"]
     call = _one(spans, "brpc.call")

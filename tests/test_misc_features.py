@@ -148,8 +148,11 @@ class TestReplyBeatsTheWritesReturn:
 
     @pytest.fixture
     def python_plane(self, monkeypatch):
-        """call(attachment=None) -> controller, over ici://6 with the native
-        tier's binding off; gives the server too."""
+        """call(attachment=None) -> controller, from chip 5 over ici://6
+        with the native tier's binding off; gives the server too.  The
+        caller sits on the next chip so that a DEVICE attachment MOVES and
+        its delivery is gated: a ref pass on one chip commits on the
+        writer's own thread, inside ``sock.write``."""
         opts = rpc.ServerOptions()
         opts.usercode_inline = True
         server = rpc.Server(opts)
@@ -157,7 +160,7 @@ class TestReplyBeatsTheWritesReturn:
         assert server.start("ici://6") == 0
         ch = rpc.Channel()
         ch.init("ici://6", options=rpc.ChannelOptions(
-            connection_type="pooled", timeout_ms=60000, ici_local_device=6))
+            connection_type="pooled", timeout_ms=60000, ici_local_device=5))
         monkeypatch.setattr(ch, "_native_ici_binding", lambda cntl: None)
 
         def call(message, attachment=None, cntl=None):
@@ -202,7 +205,8 @@ class TestReplyBeatsTheWritesReturn:
         from brpc_tpu.bthread.timer_thread import TimerThread
         from brpc_tpu.ici import transport as tr
         call, _server = python_plane
-        # every delivery through the poller: the reply ends the call there
+        # every delivery of what moved through the poller: the reply ends
+        # the call there
         monkeypatch.setattr(tr, "_all_ready", lambda arrays: False)
         ending, armed = threading.Event(), threading.Event()
         cntl = rpc.Controller()
@@ -223,7 +227,7 @@ class TestReplyBeatsTheWritesReturn:
             return real_end(chan, c)
         monkeypatch.setattr(rpc.Channel, "_on_call_end", end_slowly)
         block = jax.device_put(jnp.arange(4096, dtype=jnp.uint8),
-                               jax.devices()[6])
+                               jax.devices()[5])
         call("late", attachment=block, cntl=cntl)
         assert armed.is_set() and cntl._timeout_timer is not None
         assert not TimerThread.instance()._entries.get(cntl._timeout_timer)

@@ -257,6 +257,54 @@ class TestStreamingRealTransports:
         assert server.start("ici://61") == 0
         self._run_roundtrip(server, "ici://61")
 
+    def test_over_ici_a_frame_is_consumed_by_the_thread_that_wrote_it(
+            self, monkeypatch):
+        """The in-process ici socket's reader runs on the delivering
+        thread, the server's side too: a DATA frame reaches the server
+        stream's ``on_data`` on the writer's own thread (no reader tasklet
+        between them), and the handler stays on the stream's consumer."""
+        from brpc_tpu.rpc import stream as stream_mod
+        reads, handled = [], []
+        real = stream_mod.Stream.on_data
+
+        def on_data(self, data):
+            reads.append((self.is_client, threading.get_ident()))
+            return real(self, data)
+        monkeypatch.setattr(stream_mod.Stream, "on_data", on_data)
+
+        class Where(Collector):
+            def on_received_messages(self, sid, msgs):
+                handled.append(threading.get_ident())
+                super().on_received_messages(sid, msgs)
+
+        server = rpc.Server()
+        server.add_service(StreamingEchoService())
+        assert server.start("ici://62") == 0
+        try:
+            ch = rpc.Channel()
+            ch.init("ici://62")
+            collector = Where()
+            cntl = rpc.Controller()
+            stream = rpc.stream_create(
+                cntl, rpc.StreamOptions(handler=collector))
+            ch.call_method("StreamingEchoService.StartStream", cntl,
+                           EchoRequest(message="s"), EchoResponse)
+            assert not cntl.failed(), cntl.error_text
+            assert stream.wait_connected(5)
+            for i in range(5):
+                assert stream.write(IOBuf(b"%03d" % i), timeout=10) == 0
+            deadline = time.time() + 15
+            while len(collector.messages) < 5 and time.time() < deadline:
+                time.sleep(0.01)
+            assert len(collector.messages) == 5
+            me = threading.get_ident()
+            at_server = [t for is_client, t in reads if not is_client]
+            assert at_server == [me] * 5
+            assert handled and me not in handled
+            stream.close()
+        finally:
+            server.stop()
+
 
 class _FakeBulkWire:
     """The shared uuid->bytes frame map of a bulk connection pair.  The
