@@ -10,4 +10,5 @@ from .collective_lowering import (CollectiveChannel, MERGE_SUM, MERGE_GATHER,
                                   MAP_SHARD)
 from .collective_fanout import (CollectiveFanoutPlane, CollectiveMerger,
                                 ShardingCallMapper, ReplicateFanoutMapper,
+                                fanout_reduce_stats,
                                 register_device_handler)

@@ -72,8 +72,10 @@ import itertools
 import threading
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from .. import bvar
 from ..butil import debug_sync as _dbg
 from ..butil import flags as _flags
+from ..butil import layer_span as _span
 from ..butil import logging as log
 from ..butil.iobuf import IOBuf
 from . import parallel_channel as _pc
@@ -469,6 +471,24 @@ class ReplicateFanoutMapper:
         return SubCall(request)
 
 
+# the reduce's own totals (``fanout_reduce_stats()``, ``/vars``
+# ``rpc_fanout_reduce_<key>``), process-wide and kept past a channel's close:
+# ``programs`` dispatches of ``brpc_fanout_gather``; ``input_bytes`` /
+# ``input_blocks`` the sub-replies' DEVICE refs that went into them;
+# ``output_bytes`` the ONE array each gave; ``host_merges`` merges made with
+# numpy (``_merge_host``: a host operand, or a member that answered from the
+# host); ``lazy_reads`` programs that ran because ``fanout_result`` was read
+# (gather and concat), not at the finalize (sum)
+_REDUCE_KEYS = ("programs", "input_bytes", "output_bytes", "input_blocks",
+                "host_merges", "lazy_reads")
+_gr = {k: bvar.Adder(f"rpc_fanout_reduce_{k}") for k in _REDUCE_KEYS}
+
+
+def fanout_reduce_stats() -> Dict[str, int]:
+    """What the merges of this process's operand fan-outs have done."""
+    return {k: v.get_value() for k, v in _gr.items()}
+
+
 _gather_program = None
 
 
@@ -511,25 +531,44 @@ def _gather_jit():
     return _gather_program
 
 
-def _gather(parts, merge: str, dtype: str, shard_shape, home):
+def _gather(parts, merge: str, dtype: str, shard_shape, home, mark=None):
     """ONE device array out of the sub-replies' DEVICE refs, in the order
-    given, on ``home``: one ``brpc_fanout_gather`` program."""
+    given, on ``home``: one ``brpc_fanout_gather`` program, dispatched and
+    not waited for (a sum runs here under the parent's lock).  A partial ref
+    is cut by the transport's compiled slicer (``transport._cut``), never by
+    jnp's eager ``__getitem__``.  Layer span ``brpc.fanout.reduce``: the walk
+    over the refs and the dispatch (n: the refs' bytes, m: their count); its
+    cause is the operation's ``brpc.fanout`` (``mark``) wherever it runs."""
     import jax
-    blocks = []
-    for part in parts:
-        bs = []
-        for r in part.device_refs():
-            a = r.block.data
-            a = a if a.ndim == 1 else a.reshape(-1)
-            if r.offset or r.length != a.shape[0]:
-                a = a[r.offset:r.offset + r.length]
-            if home is not None and home not in a.devices():
-                a = jax.device_put(a, home)
-            bs.append(a)
-        blocks.append(tuple(bs))
-    return _gather_jit()(tuple(blocks), merge, dtype,
-                         tuple(shard_shape) if shard_shape is not None
-                         else None)
+    from ..ici.transport import _cut
+    refs = [part.device_refs() for part in parts]
+    nbytes = sum(r.length for rs in refs for r in rs)
+    nblocks = sum(map(len, refs))
+    ls = _span.layer_begin("brpc.fanout.reduce", n=nbytes, mark=mark,
+                           m=nblocks, cpu=True) \
+        if _span.layer_on() else None
+    try:
+        blocks = []
+        for rs in refs:
+            bs = []
+            for r in rs:
+                a = r.block.data
+                a = _cut(a if a.ndim == 1 else a.reshape(-1), r)
+                if home is not None and home not in a.devices():
+                    a = jax.device_put(a, home)
+                bs.append(a)
+            blocks.append(tuple(bs))
+        out = _gather_jit()(tuple(blocks), merge, dtype,
+                            tuple(shard_shape) if shard_shape is not None
+                            else None)
+    finally:
+        if ls is not None:
+            ls.end()
+    _gr["programs"] << 1
+    _gr["input_bytes"] << nbytes
+    _gr["input_blocks"] << nblocks
+    _gr["output_bytes"] << out.nbytes
+    return out
 
 
 class CollectiveMerger:
@@ -589,18 +628,27 @@ class CollectiveMerger:
                 [p.to_bytes() for p in ordered])
             return
         home = d["_fanout_device"]
+        # the operation's brpc.fanout, where a session is on (parallel_
+        # channel.py leaves it): the cause of the reduce's span, whenever
+        # and on whichever thread the one array is made
+        mark = d.pop("_fanout_mark", None)
 
         def one_array():
             return _gather(ordered, self.collective_merge, self.dtype,
-                           self.shard_shape, home)
+                           self.shard_shape, home, mark)
+
+        def one_array_on_read():
+            _gr["lazy_reads"] << 1
+            return one_array()
 
         if self.collective_merge == MERGE_SUM:
             parent_cntl.fanout_result = one_array()
         else:
-            d["_fanout_result_lazy"] = one_array
+            d["_fanout_result_lazy"] = one_array_on_read
 
     def _merge_host(self, blobs):
         import numpy as np
+        _gr["host_merges"] << 1
         arrs = []
         for blob in blobs:
             a = np.frombuffer(blob, dtype=self.dtype)

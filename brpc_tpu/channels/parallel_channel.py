@@ -22,7 +22,10 @@ only while a jax profiler session is on: ``brpc.fanout`` (``call_method``
 entry → the operation's end), ``brpc.fanout.issue`` (one a sub-call: map and
 the sub-call's start, its ``brpc.call`` inside), ``brpc.fanout.wait`` (the
 caller parked for the last sub-call) and ``brpc.fanout.merge`` (a sub-reply
-folded under the parent's lock, and the finalize).
+folded under the parent's lock, and the finalize); inside the finalize, or
+where ``fanout_result`` is read, ``brpc.fanout.reduce`` (collective_fanout.py:
+the one array made of the sub-replies' DEVICE refs, with totals of its own,
+``fanout_reduce_stats()``).
 """
 from __future__ import annotations
 
@@ -348,9 +351,13 @@ class _ParallelCallState:
                     f"{self.sub_errors[:4]}")
             else:
                 # m=1: the finalize, not a sub-reply's merge
-                ls = _span.layer_begin("brpc.fanout.merge", n=self.merged,
-                                       mark=self.mark, m=1) \
-                    if self.mark is not None else None
+                ls = None
+                if self.mark is not None:
+                    ls = _span.layer_begin("brpc.fanout.merge",
+                                           n=self.merged, mark=self.mark,
+                                           m=1)
+                    # for the finalizer's brpc.fanout.reduce
+                    self.cntl.__dict__["_fanout_mark"] = self.mark
                 try:
                     self.finalizer.finalize_fanout(self.cntl)
                 except Exception as e:

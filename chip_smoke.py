@@ -483,6 +483,7 @@ def phase_operand_fanout(rng, dev) -> None:
             connection_type="pooled"))
         host = rng.integers(0, 256, size=(width, shard), dtype=np.uint8)
         answers = host ^ np.uint8(0x5A)
+        floats = (host[1].view(np.uint32) >> 9).astype(np.float32) / 64
         cases = (
             ("shard+concat uint8", channels.MAP_SHARD,
              channels.MERGE_CONCAT, "uint8", host, answers.reshape(-1)),
@@ -494,7 +495,13 @@ def phase_operand_fanout(rng, dev) -> None:
              answers.view(np.float32)),
             ("replicate+gather float32", channels.MAP_REPLICATE,
              channels.MERGE_GATHER, "float32", host[0].view(np.float32),
-             np.stack([answers[0].view(np.float32)] * width)))
+             np.stack([answers[0].view(np.float32)] * width)),
+            # well-formed floats: the operand is the bytes that the xor
+            # turns into ``floats``, and every member answers ``floats``
+            ("replicate+sum float32", channels.MAP_REPLICATE,
+             channels.MERGE_SUM, "float32",
+             (floats.view(np.uint8) ^ np.uint8(0x5A)).view(np.float32),
+             functools.reduce(np.add, [floats] * width)))
         for label, mapping, merge, dtype, operand, want in cases:
             pc = channels.ParallelChannel(fail_limit=1)
             mapper = channels.ShardingCallMapper() \

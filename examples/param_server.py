@@ -6,6 +6,16 @@ A data-parallel trainer over the ICI mesh: each device computes a gradient
 shard, ParallelChannel-merge-as-psum synchronizes them (one compiled
 collective per step), and orbax checkpoints the replicated params so
 training resumes exactly where it stopped.
+
+Which route the benchmark measures: this example runs the LOWERED route (a
+``CollectiveChannel`` over the mesh: one SPMD program, the merge a ``psum``).
+The benchmark's deployment ``param_server_local`` (cell ``pushpull_4x16m``,
+``benchmarks/configs/param_server_local.json``) measures the other one, the
+per-member loop on ONE chip: a ``ParallelChannel`` of four sub-channels, the
+range replicated by reference, each worker's float32 contribution an RPC
+reply, ``CollectiveMerger(MERGE_SUM)`` summing them in one device program
+(``brpc_fanout_gather``) into ``cntl.fanout_result``.  The mesh ``psum`` is
+not measured by any cell yet (PERF.md section 7, ``allreduce_1g``).
 """
 from __future__ import annotations
 
