@@ -329,9 +329,11 @@ def _carrier(dt):
     TPU's ``concatenate`` and ``stack`` of float32 quiet every NaN and flush
     every denormal (chip run, PR 35: 8,178 of 8,178 such values of 2**20
     random bit patterns changed; views and bitcasts changed none), and an
-    RPC's bytes arrive as they were sent."""
+    RPC's bytes arrive as they were sent.  bfloat16 is a float here too,
+    though numpy's ``kind`` calls it ``V``: moved as itself even the CPU's
+    ``stack`` quiets its NaNs."""
     import jax.numpy as jnp
-    if dt.kind == "f" and dt.itemsize <= 4:
+    if jnp.issubdtype(dt, jnp.floating) and dt.itemsize <= 4:
         return jnp.dtype(f"uint{8 * dt.itemsize}")
     return dt
 
@@ -489,13 +491,41 @@ def fanout_reduce_stats() -> Dict[str, int]:
     return {k: v.get_value() for k, v in _gr.items()}
 
 
+# how the reduce's typed view went (``fanout_view_stats()``, ``/vars``
+# ``rpc_fanout_view_<key>``), in blocks of the sub-replies: ``in_layout_blocks``
+# had their words built in the byte tiling they arrived in
+# (``byte_words.words``); ``relayout_blocks`` took ``.view``, a relayout on
+# the TPU (a part with a block that is no whole tile, an 8-byte dtype).  A
+# 1-byte dtype is viewed by neither and counted in neither.
+_VIEW_KEYS = ("in_layout_blocks", "relayout_blocks")
+_gv = {k: bvar.Adder(f"rpc_fanout_view_{k}") for k in _VIEW_KEYS}
+
+
+def fanout_view_stats() -> Dict[str, int]:
+    """How the merges' typed views of this process's sub-replies went."""
+    return {k: v.get_value() for k, v in _gv.items()}
+
+
 _gather_program = None
 
 
+def _part_words(bs, carrier):
+    """One part's blocks (flat ``uint8``) as ONE array of ``carrier``: each
+    block's words built in its own tiling where every block of the part is
+    ``byte_words.in_layout``, else the blocks joined and ``.view``-ed."""
+    import jax.numpy as jnp
+    from . import byte_words
+    if byte_words.in_layout((b.shape[0] for b in bs), carrier.itemsize):
+        ws = [byte_words.words(b, carrier) for b in bs]
+        return ws[0] if len(ws) == 1 else jnp.concatenate(ws)
+    a = bs[0] if len(bs) == 1 else jnp.concatenate(bs)
+    return a.view(carrier)
+
+
 def _gather_jit():
-    """The one jitted ``brpc_fanout_gather``: each part's blocks joined,
-    viewed as ``dtype`` / ``shard_shape`` (a float as the integer of its
-    width until the end, ``_carrier``), then stacked, concatenated or
+    """The one jitted ``brpc_fanout_gather``: each part's blocks as ONE array
+    of ``dtype`` / ``shard_shape`` (a float as the integer of its width until
+    the end, ``_carrier``; ``_part_words``), then stacked, concatenated or
     summed.  Built on first use (this module imports no jax)."""
     global _gather_program
     if _gather_program is None:
@@ -515,8 +545,7 @@ def _gather_jit():
 
                 arrs = []
                 for bs in blocks:
-                    a = bs[0] if len(bs) == 1 else jnp.concatenate(bs)
-                    a = a.view(carrier)
+                    a = _part_words(bs, carrier)
                     if shard_shape is not None:
                         a = a.reshape(shard_shape)
                     arrs.append(a)
@@ -540,7 +569,9 @@ def _gather(parts, merge: str, dtype: str, shard_shape, home, mark=None):
     over the refs and the dispatch (n: the refs' bytes, m: their count); its
     cause is the operation's ``brpc.fanout`` (``mark``) wherever it runs."""
     import jax
+    import jax.numpy as jnp
     from ..ici.transport import _cut
+    from .byte_words import in_layout
     refs = [part.device_refs() for part in parts]
     nbytes = sum(r.length for rs in refs for r in rs)
     nblocks = sum(map(len, refs))
@@ -568,6 +599,12 @@ def _gather(parts, merge: str, dtype: str, shard_shape, home, mark=None):
     _gr["input_bytes"] << nbytes
     _gr["input_blocks"] << nblocks
     _gr["output_bytes"] << out.nbytes
+    itemsize = _carrier(jnp.dtype(dtype)).itemsize
+    if itemsize > 1:
+        for bs in blocks:
+            _gv["in_layout_blocks" if in_layout(
+                (b.shape[0] for b in bs), itemsize)
+                else "relayout_blocks"] << len(bs)
     return out
 
 

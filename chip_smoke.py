@@ -502,6 +502,19 @@ def phase_operand_fanout(rng, dev) -> None:
              channels.MERGE_SUM, "float32",
              (floats.view(np.uint8) ^ np.uint8(0x5A)).view(np.float32),
              functools.reduce(np.add, [floats] * width)))
+        # the float32 gathers carry NaNs of every payload and denormals: what
+        # the TPU's concatenate of floats would change, and the words built
+        # in the replies' byte tiling (channels/byte_words.py) must not
+        bits = answers.view(np.uint32)
+        exponent, mantissa = bits & 0x7F800000, bits & 0x7FFFFF
+        payloads = np.unique(mantissa[(exponent == 0x7F800000)
+                                      & (mantissa != 0)])
+        denormals = np.count_nonzero((exponent == 0) & (mantissa != 0))
+        check(payloads.size > 100 and denormals > 100,
+              f"the float32 cases hold {payloads.size} NaN payloads and "
+              f"{denormals} denormals")
+        view_before = channels.fanout_view_stats()
+        reduce_before = channels.fanout_reduce_stats()
         for label, mapping, merge, dtype, operand, want in cases:
             pc = channels.ParallelChannel(fail_limit=1)
             mapper = channels.ShardingCallMapper() \
@@ -542,6 +555,16 @@ def phase_operand_fanout(rng, dev) -> None:
                   and delta["sub_calls"] == delta["merges"] == width
                   and delta["device_operand_bytes"] == width * shard,
                   f"fan-out {label}: counters {delta}")
+            # a 4-byte result's words are built in the replies' own tiling
+            views = channels.fanout_view_stats()
+            reduced = channels.fanout_reduce_stats()
+            grew = {k: views[k] - view_before[k] for k in views}
+            blocks = reduced["input_blocks"] - reduce_before["input_blocks"] \
+                if dtype != "uint8" else 0
+            view_before, reduce_before = views, reduced
+            check(blocks >= width * (dtype != "uint8") and grew == {
+                "in_layout_blocks": blocks, "relayout_blocks": 0},
+                f"fan-out {label}: view counters {grew}, {blocks} blocks")
             say(f"[fanout] {label}: {width} x {fmt_bytes(shard)} from a "
                 f"device operand, result on {dev} == numpy, 0 host bytes, "
                 f"{took * 1e3:.0f} ms (smoke timing)")

@@ -401,3 +401,187 @@ def test_no_session_no_span_and_the_totals_are_on_vars(mesh, deployment):
     # not keys of fanout_stats(): its nine stay nine
     assert len(channels.fanout_stats()) == 9
     assert not set(REDUCE_KEYS) & set(channels.fanout_stats())
+
+
+# ---- the typed view: words built in the byte tiling the replies have -------
+
+VIEW_KEYS = ("in_layout_blocks", "relayout_blocks")
+TYPED = ["float32", "int32", "uint32", "float16", "bfloat16"]
+
+
+def _bits(rng, nbytes, dtype):
+    """Random bit patterns of ``dtype``, with NaNs of several payloads and
+    denormals planted in them where it is a float."""
+    raw = rng.integers(0, 256, nbytes, dtype=np.uint8)
+    if dtype in ("float32", "float16", "bfloat16"):
+        width = np.dtype(dtype).itemsize if dtype != "bfloat16" else 2
+        carrier = raw.view(f"<u{width}")
+        exp_bits = {"float32": (23, 8), "float16": (10, 5),
+                    "bfloat16": (7, 8)}[dtype]
+        mant, ebits = exp_bits
+        nan_exp = ((1 << ebits) - 1) << mant
+        carrier[0::7] = (carrier[0::7] | nan_exp) | 1      # NaN payloads
+        carrier[3::11] &= ~np.array(nan_exp, carrier.dtype)  # denormals
+    return raw
+
+
+def _host_dtype(dtype):
+    import ml_dtypes
+    return ml_dtypes.bfloat16 if dtype == "bfloat16" else np.dtype(dtype)
+
+
+def _as_parts(mesh, payloads, blocks, partial=False):
+    """Each payload as an ``IOBuf`` of ``blocks`` DEVICE refs on device 0 —
+    whole blocks, or refs into larger blocks with bytes before and after."""
+    import jax
+    parts = []
+    for i, raw in enumerate(payloads):
+        step = raw.size // blocks
+        part = IOBuf()
+        for k in range(blocks):
+            chunk = raw[k * step:(k + 1) * step]
+            if not partial:
+                part.append_device_array(jax.device_put(chunk,
+                                                        mesh.device(0)))
+                continue
+            lead, tail = 4096 * (i + 1) + 64, 32 * (k + 1)
+            whole = IOBuf()
+            whole.append_device_array(jax.device_put(np.concatenate(
+                [np.full(lead, 0xEE, np.uint8), chunk,
+                 np.full(tail, 0xDD, np.uint8)]), mesh.device(0)))
+            whole.pop_front(lead)
+            whole.pop_back(tail)
+            part.append(whole)
+        parts.append(part)
+    return parts
+
+
+def _grew(before):
+    return {k: v - before[k] for k, v in cf.fanout_view_stats().items()}
+
+
+@pytest.mark.parametrize("layout,blocks,partial", [
+    ("one-block", 1, False), ("four-blocks", 4, False),
+    ("partial-refs", 4, True)])
+@pytest.mark.parametrize("dtype", TYPED)
+def test_the_view_is_numpys_bit_for_bit_through_gather_and_concat(
+        mesh, dtype, layout, blocks, partial):
+    rng = np.random.default_rng([TYPED.index(dtype), blocks, partial])
+    payloads = [_bits(rng, 4 * 4096, dtype) for _ in range(WORKERS)]
+    want = [np.frombuffer(p.tobytes(), _host_dtype(dtype)) for p in payloads]
+    parts = _as_parts(mesh, payloads, blocks, partial)
+    for merge, expect in ((channels.MERGE_GATHER, np.stack(want)),
+                          (channels.MERGE_CONCAT, np.concatenate(want))):
+        before = cf.fanout_view_stats()
+        got = cf._gather(parts, merge, dtype, None, mesh.device(0))
+        got = np.asarray(got)
+        assert got.dtype == expect.dtype and got.shape == expect.shape
+        assert got.tobytes() == expect.tobytes()       # NaN payloads kept
+        assert _grew(before) == {"in_layout_blocks": WORKERS * blocks,
+                                 "relayout_blocks": 0}
+
+
+def test_the_sum_is_numpys_float32_sum_in_sub_channel_order(mesh):
+    """Contributions of mixed magnitudes, so that the order of the adds
+    shows in the bits: ((g0 + g1) + g2) + g3, each add in float32."""
+    rng = np.random.default_rng(41)
+    n = 8 * 4096 // 4
+    gs = [(rng.standard_normal(n) * 10.0 ** rng.integers(-3, 4, n))
+          .astype(np.float32) for _ in range(WORKERS)]
+    want = ((gs[0] + gs[1]) + gs[2]) + gs[3]
+    assert want.tobytes() != (((gs[3] + gs[2]) + gs[1]) + gs[0]).tobytes()
+    parts = _as_parts(mesh, [g.view(np.uint8) for g in gs], 2)
+    before = cf.fanout_view_stats()
+    got = np.asarray(cf._gather(parts, channels.MERGE_SUM, "float32", None,
+                                mesh.device(0)))
+    assert got.dtype == np.float32 and got.tobytes() == want.tobytes()
+    assert _grew(before) == {"in_layout_blocks": 2 * WORKERS,
+                             "relayout_blocks": 0}
+
+
+@pytest.mark.parametrize("lengths,in_layout,relayout", [
+    ((4096 + 8, 4096 - 8), 0, 2 * WORKERS),     # no block a whole tile
+    ((8192, 12), 0, 2 * WORKERS),              # one block of the part
+    ((4096, 4096), 2 * WORKERS, 0),
+], ids=["odd", "one-odd", "whole-tiles"])
+def test_a_part_with_a_block_that_is_no_whole_tile_takes_the_view(
+        mesh, lengths, in_layout, relayout):
+    """The choice is the part's, made from its blocks' lengths alone, and
+    counted in blocks; the answer is numpy's either way."""
+    import jax
+    rng = np.random.default_rng(sum(lengths))
+    payloads = [_bits(rng, sum(lengths), "float32") for _ in range(WORKERS)]
+    parts = []
+    for raw in payloads:
+        part, at = IOBuf(), 0
+        for n in lengths:
+            part.append_device_array(jax.device_put(raw[at:at + n],
+                                                    mesh.device(0)))
+            at += n
+        parts.append(part)
+    before = cf.fanout_view_stats()
+    got = cf._gather(parts, channels.MERGE_CONCAT, "float32", None,
+                     mesh.device(0))
+    assert np.asarray(got).tobytes() == b"".join(p.tobytes()
+                                                 for p in payloads)
+    assert _grew(before) == {"in_layout_blocks": in_layout,
+                             "relayout_blocks": relayout}
+
+
+def test_bytes_are_viewed_by_neither_path_and_counted_in_neither(mesh):
+    rng = np.random.default_rng(7)
+    payloads = [_bits(rng, 8192, "uint8") for _ in range(WORKERS)]
+    before = cf.fanout_view_stats()
+    got = cf._gather(_as_parts(mesh, payloads, 2), channels.MERGE_CONCAT,
+                     "uint8", None, mesh.device(0))
+    assert np.asarray(got).tobytes() == b"".join(p.tobytes()
+                                                 for p in payloads)
+    assert cf.fanout_view_stats() == before
+
+
+@pytest.mark.parametrize("lengths,itemsize,ok", [
+    ((4096,), 4, True), ((4096, 1 << 22), 2, True), ((4096,), 1, False),
+    ((4096,), 8, False), ((4096, 4100), 4, False), ((0,), 4, False),
+    ((), 4, False)])
+def test_in_layout_asks_whole_tiles_of_two_or_four_byte_words(
+        lengths, itemsize, ok):
+    from brpc_tpu.channels import byte_words
+    assert byte_words.TILE_BYTES == 32 * 128
+    assert byte_words.in_layout(lengths, itemsize) is ok
+
+
+@pytest.mark.parametrize("itemsize", [2, 4])
+def test_the_selection_moves_each_byte_once_and_sums_under_two_to_the_16(
+        itemsize):
+    """Every byte of a 512-byte group lands in exactly one half-word, as
+    its low (1) or high (256) byte, and every half-word takes two bytes:
+    each output of the MXU is exact in float32."""
+    from brpc_tpu.channels import byte_words
+    s = byte_words._selection(itemsize)
+    assert s.shape == (512, 256)
+    assert np.array_equal(np.count_nonzero(s, axis=1), np.ones(512))
+    assert np.array_equal(np.count_nonzero(s, axis=0), np.full(256, 2))
+    assert set(np.unique(s)) == {0.0, 1.0, 256.0}
+    assert (s.sum(axis=0) * 255).max() < 2 ** 16
+    # the stream's bytes in order, through the selection, are the words
+    group = np.arange(512, dtype=np.int64) % 251
+    planes = group.reshape(4, 128)                 # packed rows: plane q
+    halves = planes.reshape(-1) @ s                # [256]: half h, lane m
+    words = halves[:128].astype(np.uint32) | (halves[128:].astype(np.uint32)
+                                              << 16)
+    want = group.astype(np.uint8)
+    if itemsize == 4:
+        assert np.array_equal(words, want.view("<u4"))
+    else:                     # packed u16 rows 2a, 2a + 1 of the 32-bit rows
+        as16 = words.view("<u2").reshape(128, 2).T.reshape(-1)
+        assert np.array_equal(as16, want.view("<u2"))
+
+
+def test_the_view_counters_are_on_vars_and_apart_from_the_reduces():
+    from brpc_tpu import bvar
+    assert tuple(channels.fanout_view_stats()) == VIEW_KEYS
+    assert {f"rpc_fanout_view_{k}" for k in VIEW_KEYS} \
+        <= set(bvar.list_exposed())
+    assert not set(VIEW_KEYS) & set(channels.fanout_reduce_stats())
+    assert len(channels.fanout_reduce_stats()) == 6
+    assert len(channels.fanout_stats()) == 9

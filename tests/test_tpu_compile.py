@@ -15,6 +15,8 @@ libtpu, and every xdist worker imports this file), everything built from
 it lives in fixtures too, the compiles run in the test's own process, and
 all of them stay in THIS file so one worker owns the library.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -316,3 +318,31 @@ def test_fanout_cell_programs_at_the_cells_size(one_chip):
     stacked = cf._gather_jit().lower(parts, cf.MERGE_GATHER, "float32",
                                      None).compile()
     assert stacked.memory_analysis().output_size_in_bytes == 64 * MB
+
+
+# the signature of the relayout a byte->word view costs on the TPU: a u8
+# array whose minor dimension is the word's byte count, which the chip pads
+# to 128 lanes (PERF.md §5 pushpull_4x16m: 12.8 of a 20 ms reduce)
+_WORD_RELAYOUT = re.compile(r"u8\[(?:\d+,)*4\]")
+
+
+@pytest.mark.parametrize("merge,out_bytes", [("sum", 16 * MB),
+                                             ("gather", 64 * MB)])
+def test_the_reduce_builds_its_words_in_the_byte_tiling(one_chip, merge,
+                                                        out_bytes):
+    """``pushpull_4x16m``'s reduce (four parts of four u8[4194304] pieces,
+    float32) and a float32 gather of the same replies: ONE program, whose
+    words are built by the ``brpc_byte_words`` kernel from the bytes as they
+    lie — no u8 shape with a minor dimension of 4 anywhere in it."""
+    import jax
+    import jax.numpy as jnp
+    from brpc_tpu.channels import collective_fanout as cf
+    piece = jax.ShapeDtypeStruct((4 * MB,), jnp.uint8, sharding=one_chip)
+    parts = tuple((piece,) * 4 for _ in range(4))
+    merge = {"sum": cf.MERGE_SUM, "gather": cf.MERGE_GATHER}[merge]
+    compiled = cf._gather_jit().lower(parts, merge, "float32",
+                                      None).compile()
+    assert compiled.memory_analysis().output_size_in_bytes == out_bytes
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not _WORD_RELAYOUT.findall(text)
