@@ -12,3 +12,5 @@ from .collective_fanout import (CollectiveFanoutPlane, CollectiveMerger,
                                 ShardingCallMapper, ReplicateFanoutMapper,
                                 fanout_reduce_stats, fanout_view_stats,
                                 register_device_handler)
+from .expert_dispatch import (ExpertBatch, ExpertDispatchMapper,
+                              ExpertCombineMerger, expert_dispatch_stats)

@@ -21,6 +21,11 @@ The two halves are joined by shift and or, and each row of the result is
 128 words of the stream, in order.  Nothing is computed on a value: the
 words are the block's bits, NaN payloads and denormals included.
 
+``word_bytes`` is the way back for 2-byte words (a handler that answers
+bfloat16 rows): the packed 32-bit words of ``u16[n/128, 128]`` hold two
+rows of the stream each, and one 0/1 placement matrix puts each of their
+bytes in the lane its output byte has; the kernel writes ``u8[n/64, 128]``.
+
 Where the program is lowered for the CPU the same kernel runs in Pallas's
 interpreter; which one is decided by the platform the program is lowered
 for (``jax.lax.platform_dependent``), so a compile for a described TPU
@@ -113,3 +118,75 @@ def words(block, carrier):
     out = jax.lax.platform_dependent(
         x, s, tpu=call(False), default=call(True))
     return jax.lax.bitcast_convert_type(out, carrier).reshape(-1)
+
+
+# 2-byte words to a block: words a grid step (u16 rows of 128), 256 KiB in
+_MAX_WORD_ROWS = 1024
+
+
+@functools.lru_cache(maxsize=None)
+def _placement():
+    """``S[512, 512]``, the inverse of ``_selection(2)``: a packed 32-bit
+    input word ``(a, m)`` holds words ``256a + m`` (low half) and
+    ``256a + 128 + m`` (high half) of the stream, so its four byte planes
+    (plane-major columns ``128p + m``) hold the low and high bytes of the
+    words ``256a .. 256a + 255``; output column ``128q + l`` is byte ``q``
+    of output word ``(a, l)``, the stream's byte ``512a + 128q + l``: word
+    ``64q + l // 2`` of the group, its low byte where ``l`` is even."""
+    import numpy as np
+    s = np.zeros((4 * LANES, 4 * LANES), np.float32)
+    for q in range(4):
+        for l in range(LANES):
+            o, t = 64 * q + l // 2, l % 2
+            p, m = (t, o) if o < LANES else (2 + t, o - LANES)
+            s[LANES * p + m, LANES * q + l] = 1.0
+    return s
+
+
+def _bytes_kernel(x_ref, s_ref, o_ref):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.pallas import tpu as pltpu
+    w = pltpu.bitcast(x_ref[...], jnp.int32)
+    planes = [(jax.lax.shift_right_logical(w, 8 * p) & 0xFF)
+              .astype(jnp.float32).astype(jnp.bfloat16) for p in range(4)]
+    y = jnp.dot(jnp.concatenate(planes, axis=1), s_ref[...],
+                preferred_element_type=jnp.float32).astype(jnp.int32)
+    out = y[:, :LANES]
+    for q in range(1, 4):
+        out = out | (y[:, LANES * q:LANES * (q + 1)] << (8 * q))
+    o_ref[...] = pltpu.bitcast(out, jnp.uint8)
+
+
+def word_bytes(words):
+    """The flat ``uint8`` block of ``words`` (a flat 2-byte array whose
+    byte length ``in_layout`` admits), in the order ``words.view(uint8)``
+    gives them, written in the byte tiling: the inverse of ``words(block,
+    uint16)``.  Each byte goes to the MXU as bfloat16 and one 0/1 placement
+    matrix puts it in the lane it belongs to; every output column holds one
+    product, exact.  Traced inside a jitted program."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    rows = words.shape[0] // LANES
+    step = _MAX_WORD_ROWS
+    while rows % step:
+        step //= 2
+
+    def call(interpret):
+        return pl.pallas_call(
+            _bytes_kernel,
+            out_shape=jax.ShapeDtypeStruct((2 * rows, LANES), jnp.uint8),
+            grid=(rows // step,),
+            in_specs=[pl.BlockSpec((step, LANES), lambda i: (i, 0)),
+                      pl.BlockSpec((4 * LANES, 4 * LANES), lambda i: (0, 0))],
+            out_specs=pl.BlockSpec((2 * step, LANES), lambda i: (i, 0)),
+            interpret=interpret,
+            name="brpc_word_bytes",
+        )
+
+    x = jax.lax.bitcast_convert_type(words, jnp.uint16).reshape(rows, LANES)
+    s = jnp.asarray(_placement(), jnp.bfloat16)
+    out = jax.lax.platform_dependent(
+        x, s, tpu=call(False), default=call(True))
+    return out.reshape(-1)

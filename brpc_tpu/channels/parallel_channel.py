@@ -337,8 +337,11 @@ class _ParallelCallState:
 
     def _end_locked(self) -> None:
         self.ended = True
+        # a merger whose skipped sub-calls are empty parts, not missing ones
+        # (an expert rank with no token: expert_dispatch.py)
+        skips_merge = getattr(self.finalizer, "merges_skips", False)
         if self.finalizer is not None and not self.cntl.failed():
-            if self.failed or self.skipped:
+            if self.failed or (self.skipped and not skips_merge):
                 # index-merged collective semantics are all-or-nothing:
                 # a gather/sum missing a shard — whether its sub FAILED
                 # or was mapper-SKIPPED — is WRONG data, not a partial
@@ -367,7 +370,8 @@ class _ParallelCallState:
                 finally:
                     if ls is not None:
                         ls.end()
-            if not self.cntl.failed() and self.merged < self.subs:
+            merged = self.merged + (self.skipped if skips_merge else 0)
+            if not self.cntl.failed() and merged < self.subs:
                 _g["partial_results"] << 1
         if self.span is not None:
             self.span.finish()

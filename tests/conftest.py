@@ -228,11 +228,16 @@ def _resource_census(request):
 # hold the hook itself to the ids of ONE fan-out cell.  test_pushpull_cell.py
 # holds every clause of each that can still hold, by entry and by order, for
 # every cell up to its own, so the next cell appended skips none of them.
+#
+# A seventh cell was appended since (``ep8_dispatch_combine_4096``, whose client
+# ``ep_dispatch`` is made of unary sub-calls too) and skips by the same rules:
+# test_ep8_dispatch_combine_cell.py holds the three unary controls to come
+# out not correct, the breaks where a reply is merged, and the hook's ids.
 
 _UNARY_CONTROLS = ("flipped_byte", "stale_reply", "host_reply")
 # clients whose operations are made of unary calls (they draw call ids, and a
 # control that alters a unary reply in ``done`` reaches them)
-_CLIENTS_OF_UNARY_CALLS = ("fanout", "pushpull")
+_CLIENTS_OF_UNARY_CALLS = ("fanout", "pushpull", "ep_dispatch")
 _BREAKS_AT_THE_CALLS_RETURN = ("corrupted_byte-byte_mismatches",
                                "wrong_chip-misplaced_replies")
 

@@ -346,3 +346,50 @@ def test_the_reduce_builds_its_words_in_the_byte_tiling(one_chip, merge,
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert not _WORD_RELAYOUT.findall(text)
+
+
+# a 2-byte word's bytes as the minor dimension: the relayout of a bfloat16
+# reply viewed as bytes, or of bytes viewed as bfloat16
+_HALF_RELAYOUT = re.compile(r"u8\[(?:\d+,)*2\]")
+
+
+def test_the_expert_ranks_and_the_combine_at_the_cells_size(one_chip):
+    """``ep8_dispatch_combine_4096``'s rank (benchmarks/services/
+    ExpertRank.py: a buffer of 2,960 records of 7,488 B in, as the
+    transport's 4 MiB pieces, 2,960 bfloat16 rows out) and the combine of
+    eight such replies into ONE bfloat16[4096, 7168]: each writes or reads
+    its 2-byte words by a kernel in the byte tiling
+    (``byte_words.word_bytes`` / ``words``), with no u8 shape whose minor
+    dimension is 2."""
+    import os
+    import sys
+    import jax
+    import jax.numpy as jnp
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if repo not in sys.path:
+        sys.path.insert(0, repo)
+    from benchmarks.services.ExpertRank import answer
+    from brpc_tpu.channels import expert_dispatch as ep
+    tokens, hidden = 4096, 7168
+    cap = ep.capacity_for(tokens, hidden, 256, 8, 8)
+    assert cap == 2960
+
+    def pieces(n):
+        sizes = [4 * MB] * (n // (4 * MB)) + [n % (4 * MB)]
+        return tuple(jax.ShapeDtypeStruct((s,), jnp.uint8, sharding=one_chip)
+                     for s in sizes if s)
+
+    request = pieces(cap * ep.record_bytes(hidden, 8))
+    rank = answer.lower(request, tuple((0, b.shape[0]) for b in request),
+                        hidden, 8).compile()
+    assert rank.memory_analysis().output_size_in_bytes == cap * hidden * 2
+    reply = pieces(cap * hidden * 2)
+    place = jax.ShapeDtypeStruct((8, tokens), jnp.int32, sharding=one_chip)
+    combine = ep._combine_program().lower(
+        (reply,) * 8, place, ranks=tuple(range(8)), hidden=hidden).compile()
+    assert combine.memory_analysis().output_size_in_bytes \
+        == tokens * hidden * 2
+    for compiled in (rank, combine):
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text
+        assert not _HALF_RELAYOUT.findall(text)
