@@ -39,6 +39,7 @@ through point-to-point sockets — see collective.py.
 """
 from __future__ import annotations
 
+import collections
 import threading
 import time as _time
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -72,15 +73,24 @@ _g_small_relocations = bvar.Adder("ici_transport_small_relocations")
 # array spread over devices, a block an int32 start cannot index)
 _g_compiled_cuts = bvar.Adder("ici_transport_compiled_cuts")
 _g_eager_cuts = bvar.Adder("ici_transport_eager_cuts")
+# cut runs (``IciSocket._resident_cut``): dispatches of the run slicer, the
+# pieces handed out of a run (its first included), and the pieces a run cut
+# that were never sent (a miss, a closed socket); ``compiled_cuts`` counts
+# a run's pieces too, so compiled_cuts / cut_runs is pieces per dispatch
+_g_cut_runs = bvar.Adder("ici_transport_cut_runs")
+_g_run_pieces = bvar.Adder("ici_transport_run_pieces")
+_g_run_pieces_dropped = bvar.Adder("ici_transport_run_pieces_dropped")
 # what a delivery waited for: DEVICE refs that were in the target chip's HBM
 # already and were committed with no gate, and arrays that a delivery handed
 # to the device poller (what moved by ``device_put``, a fabric socket's waits)
 _g_resident_ungated = bvar.Adder("ici_transport_resident_refs_ungated")
 _g_gated_arrays = bvar.Adder("ici_transport_gated_arrays")
 
-# the compiled cut's caches (``piece_slicer``, ``_start_operand``)
+# the compiled cut's caches (``piece_slicer``, ``run_slicer``,
+# ``_start_operand``)
 _cut_lock = _dbg.make_lock("ici.transport._cut_lock")
 _cut_program = None
+_run_program = None
 _cut_starts: Dict[tuple, Any] = {}
 MAX_CUT_STARTS = 512
 
@@ -90,6 +100,7 @@ _GUARDED_BY_GLOBALS = {
     "_ici_device_bytes_moved": "_ici_stats_lock",
     "_listeners": "_listeners_lock",
     "_cut_program": "_cut_lock",
+    "_run_program": "_cut_lock",
     "_cut_starts": "_cut_lock",
 }
 
@@ -129,15 +140,20 @@ def ici_piece_stats() -> Dict[str, int]:
     bytes of the same socket were still un-consumed at the peer, DEVICE
     refs that crossed chips under the device plane's threshold (slice +
     device_put), the host-side cuts out of a device array: by the
-    compiled slicer, and by the eager ``arr[a:b]`` left for an array the
-    slicer does not take, and what the deliveries waited for: DEVICE refs
-    resident on the target that were committed with no gate, and arrays
-    handed to the device poller."""
+    compiled slicers (pieces, alone or in a run), and by the eager
+    ``arr[a:b]`` left for an array the slicers do not take, the cut runs:
+    dispatches of the run slicer, pieces handed out of a run and pieces a
+    run cut that were never sent, and what the deliveries waited for:
+    DEVICE refs resident on the target that were committed with no gate,
+    and arrays handed to the device poller."""
     return {"borrowed_header_pieces": _g_borrowed_headers.get_value(),
             "pipelined_pieces": _g_pipelined_pieces.get_value(),
             "small_relocations": _g_small_relocations.get_value(),
             "compiled_cuts": _g_compiled_cuts.get_value(),
             "eager_cuts": _g_eager_cuts.get_value(),
+            "cut_runs": _g_cut_runs.get_value(),
+            "run_pieces": _g_run_pieces.get_value(),
+            "run_pieces_dropped": _g_run_pieces_dropped.get_value(),
             "resident_refs_ungated": _g_resident_ungated.get_value(),
             "gated_arrays": _g_gated_arrays.get_value()}
 
@@ -313,7 +329,6 @@ class OrderedDelivery:
 
     # fablint: init
     def _init_delivery(self) -> None:
-        import collections
         self._dq = collections.deque()    # entries: [ready, commit_fn, mark]
         self._dq_lock = _dbg.make_lock("OrderedDelivery._dq_lock")
         self._dq_draining = False
@@ -407,6 +422,10 @@ class IciSocket(CreditWindow, OrderedDelivery, Socket):
         self._inflight_sends: Dict[int, Tuple] = {}
         self._inflight_seq = 0
         self._inflight_lock = _dbg.make_lock("IciSocket._inflight_lock")
+        # the pieces of a resident ref that one dispatch cut ahead of the
+        # window (``_resident_cut``): touched by the socket's one writer,
+        # and dropped by ``_transport_close``
+        self._cut_run: Optional[_CutRun] = None
 
     @property
     def queue_last_message(self) -> bool:
@@ -441,7 +460,7 @@ class IciSocket(CreditWindow, OrderedDelivery, Socket):
             if _span.layer_on() else None
         try:
             frame = data.cut(n)
-            chunks = self._relocate(frame)
+            chunks = self._relocate(frame, data)
             if piece is not None:
                 _span.layer_record("brpc.ici.relocate", piece.start_ns,
                                    _time.perf_counter_ns())
@@ -454,11 +473,13 @@ class IciSocket(CreditWindow, OrderedDelivery, Socket):
             _ici_bytes_moved += n
         return n
 
-    def _relocate(self, frame: IOBuf) -> List:
+    def _relocate(self, frame: IOBuf, rest: IOBuf) -> List:
         """Move DEVICE refs to the peer's chip (HBM→HBM over ICI); host
         refs pass through as bytes.  Three routes, chosen on the ref's
         BLOCK before anything is cut: resident on the target, the ref is
-        passed (sliced where it is not the whole block) and nothing moved
+        passed (sliced where it is not the whole block, by a cut run where
+        the frame's last ref goes on in ``rest``, what is still queued:
+        ``_resident_cut``) and nothing moved
         — the chunk ``(array, length, moved)`` says so, and ``_deliver``
         waits only for what did; not resident and
         at/above ``ici_device_plane_threshold``, a send WR is posted on the
@@ -477,7 +498,8 @@ class IciSocket(CreditWindow, OrderedDelivery, Socket):
         chunks: List = []
         pending_host: List[bytes] = []
         global _ici_device_bytes_moved
-        for i in range(frame.backing_block_num()):
+        last = frame.backing_block_num() - 1
+        for i in range(last + 1):
             r = frame.backing_block(i)
             if r.block.kind == DEVICE:
                 if pending_host:
@@ -495,7 +517,9 @@ class IciSocket(CreditWindow, OrderedDelivery, Socket):
                 # already in the target chip's HBM: pure ref pass — the
                 # zero-copy case the block_pool discipline exists for
                 if resident:
-                    chunks.append((_cut(arr, r), r.length, False))
+                    chunks.append((self._resident_cut(
+                        arr, r, rest if i == last else None),
+                        r.length, False))
                     with _ici_stats_lock:
                         _ici_device_bytes_moved += r.length
                     continue
@@ -538,6 +562,68 @@ class IciSocket(CreditWindow, OrderedDelivery, Socket):
         if pending_host:
             chunks.append(b"".join(pending_host))
         return chunks
+
+    def _resident_cut(self, arr, r, rest: Optional[IOBuf]):
+        """The array a resident ref ``r`` of ``arr`` is passed as.  A piece
+        the pending cut run holds is taken from it; any other piece drops
+        the run (a miss).  Where the ref goes on in ``rest`` right where
+        ``r`` ends, ONE dispatch of the run slicer cuts ``r`` and the next
+        pieces of it on the window's piece grid — each ``piece_bytes``,
+        the last clipped to the ref's end, at most a window of them — and
+        keeps the rest for the frames that follow; else ``_cut`` as ever
+        (a window of one piece, a ref whose rest fits in this piece).
+        The socket's writer is single (the KeepWrite rule), so only
+        ``_transport_close`` races it (``_CutRun``)."""
+        run = self._cut_run
+        if run is not None:
+            got = run.take(r)
+            if got is not None:
+                if not run.pieces:
+                    self._cut_run = None
+                return got
+            self._drop_run()
+        plan = self._run_plan(arr, r, rest)
+        if plan is None:
+            return _cut(arr, r)
+        device, lengths = plan
+        run = _CutRun(r.block, r.offset, lengths,
+                      _cut_pieces(arr, device, r.offset, lengths))
+        got = run.take(r)
+        self._cut_run = run
+        if self.failed:
+            self._drop_run()      # closed while it was cut
+        return got
+
+    def _run_plan(self, arr, r, rest: Optional[IOBuf]):
+        """``(device, lengths)`` of a cut run that starts with ``r``, or
+        None where there is no run of two: the window holds one piece,
+        ``rest`` does not begin with the same block right where ``r``
+        ends, or the run slicer does not take the array."""
+        ahead = self.window_bytes // self.piece_bytes
+        if ahead < 2 or rest is None or not rest.backing_block_num():
+            return None
+        nxt = rest.backing_block(0)
+        at = r.offset + r.length
+        if nxt.block is not r.block or nxt.offset != at or r.offset < 0:
+            return None
+        size, end = len(arr), nxt.offset + nxt.length
+        if end > size or getattr(arr, "sharding", None) is None:
+            return None
+        device = _slicer_device(arr, size)
+        if device is None:
+            return None
+        lengths = [r.length]
+        while at < end and len(lengths) < ahead:
+            lengths.append(min(self.piece_bytes, end - at))
+            at += lengths[-1]
+        return device, tuple(lengths)
+
+    def _drop_run(self) -> None:
+        run, self._cut_run = self._cut_run, None
+        if run is not None:
+            dropped = run.drop()
+            if dropped:
+                _g_run_pieces_dropped << dropped
 
     def _deliver(self, peer: "IciSocket", chunks: List) -> None:
         waits: List = []
@@ -659,6 +745,7 @@ class IciSocket(CreditWindow, OrderedDelivery, Socket):
             peer._wake_window()
         # release our own writers blocked on the (now dead) window
         self._wake_window()
+        self._drop_run()
 
 
 def _header_run(data: IOBuf, bound: int) -> Optional[int]:
@@ -698,6 +785,32 @@ def piece_slicer():
     return fn
 
 
+def run_slicer():
+    """The jitted ``brpc_ici_cut_run``, ``piece_slicer``'s companion for a
+    cut run: ``dynamic_slice(block, (start + sum(lengths[:i]),),
+    (lengths[i],))`` for each of the static ``lengths``, out as separate
+    arrays; ``start`` an operand, so one executable serves every offset of
+    a (block bytes, lengths) shape."""
+    global _run_program
+    with _cut_lock:
+        fn = _run_program
+    if fn is None:
+        import functools
+        import jax
+
+        @functools.partial(jax.jit, static_argnums=2)
+        def brpc_ici_cut_run(block, start, lengths):
+            out, at = [], 0
+            for n in lengths:
+                out.append(jax.lax.dynamic_slice(block, (start + at,), (n,)))
+                at += n
+            return tuple(out)
+
+        with _cut_lock:
+            fn = _run_program = _run_program or brpc_ici_cut_run
+    return fn
+
+
 def _start_operand(device, offset: int):
     """A cut's start as a device-resident int32 scalar, kept per (device,
     offset): a frame layout cuts at the same offsets again, and a host
@@ -732,11 +845,10 @@ def _cut(arr, r):
     if r.offset < 0 or r.length < 0 or r.offset + r.length > size:
         raise ValueError(f"ref [{r.offset}, {r.offset + r.length}) is not "
                          f"inside its block of {size} bytes")
-    sharding = getattr(arr, "sharding", None)
-    if sharding is None:
+    if getattr(arr, "sharding", None) is None:
         return arr[r.offset:r.offset + r.length]
-    devices = sharding.device_set
-    compiled = len(devices) == 1 and size < 1 << 31
+    device = _slicer_device(arr, size)
+    compiled = device is not None
     (_g_compiled_cuts if compiled else _g_eager_cuts) << 1
     # layer span brpc.ici.cut: the dispatch alone, inside its piece's
     # brpc.ici.relocate (n: the cut's bytes, m: 1 for the eager fall-back)
@@ -745,13 +857,80 @@ def _cut(arr, r):
         if _span.layer_on() else None
     try:
         if compiled:
-            (device,) = devices
             return piece_slicer()(arr, _start_operand(device, r.offset),
                                   r.length)
         return arr[r.offset:r.offset + r.length]
     finally:
         if cut is not None:
             cut.end()
+
+
+def _slicer_device(arr, size: int):
+    """The one device of a device array the compiled slicers take — on a
+    single device, with offsets an int32 start can hold — else None."""
+    devices = arr.sharding.device_set
+    if len(devices) != 1 or size >= 1 << 31:
+        return None
+    (device,) = devices
+    return device
+
+
+def _cut_pieces(arr, device, offset: int, lengths: Tuple[int, ...]) -> Tuple:
+    """ONE dispatch of the run slicer (``run_slicer``): the pieces of
+    ``lengths`` from ``offset`` on, as separate arrays.  The caller checked
+    that they lie inside the block."""
+    _g_cut_runs << 1
+    _g_compiled_cuts << len(lengths)
+    # layer span brpc.ici.cut: one a dispatch, n the run's bytes
+    cut = _span.layer_begin("brpc.ici.cut", n=sum(lengths), cpu=True) \
+        if _span.layer_on() else None
+    try:
+        return run_slicer()(arr, _start_operand(device, offset), lengths)
+    finally:
+        if cut is not None:
+            cut.end()
+
+
+class _CutRun:
+    """The pieces of one resident block that a cut run holds for the frames
+    to come: a strong ref to the block (matched by ``is``) and
+    ``(offset, length, array)`` in the order the window sends them.  A
+    piece leaves by ``take`` or ``drop``, each a ``popleft``, so a writer
+    that takes while ``_transport_close`` drops the run counts each piece
+    once."""
+
+    __slots__ = ("block", "pieces")
+
+    def __init__(self, block, offset: int, lengths: Tuple[int, ...],
+                 arrays: Tuple):
+        self.block = block
+        self.pieces = collections.deque()
+        for n, a in zip(lengths, arrays):
+            self.pieces.append((offset, n, a))
+            offset += n
+
+    def take(self, r):
+        """The next piece's array where it is ``r``; None on a miss."""
+        try:
+            offset, length, arr = self.pieces[0]
+            if (r.block is not self.block or r.offset != offset
+                    or r.length != length):
+                return None
+            self.pieces.popleft()
+        except IndexError:        # dropped under us
+            return None
+        _g_run_pieces << 1
+        return arr
+
+    def drop(self) -> int:
+        """Free the pieces left; how many there were."""
+        n = 0
+        while True:
+            try:
+                self.pieces.popleft()
+            except IndexError:
+                return n
+            n += 1
 
 
 class _PlaneDesc:

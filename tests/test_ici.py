@@ -1000,13 +1000,28 @@ class TestRelocateCutsOnTheChip:
         out = type("Wrote", (), {})()
         out.cuts, out.released, out.pieces, out.posts, out.puts = \
             [], [], [], [], []
+        out.runs = []
         out.peak_unacked = out.refused = 0
         real_cut, real_pin = tr._cut, a._pin_until_sent
         real_write, real_post = a._do_write, tr._dp.plane().post_send
+        real_pieces, real_take = tr._cut_pieces, tr._CutRun.take
 
+        # out.cuts: every piece cut out of a block, in the order it went —
+        # by a lone cut, or handed out of a cut run (out.runs: the run
+        # slicer's dispatches, by their pieces' lengths)
         def counting_cut(arr, r):
             got = real_cut(arr, r)
             if got is not arr:
+                out.cuts.append((r.offset, r.length))
+            return got
+
+        def counting_pieces(arr, device, offset, lengths):
+            out.runs.append(lengths)
+            return real_pieces(arr, device, offset, lengths)
+
+        def counting_take(run, r):
+            got = real_take(run, r)
+            if got is not None:
                 out.cuts.append((r.offset, r.length))
             return got
 
@@ -1028,6 +1043,8 @@ class TestRelocateCutsOnTheChip:
             out.peak_unacked = max(out.peak_unacked, a.unacked_send_bytes())
             return n
         monkeypatch.setattr(tr, "_cut", counting_cut)
+        monkeypatch.setattr(tr, "_cut_pieces", counting_pieces)
+        monkeypatch.setattr(tr._CutRun, "take", counting_take)
         monkeypatch.setattr(a, "_pin_until_sent", counting_pin)
         monkeypatch.setattr(tr._dp.plane(), "post_send", counting_post)
         monkeypatch.setattr(a, "_do_write", counting_write)
@@ -1086,6 +1103,7 @@ class TestRelocateCutsOnTheChip:
                          - stats["small_relocations"])
             out.pipelined = (after["pipelined_pieces"]
                              - stats["pipelined_pieces"])
+            out.grew = {k: after[k] - stats[k] for k in after}
             return out
         finally:
             a.set_failed()
@@ -1254,9 +1272,219 @@ class TestRelocateCutsOnTheChip:
         assert out.got == out.want
         assert out.pieces == [hdr + piece] + [piece] * 15
         assert out.cuts == [(k * piece, piece) for k in range(16)]
+        # ... handed out of four dispatches of the run slicer, a window each
+        assert out.runs == [(piece,) * (window // piece)] * 4
         # more than one piece really was in flight, under the bound
         assert piece < out.peak_unacked <= window + hdr
         assert out.pipelined >= 2 and out.small == 0
+
+    # ---- cut runs: one dispatch cuts a window's worth of pieces ----------
+
+    def _run_counts(self, out):
+        return {k: out.grew[k] for k in ("cut_runs", "run_pieces",
+                                         "run_pieces_dropped",
+                                         "compiled_cuts")}
+
+    @pytest.mark.parametrize("hdr", HEADERS)
+    def test_a_resident_ref_goes_in_runs_of_a_window(
+            self, mesh, monkeypatch, host_mesh_plane, hdr):
+        """64 MiB resident at the default geometry: four dispatches of
+        four pieces each, on the piece grid, the header in the first
+        piece, every piece handed out and none dropped."""
+        window, piece = self._default_geometry()
+        out = self._write_and_drain(
+            mesh, monkeypatch, 4, 4, header=b"h" * hdr, window=window,
+            nbytes=16 * piece, reads=[1 << 26] * 64)
+        assert out.got == out.want
+        assert out.pieces == [hdr + piece] + [piece] * 15
+        assert out.cuts == [(k * piece, piece) for k in range(16)]
+        assert out.runs == [(piece,) * 4] * 4
+        assert self._run_counts(out) == {
+            "cut_runs": 4, "run_pieces": 16, "run_pieces_dropped": 0,
+            "compiled_cuts": 16}
+        assert out.puts == [] and out.posts == []
+        assert all(len(x) == piece for x in out.delivered)
+
+    def test_a_run_is_clipped_to_the_refs_end(self, mesh, monkeypatch,
+                                              host_mesh_plane):
+        """A block of ten pieces and a short tail (the size of an
+        expert-parallel reply): runs of four, four and three, the last
+        piece the tail."""
+        window, piece = self._default_geometry()
+        nbytes = 42_434_560
+        tail = nbytes - 10 * piece
+        assert 0 < tail < piece
+        out = self._write_and_drain(
+            mesh, monkeypatch, 4, 4, header=b"h" * self.HDR, window=window,
+            nbytes=nbytes, reads=[1 << 26] * 64)
+        assert out.got == out.want
+        assert out.pieces == [self.HDR + piece] + [piece] * 9 + [tail]
+        assert out.runs == [(piece,) * 4, (piece,) * 4,
+                            (piece, piece, tail)]
+        assert out.cuts == [(k * piece, piece) for k in range(10)] \
+            + [(10 * piece, tail)]
+        assert self._run_counts(out) == {
+            "cut_runs": 3, "run_pieces": 11, "run_pieces_dropped": 0,
+            "compiled_cuts": 11}
+
+    def test_a_ref_followed_by_a_second_block_in_the_frame(
+            self, mesh, monkeypatch, host_mesh_plane):
+        """Two blocks of a piece and a half: the first block's run ends at
+        its end, and the piece that holds its tail and the second block's
+        head starts a run of the second block on the grid that the window
+        cuts it on."""
+        window, piece = self._default_geometry()
+        half = piece // 2
+        block = piece + half
+        out = self._write_and_drain(
+            mesh, monkeypatch, 4, 4, header=b"h" * self.HDR, window=window,
+            nbytes=2 * block, blocks=2, reads=[1 << 26] * 64)
+        assert out.got == out.want
+        assert out.pieces == [self.HDR + piece, piece, piece]
+        assert out.runs == [(piece, half), (half, piece)]
+        # the first block's two pieces, then the second block's
+        assert out.cuts == [(0, piece), (piece, half), (0, half),
+                            (half, piece)]
+        assert self._run_counts(out) == {
+            "cut_runs": 2, "run_pieces": 4, "run_pieces_dropped": 0,
+            "compiled_cuts": 4}
+
+    def test_a_window_of_one_piece_makes_no_run(self, mesh, monkeypatch,
+                                                host_mesh_plane):
+        window, piece = self._default_geometry()
+        out = self._write_and_drain(
+            mesh, monkeypatch, 4, 4, header=b"h" * self.HDR, window=piece,
+            nbytes=16 * piece, reads=[1 << 26] * 64)
+        assert out.got == out.want
+        assert out.runs == [] and len(out.cuts) == 16
+        assert self._run_counts(out) == {
+            "cut_runs": 0, "run_pieces": 0, "run_pieces_dropped": 0,
+            "compiled_cuts": 16}
+
+    P = 4096
+
+    def _one_socket(self, mesh, monkeypatch, dev=4):
+        """A connected pair on one device whose window is four pieces of
+        ``P`` bytes."""
+        from brpc_tpu.ici import transport as tr
+        monkeypatch.setattr(tr, "PIECE_BYTES", self.P)
+        a = tr.IciSocket(dev, dev, mesh, window_bytes=4 * self.P)
+        b = tr.IciSocket(dev, dev, mesh, window_bytes=4 * self.P)
+        a.peer, b.peer = b, a
+        return a, b
+
+    def test_a_miss_drops_the_run_and_cuts_as_before(self, mesh,
+                                                     monkeypatch):
+        """A resident piece that is not the run's next one drops what the
+        run holds (counted) and is cut alone, byte for byte."""
+        from brpc_tpu.butil.iobuf import IOBuf
+        from brpc_tpu.ici import transport as tr
+        a, b = self._one_socket(mesh, monkeypatch)
+        try:
+            arr = self._payload(mesh, 4, 16 * self.P)
+            host = np.asarray(arr)
+            data = IOBuf()
+            data.append_device_array(arr)
+            frame = data.cut(self.P)
+            r = frame.backing_block(0)
+            before = tr.ici_piece_stats()
+            got = a._resident_cut(arr, r, data)
+            assert bytes(np.asarray(got)) == bytes(host[:self.P])
+            assert len(a._cut_run.pieces) == 3
+            # not the next piece: a piece further on
+            data.pop_front(self.P)
+            miss = data.cut(self.P).backing_block(0)
+            got = a._resident_cut(arr, miss, None)
+            assert bytes(np.asarray(got)) \
+                == bytes(host[2 * self.P:3 * self.P])
+            assert a._cut_run is None
+            after = tr.ici_piece_stats()
+            grew = {k: after[k] - before[k] for k in
+                    ("cut_runs", "run_pieces", "run_pieces_dropped",
+                     "compiled_cuts")}
+            assert grew == {"cut_runs": 1, "run_pieces": 1,
+                            "run_pieces_dropped": 3, "compiled_cuts": 5}
+        finally:
+            a.set_failed()
+            b.set_failed()
+
+    def test_a_socket_closed_with_a_run_pending_drops_it(self, mesh,
+                                                         monkeypatch):
+        """The writer parks with the fourth piece of a run held (the
+        first three fill the window and nobody reads); closing the socket
+        frees it, counted."""
+        from brpc_tpu.butil.iobuf import IOBuf
+        from brpc_tpu.ici import transport as tr
+        a, b = self._one_socket(mesh, monkeypatch)
+        try:
+            buf = IOBuf(b"h" * self.HDR)
+            buf.append_device_array(self._payload(mesh, 4, 16 * self.P))
+            before = tr.ici_piece_stats()
+            assert a.write(buf) == 0
+            def parked():
+                run = a._cut_run
+                return a.send_window_left() < self.P and run is not None \
+                    and len(run.pieces) == 1
+            deadline = time.monotonic() + 10
+            while not parked() and time.monotonic() < deadline:
+                time.sleep(0.002)
+            assert parked()
+            run = a._cut_run
+            a.set_failed()
+            assert a._cut_run is None and not run.pieces
+            after = tr.ici_piece_stats()
+            assert after["run_pieces_dropped"] \
+                - before["run_pieces_dropped"] == 1
+            assert after["run_pieces"] - before["run_pieces"] == 3
+        finally:
+            a.set_failed()
+            b.set_failed()
+
+    @pytest.mark.parametrize("pairs", [2, 8])
+    def test_a_run_taken_while_it_is_dropped_counts_each_piece_once(
+            self, pairs):
+        """A writer taking pieces while ``_transport_close`` drops the run,
+        more threads than cores and a short switch interval: each piece is
+        handed out or dropped, never both, and a piece handed out is one
+        the run held, taken in order."""
+        from brpc_tpu.ici import transport as tr
+        block, n, rounds = object(), 64, 50
+        wrong = []
+
+        def ref(k):
+            return type("Ref", (), {"block": block, "offset": 10 * k,
+                                    "length": 10})()
+
+        def one_pair(pair):
+            for round_ in range(rounds):
+                run = tr._CutRun(block, 0, (10,) * n, tuple(range(n)))
+                dropped = []
+                t = threading.Thread(
+                    target=lambda: dropped.append(run.drop()))
+                t.start()
+                taken = []
+                for k in range(n):
+                    got = run.take(ref(k))
+                    if got is None:
+                        break
+                    taken.append(got)
+                t.join(10)
+                if (t.is_alive() or len(taken) + dropped[0] != n
+                        or taken != list(range(len(taken)))):
+                    wrong.append((pair, round_, taken, dropped))
+
+        ts = [threading.Thread(target=one_pair, args=(i,))
+              for i in range(pairs)]
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            for t in ts:
+                t.start()
+            for t in ts:
+                t.join(60)
+        finally:
+            sys.setswitchinterval(interval)
+        assert not wrong and not any(t.is_alive() for t in ts)
 
     def test_a_writer_short_of_a_piece_parks_and_does_not_spin(
             self, mesh, monkeypatch, host_mesh_plane):
@@ -1410,7 +1638,11 @@ class TestRelocateCutsOnTheChip:
                           ("ici_transport_small_relocations",
                            "small_relocations"),
                           ("ici_transport_compiled_cuts", "compiled_cuts"),
-                          ("ici_transport_eager_cuts", "eager_cuts")):
+                          ("ici_transport_eager_cuts", "eager_cuts"),
+                          ("ici_transport_cut_runs", "cut_runs"),
+                          ("ici_transport_run_pieces", "run_pieces"),
+                          ("ici_transport_run_pieces_dropped",
+                           "run_pieces_dropped")):
             assert bvar.find_exposed(name).get_value() == stats[key]
         assert len(tr.ici_transport_stats()) == 2
 
@@ -1584,15 +1816,19 @@ class TestCompiledCut:
         with tr._cut_lock:
             assert len(tr._cut_starts) == 16
 
-    def _sixteen_piece_echo(self, mesh, monkeypatch, dev):
+    def _sixteen_piece_echo(self, mesh, monkeypatch, dev, window_pieces=4):
         """One echo of a device attachment sixteen pieces long over an
         ``ici://`` connection on one chip (5 MiB: above the native tier's
-        window, so the Python ici plane carries it); the reply is held to
-        the request byte for byte and to sixteen whole blocks on the chip.
-        Returns the two cut counters' growth."""
+        window, so the Python ici plane carries it), through sockets whose
+        window is ``window_pieces`` pieces (four: the default geometry);
+        the reply is held to the request byte for byte and to sixteen
+        whole blocks on the chip.  Returns the two cut counters' growth."""
+        from brpc_tpu.butil import flags as _flags
         from brpc_tpu.ici import transport as tr
         piece = 320 * 1024
         monkeypatch.setattr(tr, "PIECE_BYTES", piece)
+        monkeypatch.setattr(_flags.flag_object("ici_socket_window_bytes"),
+                            "value", window_pieces * piece)
         host, arr = self._block(mesh, dev=dev, nbytes=16 * piece, salt=17)
         options = rpc.ServerOptions()
         options.usercode_inline = True
@@ -1640,29 +1876,43 @@ class TestCompiledCut:
             jax.profiler.stop_trace()
             span.layer_spans_reset()
 
-    def test_in_a_session_each_compiled_cut_has_a_span_inside_its_piece(
-            self, mesh, monkeypatch, session):
-        """Sixteen ``brpc.ici.cut`` for the request's sixteen compiled cuts
-        and none for the reply's whole blocks; each lies inside the
-        ``brpc.ici.relocate`` of the ``brpc.ici.piece`` that caused it, on
-        that piece's thread, and carries its thread's CPU time."""
-        piece = 320 * 1024
-        assert self._sixteen_piece_echo(mesh, monkeypatch, 6) == (16, 0)
+    def _cut_spans_inside_their_pieces(self, session, dispatches, nbytes):
+        """Each ``brpc.ici.cut`` lies inside the ``brpc.ici.relocate`` of
+        the ``brpc.ici.piece`` that caused it, on that piece's thread, and
+        carries its thread's CPU time."""
         spans = session.layer_spans()
         cuts = [s for s in spans if s.name == "brpc.ici.cut"]
         pieces = {s.span_id: s for s in spans if s.name == "brpc.ici.piece"}
         relocates = {s.cause_id: s for s in spans
                      if s.name == "brpc.ici.relocate"}
-        assert len(cuts) == 16 and len(pieces) >= 32
-        assert len({c.cause_id for c in cuts}) == 16    # one a piece
+        assert len(cuts) == dispatches and len(pieces) >= 32
+        assert len({c.cause_id for c in cuts}) == dispatches  # one a piece
         for c in cuts:
             p, r = pieces[c.cause_id], relocates[c.cause_id]
-            assert (c.n, c.m, c.thread) == (piece, 0, p.thread)
+            assert (c.n, c.m, c.thread) == (nbytes, 0, p.thread)
             assert c.call_id == p.call_id
             assert p.start_ns == r.start_ns <= c.start_ns
             assert c.end_ns <= r.end_ns <= p.end_ns
             assert 0 <= c.cpu_ns <= c.end_ns - c.start_ns
             assert 0 <= p.cpu_ns <= p.end_ns - p.start_ns
+
+    def test_in_a_session_each_compiled_cut_has_a_span_inside_its_piece(
+            self, mesh, monkeypatch, session):
+        """The request's sixteen compiled cuts are four dispatches of the
+        run slicer, a window of four pieces each, so four ``brpc.ici.cut``
+        (n: the run's bytes), and none for the reply's whole blocks."""
+        piece = 320 * 1024
+        assert self._sixteen_piece_echo(mesh, monkeypatch, 6) == (16, 0)
+        self._cut_spans_inside_their_pieces(session, 4, 4 * piece)
+
+    def test_in_a_session_a_window_of_one_piece_has_a_cut_span_a_piece(
+            self, mesh, monkeypatch, session):
+        """A window of one piece makes no run: sixteen lone cuts, sixteen
+        ``brpc.ici.cut`` of a piece each."""
+        piece = 320 * 1024
+        assert self._sixteen_piece_echo(mesh, monkeypatch, 6,
+                                        window_pieces=1) == (16, 0)
+        self._cut_spans_inside_their_pieces(session, 16, piece)
 
     def test_in_a_session_the_eager_fall_back_says_m_1_and_a_whole_ref_nothing(
             self, mesh, session):

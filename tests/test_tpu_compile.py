@@ -320,6 +320,29 @@ def test_fanout_cell_programs_at_the_cells_size(one_chip):
     assert stacked.memory_analysis().output_size_in_bytes == 64 * MB
 
 
+@pytest.mark.parametrize("size, lengths", [
+    (64 * MB, (4 * MB,) * 4),                   # local_bulk_64m's request
+    (42_434_560, (4 * MB, 4 * MB, 491_520)),    # an ep8 reply's last run
+], ids=["bulk_64m", "ep8_reply_tail"])
+def test_the_cut_run_at_the_cells_sizes(one_chip, size, lengths):
+    """The transport's run slicer (``run_slicer``: one dispatch cuts a
+    window's worth of pieces out of a resident block), at a run of four
+    whole pieces and at a run clipped to its block's end."""
+    import jax
+    import jax.numpy as jnp
+    from brpc_tpu.ici.transport import run_slicer
+    block = jax.ShapeDtypeStruct((size,), jnp.uint8, sharding=one_chip)
+    start = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    run = run_slicer().lower(block, start, lengths).compile()
+    assert [o.shape for o in run.out_info] == [(n,) for n in lengths]
+    # one fusion reads the pieces out of the block where it lies: no
+    # prefetch of the whole operand, as a lone cut makes (copy-done)
+    assert "cross_program_prefetch" not in run.as_text()
+    # the pieces, and the few bytes of the tuple that holds them
+    out = run.memory_analysis().output_size_in_bytes
+    assert sum(lengths) <= out <= sum(lengths) + 4 * KB
+
+
 # the signature of the relayout a byte->word view costs on the TPU: a u8
 # array whose minor dimension is the word's byte count, which the chip pads
 # to 128 lanes (PERF.md §5 pushpull_4x16m: 12.8 of a 20 ms reduce)
