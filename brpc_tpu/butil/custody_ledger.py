@@ -63,7 +63,7 @@ def _caller_site(depth: int = 4) -> str:
     # the instrumented method to its CALL SITE, the frame a human needs
     # to find the leak.  sys._getframe is a C call — unlike
     # traceback.extract_stack it creates no interpreter frames, so the
-    # enabled ledger stays inside the fused dispatch frame budget
+    # enabled ledger stays inside the native tier's frame budget
     # (tests/test_native_ici.py test_frame_budget runs with the ledger
     # ON via conftest)
     try:

@@ -150,7 +150,7 @@ def _resolve_shm_stripes() -> int:
 # (CollectiveSequencer), and each side's single executor enters it at
 # that slot.  On backends with multi-controller collectives (TPU pods)
 # the transfer is a compiled XLA program both processes enter (shard_map
-# + ppermute / Pallas remote DMA over the 2-device submesh — the SPMD
+# + ppermute over the 2-device submesh — the SPMD
 # contract); elsewhere the bytes ride the native bulk plane under the
 # SAME sequencer (ici_device_plane_xproc_compiled=auto — this repo's CPU
 # jaxlib raises "Multiprocess computations aren't implemented on the CPU

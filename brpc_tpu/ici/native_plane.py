@@ -44,8 +44,8 @@ from ..rpc import request_context as _reqctx
 
 _U8P = ctypes.POINTER(ctypes.c_uint8)
 
-# the fused paths read the request-context slot without the
-# current()/scope() call frames — same thread-local the module owns
+# the sync client entry (call_fused) reads the request-context slot
+# without the current() call frame — same thread-local the module owns
 _reqctx_tls = _reqctx._tls
 
 # call_fused returns this when the call must re-route to the Python
@@ -54,15 +54,15 @@ _reqctx_tls = _reqctx._tls
 FUSED_FALLTHROUGH = object()
 
 # the raw C string_at (stable since 2.5): the public wrapper is a
-# Python frame per read, and the fused paths read 2-3 borrowed buffers
-# per RPC
+# Python frame per read, and call_fused reads 2-3 borrowed buffers per
+# RPC
 _string_at = ctypes._string_at
 
 _fused_ffi = None
 
 
 def _fused_call_binding():
-    """Fused-path FFI binding for call4 whose payload/att-host argtypes
+    """call_fused's FFI binding for call4, whose payload/att-host argtypes
     are ``c_char_p`` — bytes objects pass straight through (ABI-identical
     pointer) instead of paying two ``ctypes.cast`` frames per call.
     Bound on a SEPARATE CDLL handle so the legacy ``call`` keeps its
@@ -102,19 +102,13 @@ _flags.define_flag("ici_upcall_batch_age_us", 50,
 # (build_attachment_from_c): its host spans interleave with the device
 # segs positionally.
 
-# Fused dispatch (ISSUE 13): the per-RPC interpreter-frame chain on the
-# native-ici hot path collapses into single flat code objects —
-# _process/_execute/done fuse into ServerBinding._process_fused +
-# _FusedDone on the server, Channel.call_method's native preamble +
-# screens + ChannelBinding.call fuse into ChannelBinding.call_fused on
-# the client, with per-method dispatch resolved ONCE per (listener,
-# method) instead of per call.  Off = the PR-12 frame chain
-# byte-for-byte (the A/B leg).  Snapshot at bind/connect time.
-_flags.define_flag("ici_fused_dispatch", True,
-                   "collapse the native-ici per-RPC dispatch chain "
-                   "into fused code objects (server process/execute/"
-                   "done and the client call path); off restores the "
-                   "unfused PR-12 frame chain for A/B")
+# One dispatch chain a side.  Server: every request runs
+# ServerBinding._process (gates, admission) → _execute (parse, invoke,
+# done), inline on the delivering thread, on a tasklet or on the
+# usercode pool.  Client: every sync call enters
+# ChannelBinding.call_fused — Channel.call_method's native preamble,
+# screens and the issue as one flat code object; async and
+# fault-injected calls run ChannelBinding.call.
 
 # hot-path module handles, resolved once at first call: the per-call
 # `from x import y` dance measured ~1 us/call on the fast plane (the
@@ -797,27 +791,10 @@ class ServerBinding:
         lib.brpc_tpu_ici_set_batch_params(
             h, int(_flags.get_flag("ici_upcall_max_batch")),
             int(_flags.get_flag("ici_upcall_batch_age_us")))
-        # fused dispatch (ISSUE 13), snapshot at bind: the inline hot
-        # path runs through _process_fused — one flat code object per
-        # request — with the per-method dispatch tuple resolved once per
-        # raw method key and every hot module handle bound HERE instead
-        # of re-resolved per call
-        self._fused = bool(_flags.get_flag("ici_fused_dispatch"))
-        # the batch-of-1 fast lane's gate, snapshot at bind (options
-        # are final once start() ran; a flag flipped later takes effect
-        # with the next listener, never mid-listener)
-        self._fused_inline1 = self._fused and bool(
-            getattr(server.options, "usercode_inline", False))
-        self._fcache: Dict[bytes, tuple] = {}   # mkey -> dispatch tuple
         self._stages_on, self._record_stage, self._enter_handler = \
             _stage_modules()
-        self._pool = _controller_pool()
-        # dispatch-route truth (OBSERVABILITY.md): how many requests ran
-        # the fused body vs the legacy chain on this listener — plain
-        # ints bumped on the hot path (an Adder op per RPC is real µs),
-        # published by describe()
+        # always 0: the benchmark's counter table reads it
         self.fused_dispatched = 0
-        self.legacy_dispatched = 0
         with _server_bindings_lock:
             _server_bindings[device_id] = self
 
@@ -857,21 +834,10 @@ class ServerBinding:
         dispatch modes fan the requests out (tasklets / usercode pool —
         the queued counter counts BATCH CONTENTS, one per request, so
         the lame-duck drain gate sees each of them)."""
-        # the idle/low-load fast lane: ONE fused inline request, no
-        # collector, no loop setup — the dominant shape of a closed-loop
-        # echo (the snapshot below is taken at bind; options are final
-        # once the server started)
-        if n == 1 and self._fused_inline1:
-            try:
-                self._process_fused(reqs[0], None)
-            except Exception as e:
-                self._batch_request_failed(reqs[0], e)
-            return
         try:
             server = self._server
             inline = getattr(server.options, "usercode_inline", False)
             pool = getattr(server, "usercode_pool", None)
-            fused = self._fused and inline
             # a batch of ONE (the idle/low-load shape) responds directly —
             # the collector only earns its lock when there is something
             # to amortize
@@ -889,17 +855,6 @@ class ServerBinding:
                     r = reqs[i]
                     token = r.token
                     try:
-                        if fused:
-                            # inline hot path: the whole request — method
-                            # resolve, gates, controller setup, parse,
-                            # invoke, completion — runs in ONE flat code
-                            # object (custody exits inside match the
-                            # legacy chain exactly; the except arm below
-                            # still covers a failure here, and its
-                            # dispose of an already-exited handle is a
-                            # table-miss no-op)
-                            self._process_fused(r, collector)
-                            continue
                         mkey = r.method
                         full = names.get(mkey)
                         if full is None:
@@ -1110,7 +1065,6 @@ class ServerBinding:
         not pin a backup thread for the pool's default bound)."""
         server = self._server
         _src, att_mode = reg
-        start_ns = _time.monotonic_ns()
         pool = server.usercode_pool
         try:
             if pool is None:
@@ -1127,17 +1081,16 @@ class ServerBinding:
             item = (token, errors.ERPCTIMEDOUT,
                     f"isolated handler exceeded deadline "
                     f"({deadline_left_ms}ms)".encode(), b"", b"",
-                    (), (None, errors.ERPCTIMEDOUT, 0, server), 0, 0)
+                    (), server.on_request_out, 0, 0)
             self._respond_item(item)
             return
         except Exception as e:
             self._release_attachment_custody(attachment)
             item = (token, errors.EINTERNAL,
                     f"{type(e).__name__}: {e}".encode(), b"", b"",
-                    (), (None, errors.EINTERNAL, 0, server), 0, 0)
+                    (), server.on_request_out, 0, 0)
             self._respond_item(item)
             return
-        latency_us = (_time.monotonic_ns() - start_ns) // 1000
         pass_h = 0
         att_host = b""
         segs = ()
@@ -1152,231 +1105,11 @@ class ServerBinding:
                 # legacy-walk attachment: the echo pays the split
                 att_host, segs = split_attachment(attachment)
         item = (token, 0, b"", resp, att_host, segs,
-                (None, 0, latency_us, server), 0, pass_h)
+                server.on_request_out, 0, pass_h)
         self._respond_item(item)
-
-    # ---- fused dispatch (ISSUE 13) -----------------------------------
-
-    def _batch_request_failed(self, r, e) -> None:
-        """Per-request failure isolation for the fused batch-of-1 fast
-        lane — mirrors the loop's except arm: answer THIS token
-        EINTERNAL and release THIS request's seg custody (a dispose of
-        an already-exited handle is a table-miss no-op)."""
-        log.error("ici batch request failed: %s", e, exc_info=True)
-        try:
-            if r.att_handle:
-                self._lib.brpc_tpu_ici_att_dispose(r.att_handle)
-            else:
-                for j in range(r.nsegs):
-                    sg = r.segs[j]
-                    if sg.is_dev:
-                        _registry.release(sg.key)
-        except Exception:
-            pass
-        try:
-            self._respond_one(r.token, errors.EINTERNAL,
-                              f"{type(e).__name__}: {e}")
-        except Exception:
-            pass
-
-    def _fused_entry(self, mkey: bytes):
-        """Resolve + memoize the per-method dispatch tuple for a raw
-        method key: (full, handler fn, request_cls, response_cls,
-        status).  Everything per-method — name decode, method lookup,
-        codec classes, the limiter handle — resolves ONCE per listener
-        instead of per call.  Services cannot be added after start, so
-        the cache never goes stale; a miss (unknown method) is NOT
-        cached so a typo probe can't grow the table."""
-        full = mkey.decode()
-        md = self._server.find_method(full)
-        if md is None:
-            return None
-        ent = (full, md.fn, md.request_cls, md.response_cls,
-               self._server.method_status(full))
-        self._fcache[mkey] = ent
-        return ent
-
-    def _process_fused(self, r, collector) -> None:
-        """The whole inline request path as ONE flat code object —
-        the fusion of _on_batch's extraction, _process's gates, and
-        _execute's setup/parse/invoke (completion lives in _FusedDone).
-        Semantics mirror the legacy chain exactly; admission-controlled
-        servers delegate to it (the shed/WFQ decision tree is not a
-        hot-path shape).  Custody: every exit point below matches the
-        legacy chain's exactly-one-exit discipline."""
-        server = self._server
-        token = r.token
-        mkey = r.method
-        ent = self._fcache.get(mkey)
-        if ent is None:
-            ent = self._fused_entry(mkey)
-        nsegs = r.nsegs
-        ahl = r.att_host_len
-        attachment = None
-        if nsegs or ahl:
-            ah = r.att_handle
-            if ah:
-                # native custody: the seg list stays PARKED under ah —
-                # the dominant 1-seg shape reads the inline seg0 mirror
-                if nsegs == 1:
-                    total = r.seg0_nbytes
-                    attachment = NativeAttachment(
-                        ah, total, ((r.seg0_key, total, r.seg0_dev),))
-                else:
-                    meta, total = _seg_meta_from_req(r, nsegs)
-                    attachment = NativeAttachment(ah, total, meta)
-            else:
-                att_host = _string_at(r.att_host, ahl) \
-                    if ahl else b""
-                try:
-                    attachment = build_attachment_from_c(
-                        att_host, r.segs, nsegs)
-                except KeyError as e:
-                    self._respond_one(token, errors.EINTERNAL, str(e),
-                                      collector)
-                    return
-        if server._draining:
-            # lame-duck bounce comes BEFORE method resolution, like the
-            # legacy chain
-            if attachment is not None and \
-                    type(attachment) is NativeAttachment:
-                attachment._dispose_native()
-            self._respond_one(token, errors.ELOGOFF,
-                              "server is draining (lame duck)", collector)
-            return
-        if ent is None:
-            if attachment is not None and \
-                    type(attachment) is NativeAttachment:
-                attachment._dispose_native()
-            self._respond_one(token, errors.ENOMETHOD,
-                              f"no method {mkey.decode()}", collector)
-            return
-        full, fn, request_cls, response_cls, status = ent
-        pri_wire = r.priority
-        tb = r.tenant
-        ddl = r.deadline_left_ms
-        # the wire tenant decodes BEFORE any gate or pool acquire: a
-        # malformed (non-UTF-8) tenant must fail in the pre-gate region
-        # — _on_batch's except arm answers EINTERNAL and releases
-        # custody, but cannot roll back a concurrency slot or a pooled
-        # Controller (the legacy chain decoded in _on_batch for the
-        # same reason)
-        if tb:
-            tenant = self._tenant_names.get(tb)
-            if tenant is None:
-                tenant = tb.decode()
-                if len(self._tenant_names) < 1024:
-                    self._tenant_names[tb] = tenant
-        else:
-            tenant = ""
-        payload = _string_at(r.payload, r.payload_len) \
-            if r.payload_len else b""
-        if server.admission is not None:
-            # admission rides the legacy chain (identical decision tree
-            # on all planes); the fused entry still saved the method
-            # resolve — _process re-reads its own mdcache
-            self._process(token, full, payload, attachment, r.log_id,
-                          r.peer_dev, r.recv_ns, collector,
-                          (pri_wire, tenant, ddl))
-            return
-        self.fused_dispatched += 1
-        stages = self._stages_on()
-        if stages:
-            recv_ns = r.recv_ns
-            if recv_ns:
-                self._record_stage("queue", recv_ns, _time.monotonic_ns(),
-                                   None, token)
-        if not server.on_request_in():
-            if attachment is not None and \
-                    type(attachment) is NativeAttachment:
-                attachment._dispose_native()
-            self._respond_one(token, errors.ELIMIT,
-                              "server max_concurrency reached", collector)
-            return
-        if status is not None and not status.on_requested():
-            server.on_request_out()
-            if attachment is not None and \
-                    type(attachment) is NativeAttachment:
-                attachment._dispose_native()
-            self._respond_one(token, errors.ELIMIT,
-                              f"{full} concurrency limit", collector)
-            return
-        cntl = self._pool.acquire()  # fablint: custody-moved(request-lifecycle) the shim rides the request; _maybe_recycle releases it back to the pool when the response (or failure path) completes
-        d = cntl.__dict__
-        log_id = r.log_id
-        if log_id:
-            d["log_id"] = log_id
-        d["server"] = server
-        peer_dev = r.peer_dev
-        ep = self._peer_eps.get(peer_dev)
-        d["remote_side"] = ep if ep is not None \
-            else self._peer_endpoint(peer_dev)
-        has_meta = False
-        if pri_wire:
-            d["priority"] = pri_wire - 1
-            has_meta = True
-        if tb:
-            d["tenant"] = tenant
-            has_meta = True
-        if ddl:
-            d["deadline_left_ms"] = ddl
-            has_meta = True
-        if attachment is not None:
-            d["request_attachment"] = attachment
-        start_ns = _time.monotonic_ns()
-        try:
-            request = request_cls()
-            request.ParseFromString(payload)
-        except Exception as e:
-            cntl._maybe_recycle()
-            item = (token, errors.EREQUEST,
-                    f"fail to parse request: {e}".encode(), b"", b"", (),
-                    (status, errors.EREQUEST, 0, server), 0, 0)
-            if collector is None or not collector.add(item):
-                self._respond_item(item)
-            return
-        opened = None
-        if stages:
-            self._record_stage("parse", start_ns, _time.monotonic_ns(),
-                               None, token)
-            # the handler stage runs from start_ns, parse included, as
-            # the method's latency does
-            opened = self._enter_handler(start_ns, token)
-        response = response_cls()
-        fd = _FusedDone(self, token, cntl, response, status, start_ns,
-                        collector, stages, opened)
-        d["_server_done"] = fd       # cntl.send_response() support
-        try:
-            # the context scope installs only when it would matter: the
-            # request carries admission meta, or an OUTER inline context
-            # must be masked for this handler's own outbound calls
-            # (nested in-process dispatch) — the no-meta echo shape pays
-            # zero frames here.  Inlined _reqctx.scope (same
-            # save/install/restore discipline, minus the class frames).
-            prev_ctx = getattr(_reqctx_tls, "ctx", None)
-            if has_meta or prev_ctx is not None:
-                _reqctx_tls.ctx = _reqctx.InboundContext(
-                    d.get("priority"), d.get("tenant", ""), ddl) \
-                    if has_meta else None
-                try:
-                    fn(cntl, request, response, fd)
-                finally:
-                    _reqctx_tls.ctx = prev_ctx
-            else:
-                fn(cntl, request, response, fd)
-        except Exception as e:
-            log.error("ici method %s raised: %s", full, e, exc_info=True)
-            if not fd.called:
-                cntl.set_failed(errors.EINTERNAL,
-                                f"{type(e).__name__}: {e}")
-                fd()
-        finally:
-            if opened is not None:
-                opened.leave()
 
     def _process(self, token, full, payload, attachment, log_id, peer_dev,
                  recv_ns, collector, adm_meta=None) -> None:
-        self.legacy_dispatched += 1
         server = self._server
         record_stage = self._record_stage
         stages = self._stages_on()
@@ -1536,45 +1269,46 @@ class ServerBinding:
                 self._respond_one(token, err, cntl.error_text_, collector,
                                   post=post,
                                   retry_after=cntl.retry_after_ms or 0)
-                return
-            resp_att = cntl._peek_response_attachment()
-            pass_h = 0
-            if resp_att is not None:
-                if isinstance(resp_att, NativeAttachment):
-                    # echo pass-through: the UNMATERIALIZED request view
-                    # assigned as the response — or a ResponseAttachment
-                    # that ADOPTED one via append — hands the parked
-                    # handle straight back to native; zero Python walks
-                    # on the whole response side.  (A materialized view
-                    # holds no handle and falls through to the split.)
-                    pass_h = resp_att._surrender_native()
-                if pass_h:
-                    att_host, segs = b"", ()
-                elif resp_att.backing_block_num():
-                    att_host, segs = split_attachment(resp_att)
-                else:
-                    att_host, segs = b"", ()
             else:
+                resp_att = cntl._peek_response_attachment()
+                pass_h = 0
                 att_host, segs = b"", ()
-            item = (token, 0, b"", response.SerializeToString(),
-                    att_host, segs, post, 0, pass_h)
-            if stages:
-                record_stage("encode", t_done, _time.monotonic_ns(), None,
-                             token)
-            if collector is None or not collector.add(item):
-                self._respond_item(item)
+                if resp_att is not None:
+                    if isinstance(resp_att, NativeAttachment):
+                        # echo pass-through: the UNMATERIALIZED request
+                        # view assigned as the response — or a
+                        # ResponseAttachment that ADOPTED one via append
+                        # — hands the parked handle straight back to
+                        # native; zero Python walks on the whole response
+                        # side.  (A materialized view holds no handle and
+                        # falls through to the split.)
+                        pass_h = resp_att._surrender_native()
+                    if not pass_h and resp_att.backing_block_num():
+                        att_host, segs = split_attachment(resp_att)
+                item = (token, 0, b"", response.SerializeToString(),
+                        att_host, segs, post, 0, pass_h)
+                if stages:
+                    record_stage("encode", t_done, _time.monotonic_ns(),
+                                 None, token)
+                if collector is None or not collector.add(item):
+                    self._respond_item(item)
+            # the pool recycle, once however the handler completed (done()
+            # or cntl.send_response()): safe before a collector flushes —
+            # the item owns its buffers and post holds no controller
+            cntl._maybe_recycle()
 
-        cntl._server_done = done
+        cntl._server_done = done     # cntl.send_response() support
         try:
-            md.invoke(cntl, request, response, done)
+            # MethodDescriptor.invoke's context scope, without its
+            # wrapped done: done above recycles the controller itself
+            with _reqctx.scope(cntl):
+                md.fn(cntl, request, response, done)
         except Exception as e:
             log.error("ici method %s raised: %s", full, e, exc_info=True)
             if not done_called[0]:
                 cntl.set_failed(errors.EINTERNAL,
                                 f"{type(e).__name__}: {e}")
                 done()
-                cntl._release_session_data()
-                cntl._maybe_recycle()
         finally:
             if opened is not None:
                 opened.leave()
@@ -1648,14 +1382,7 @@ class ServerBinding:
             self._lib.brpc_tpu_ici_respond_batch(arr, 1)
         del seg_arr, payload, att_host, err_text   # alive across the call
         if post is not None:
-            if type(post) is tuple:
-                # fused accounting (no per-RPC closure): see _FusedDone
-                status, perr, lat, server = post
-                if status is not None:
-                    status.on_responded(perr, lat)
-                server.on_request_out()
-            else:
-                post()
+            post()
 
     def _respond_flush(self, items) -> None:
         """One ``brpc_tpu_ici_respond_batch`` crossing for every packed
@@ -1705,118 +1432,7 @@ class ServerBinding:
         for it in items:
             post = it[6]
             if post is not None:
-                if type(post) is tuple:
-                    status, perr, lat, server = post
-                    if status is not None:
-                        status.on_responded(perr, lat)
-                    server.on_request_out()
-                else:
-                    post()
-
-
-class _FusedDone:
-    """The fused completion: the legacy chain's done() + post() +
-    wrapped_done() collapsed into one callable object — response
-    encode, attachment custody exit (pass-through / split), the batched
-    write-back, and the pool recycle, with the drain-gate accounting
-    (status.on_responded + server.on_request_out) packed as a TUPLE
-    into the respond item so it still runs AFTER the response crossed
-    back to native (see _process.done's ordering note) without a
-    per-RPC closure.  Idempotent like the legacy done."""
-
-    __slots__ = ("binding", "token", "cntl", "response", "status",
-                 "start_ns", "collector", "stages", "opened", "called")
-
-    def __init__(self, binding, token, cntl, response, status, start_ns,
-                 collector, stages, opened=None):
-        self.binding = binding
-        self.token = token
-        self.cntl = cntl
-        self.response = response
-        self.status = status
-        self.start_ns = start_ns
-        self.collector = collector
-        self.stages = stages
-        self.opened = opened          # the handler stage's layer span
-        self.called = False
-
-    def __call__(self) -> None:
-        if self.called:
-            return
-        self.called = True
-        b = self.binding
-        cntl = self.cntl
-        t_done = _time.monotonic_ns()
-        latency_us = (t_done - self.start_ns) // 1000
-        stages = self.stages
-        if stages:
-            b._record_stage("handler", self.start_ns, t_done, None,
-                            self.token, self.opened)
-        d = cntl.__dict__
-        if d.get("_session_data") is not None:
-            cntl._release_session_data()
-        err = cntl.error_code_
-        status = self.status
-        server = b._server
-        if err:
-            text = cntl.error_text_
-            item = (self.token, err,
-                    text.encode() if isinstance(text, str)
-                    else (text or b""), b"", b"", (),
-                    (status, err, latency_us, server),
-                    cntl.retry_after_ms or 0, 0)
-        else:
-            resp_att = d.get("response_attachment")
-            pass_h = 0
-            att_host = b""
-            segs = ()
-            if resp_att is not None:
-                if isinstance(resp_att, NativeAttachment) \
-                        and not resp_att._mat:
-                    # echo pass-through (also the adopted append shape,
-                    # ISSUE 13 satellite): hand the parked handle
-                    # straight back — zero Python walks.  Inlined
-                    # _surrender_native.
-                    pass_h = resp_att._h
-                    resp_att._h = 0
-                if not pass_h and resp_att.backing_block_num():
-                    att_host, segs = split_attachment(resp_att)
-            item = (self.token, 0, b"", self.response.SerializeToString(),
-                    att_host, segs, (status, 0, latency_us, server),
-                    0, pass_h)
-            if stages:
-                b._record_stage("encode", t_done, _time.monotonic_ns(),
-                                None, self.token)
-        coll = self.collector
-        if coll is None or not coll.add(item):
-            b._respond_item(item)
-        # attachment custody exits, inlined (the pool-release hooks
-        # would re-discover them through getattr): a request view whose
-        # handle never exited (handler ignored it) disposes HERE; a
-        # surrendered/adopted/materialized one holds no handle and the
-        # pop makes the pool's own duck-typed sweep a no-op
-        ra = d.pop("request_attachment", None)
-        if ra is not None and isinstance(ra, NativeAttachment):
-            h = ra._h
-            if h:
-                ra._h = 0
-                fns = _att_fns
-                if fns is not None:
-                    fns[1](h)
-        ra = d.pop("response_attachment", None)
-        if ra is not None and isinstance(ra, NativeAttachment):
-            h = ra._h
-            if h:
-                ra._h = 0
-                fns = _att_fns
-                if fns is not None:
-                    fns[1](h)
-        # pool recycle (the wrapped_done tail): safe before the
-        # collector flushes — the item owns its own buffers and the
-        # accounting tuple carries no controller reference
-        pool = d.get("_recycle_pool")
-        if pool is not None:
-            pool.release(cntl)
+                post()
 
 
 # ---------------------------------------------------------------------
@@ -1827,7 +1443,7 @@ class ChannelBinding:
     """Client half: one native connection (with its credit window) to the
     in-process native listener at ``remote_dev``."""
 
-    # class-attribute alias: Channel.call_method compares the fused
+    # class-attribute alias: Channel.call_method compares call_fused's
     # result against the sentinel without an import frame per call
     FUSED_FALLTHROUGH = FUSED_FALLTHROUGH
 
@@ -1853,12 +1469,11 @@ class ChannelBinding:
         # natively — the client sheds its take-walks both ways
         self._call4 = lib.brpc_tpu_ici_call4    # bound once: attr-chain
         self._free = lib.brpc_tpu_buf_free      # lookups are per-call
-        # fused client path (ISSUE 13), snapshot at connect:
-        # Channel.call_method routes sync calls through call_fused — the
-        # preamble/screen/issue/response chain as one flat code object.  Hot module handles resolve on first call
-        # (the lazy import dance exists only for load-time cycles).
-        self._fused = bool(_flags.get_flag("ici_fused_dispatch"))
-        self._callf = _fused_call_binding() if self._fused else None
+        # Channel.call_method routes every sync call through call_fused —
+        # the preamble/screen/issue/response chain as one flat code
+        # object.  Hot module handles resolve on first call (the lazy
+        # import dance exists only for load-time cycles).
+        self._callf = _fused_call_binding()
         self._hot = None
         from ..rpc import span as _span_mod
         self._rpcz_flag = _span_mod._rpcz_flag
@@ -2056,14 +1671,14 @@ class ChannelBinding:
 
     def call_fused(self, full_name: str, cntl, request: Any,
                    response_cls, chan):
-        """The fused sync client path (ISSUE 13): Channel.call_method's
-        context/default preamble, the per-call screens, and the whole
-        ``call`` body as ONE flat code object, with the dominant
-        1-device-block attachment shape inlined (no split/fill frames)
-        and the shed-retry / fallback helpers entered ONLY when their
-        error actually occurred.  Must mirror ``call_method`` +
-        ``call`` semantics exactly — the ``ici_fused_dispatch=False``
-        leg A/Bs them.  Returns FUSED_FALLTHROUGH when the call must
+        """The sync client entry, taken by every sync call on a native
+        binding: Channel.call_method's context/default preamble, the
+        per-call screens, and the whole ``call`` body as ONE flat code
+        object, with the dominant 1-device-block attachment shape
+        inlined (no split/fill frames) and the shed-retry / fallback
+        helpers entered ONLY when their error actually occurred.  Must
+        mirror ``call_method`` + ``call`` semantics, which async calls
+        still run.  Returns FUSED_FALLTHROUGH when the call must
         re-route to the Python plane (frame too large, hedging
         configured, dead-conn re-route)."""
         opts = chan.options

@@ -485,7 +485,7 @@ class IciSocket(CreditWindow, OrderedDelivery, Socket):
         at/above ``ici_device_plane_threshold``, a send WR is posted on the
         device plane with the whole block and ``(offset, length)`` — the
         payload then crosses through a COMPILED transfer program
-        (shard_map + ppermute / Pallas remote DMA) that cuts the piece on
+        (shard_map + ppermute) that cuts the piece on
         the chip, with only a descriptor riding the delivery path; the
         matching recv is enqueued by ``_deliver`` (the QP rendezvous);
         else the slice and ``device_put``.  A refused post

@@ -170,23 +170,34 @@ class Channel:
         ls = _span.layer_begin("brpc.call", cpu=True) \
             if _span.layer_on() else None
         try:
-            # fused native fast path (ISSUE 13): a cached in-process ici
-            # binding bound with ici_fused_dispatch serves sync calls
-            # through ONE flat code object (context inherit, screens, issue,
-            # response, error tails all inside call_fused).  Anything it
-            # can't serve — oversize frames, hedging, a dead conn's one-shot
-            # re-route — returns the FALLTHROUGH sentinel and the unfused
-            # body below handles it exactly as before.
-            nch0 = self._native_ici
-            if (nch0 is not None and done is None and nch0._fused
-                    and cntl.stream_creator is None):
-                result = nch0.call_fused(method_full_name, cntl, request,
-                                         response_cls, self)
-                if result is not nch0.FUSED_FALLTHROUGH:
+            # ici:// fast path: when the target device has a native listener in
+            # this process, the whole unary hot path (frame/window/dispatch/
+            # correlation) runs in native/rpc.cpp — no Python between
+            # serialize and parse except device-ref relocation (VERDICT r3 #1).
+            # Streaming, auth, non-tpu_std protocols, backup-request hedging,
+            # and frames too large for the native send window ride the Python
+            # plane (which drains big payloads chunkwise through its credit
+            # window).
+            nch = self._native_ici
+            if nch is None:
+                nch = self._native_ici_binding(cntl)
+            elif cntl.stream_creator is not None:
+                # the cached-binding fast path must re-screen the ONE
+                # eligibility input that varies per call; the channel-level
+                # ones (protocol, auth, endpoint) were screened at cache time
+                nch = None
+            if nch is not None and done is None:
+                # every sync call — a channel's first included — runs ONE
+                # flat code object (context inherit, screens, issue,
+                # response, error tails all inside call_fused).  Anything it
+                # can't serve — oversize frames, hedging, a dead conn's
+                # one-shot re-route — returns the FALLTHROUGH sentinel and
+                # rides the Python plane below.
+                result = nch.call_fused(method_full_name, cntl, request,
+                                        response_cls, self)
+                if result is not nch.FUSED_FALLTHROUGH:
                     return result
-                skip_native = True     # the fused leg already decided the
-            else:                      # re-route; don't re-enter the native
-                skip_native = False    # block below
+                nch = None
             # cascading inbound context (rpc/request_context.py): a call made
             # inside a handler's scope inherits the inbound priority/tenant
             # unless THIS call overrides them, and its timeout is capped at
@@ -220,65 +231,39 @@ class Channel:
                 cntl.priority = self.options.priority
             if not cntl.tenant and self.options.tenant:
                 cntl.tenant = self.options.tenant
-            # ici:// fast path: when the target device has a native listener in
-            # this process, the whole unary hot path (frame/window/dispatch/
-            # correlation) runs in native/rpc.cpp — no Python between
-            # serialize and parse except device-ref relocation (VERDICT r3 #1).
-            # Streaming, auth, non-tpu_std protocols, backup-request hedging,
-            # and frames too large for the native send window ride the Python
-            # plane (which drains big payloads chunkwise through its credit
-            # window).
-            nch = None if skip_native else self._native_ici
-            if nch is None:
-                if not skip_native:
-                    nch = self._native_ici_binding(cntl)
-            elif cntl.stream_creator is not None:
-                # the cached-binding fast path must re-screen the ONE
-                # eligibility input that varies per call; the channel-level
-                # ones (protocol, auth, endpoint) were screened at cache time
-                nch = None
+            # an async call on the native tier (a sync one went through
+            # call_fused above, and nch is None if it fell through)
             if nch is not None \
                     and not self._fast_call_fits(nch, cntl, request):
                 nch = None
             if nch is not None:
                 if cntl.timeout_ms is None:
                     cntl.timeout_ms = self.options.timeout_ms
-                if done is None:
-                    result = self._native_ici_call(nch, method_full_name, cntl,
-                                                   request, response_cls)
-                    result = self._native_shed_retry(nch, method_full_name,
-                                                     cntl, request,
-                                                     response_cls, result)
-                    if not self._native_ici_fallback(cntl):
+                from ..bthread import scheduler
+
+                def _run():
+                    try:
+                        self._native_ici_call(nch, method_full_name, cntl,
+                                              request, response_cls)
+                    except Exception as e:   # done() must ALWAYS fire
+                        if not cntl.failed():
+                            cntl.set_failed(errors.EINTERNAL,
+                                            f"{type(e).__name__}: {e}")
+                        done(cntl)
+                        return
+                    if self._native_ici_fallback(cntl):
+                        # dead native conn (server restarted) or oversize
+                        # fast-fail: re-route through the Python plane
+                        self.call_method(method_full_name, cntl, request,
+                                         response_cls, done=done)
+                    else:
                         if cntl.span is not None:
                             end_client_span(cntl)
-                        return result
-                else:
-                    from ..bthread import scheduler
+                        done(cntl)
 
-                    def _run():
-                        try:
-                            self._native_ici_call(nch, method_full_name, cntl,
-                                                  request, response_cls)
-                        except Exception as e:   # done() must ALWAYS fire
-                            if not cntl.failed():
-                                cntl.set_failed(errors.EINTERNAL,
-                                                f"{type(e).__name__}: {e}")
-                            done(cntl)
-                            return
-                        if self._native_ici_fallback(cntl):
-                            # dead native conn (server restarted) or oversize
-                            # fast-fail: re-route through the Python plane
-                            self.call_method(method_full_name, cntl, request,
-                                             response_cls, done=done)
-                        else:
-                            if cntl.span is not None:
-                                end_client_span(cntl)
-                            done(cntl)
-
-                    scheduler.start_background(
-                        _run, name=f"ici-call:{method_full_name}")
-                    return None
+                scheduler.start_background(
+                    _run, name=f"ici-call:{method_full_name}")
+                return None
             # mem:// loopback fast plane (loopback.py): in-process direct
             # dispatch, no byte codec / socket machinery.  Per-call screens:
             # anything the wire plane implements that loopback doesn't

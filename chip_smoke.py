@@ -44,7 +44,7 @@ KB, MB, GB = 1 << 10, 1 << 20, 1 << 30
 
 # (attachment bytes, unary calls) — the rdma_performance attachment sweep
 # up to the BASELINE's 1 GB figure.  At or under the native send window
-# (4 MB) a call rides the native fused tier; above it, the Python ici
+# (4 MB) a call rides the native tier; above it, the Python ici
 # plane drains the payload through its credit window.
 ECHO_SWEEP = ((4 * KB, 200), (1 * MB, 100), (64 * MB, 6), (1 * GB, 2))
 STREAM_SWEEP = ((64 * KB, 24), (1 * MB, 24))     # (chunk bytes, frames)
@@ -207,8 +207,8 @@ def phase_echo(rng, dev) -> None:
             done()
 
     opts = rpc.ServerOptions()
-    opts.usercode_inline = True     # the echo handler never blocks: the
-    server = rpc.Server(opts)       # fused one-frame dispatch serves it
+    opts.usercode_inline = True     # the echo handler never blocks: it
+    server = rpc.Server(opts)       # runs on the delivering thread
     server.add_service(EchoService())
     check(server.start("ici://0") == 0, "server start on ici://0")
     try:
@@ -220,7 +220,6 @@ def phase_echo(rng, dev) -> None:
         for nbytes, calls in ECHO_SWEEP:
             host, x = device_payload(rng, nbytes, dev)
             native_before = nb.requests()
-            fused_before = nb.fused_dispatched
             _, dev_bytes_before = ici_transport_stats()
             lat = []
             for i in range(calls):
@@ -244,14 +243,13 @@ def phase_echo(rng, dev) -> None:
                     check(np.array_equal(got, host),
                           f"echo {fmt_bytes(nbytes)} call {i}: bytes differ")
             native_calls = nb.requests() - native_before
-            fused = nb.fused_dispatched - fused_before
             _, dev_bytes = ici_transport_stats()
             window = ch._native_ici.window_bytes if ch._native_ici else 0
             if nbytes + 65536 <= window:
-                check(native_calls == calls and fused == calls,
+                check(native_calls == calls,
                       f"echo {fmt_bytes(nbytes)}: {native_calls}/{calls} "
-                      f"calls on the native tier, {fused} fused")
-                route = "native fused tier"
+                      "calls on the native tier")
+                route = "native tier"
             else:
                 check(dev_bytes - dev_bytes_before >= 2 * nbytes * calls,
                       f"echo {fmt_bytes(nbytes)}: ici transport counted "

@@ -382,13 +382,6 @@ def pytest_collection_modifyitems(config, items):
                 "holds this hook to the ids of ONE fan-out cell and of two " \
                 "tests; test_pushpull_cell.py holds it to every id it " \
                 "skips for the cells up to its own"
-    # since PR 38 a stream's chunks, resident on the server's chip, meet no
-    # delivery gate: the cell's window holds no span of the device poller
-    skipped["test_traced_rehearsal_has_every_metric_of_the_stream_layer"] = \
-        "holds that stream_1m's traced line lacks none of the metrics it " \
-        "lists; the four that read the device poller's spans have nothing " \
-        "to read there; tests/test_ungated_rehearsal.py holds every " \
-        "other clause"
     for item in items:
         reason = skipped.get(item.name)
         if reason and "tests/benchmarks/" in item.nodeid:

@@ -1011,29 +1011,22 @@ class TestBuildAttachmentExceptionSafety:
         assert reg.live() == 0
 
 
-class TestFusedDispatch:
-    """ISSUE 13 tentpole: the fused per-RPC code objects (server
-    _process_fused/_FusedDone, client call_fused) must be semantically
-    byte-identical to the legacy PR-12 chain — same responses, same
-    error codes, same gate ordering, same custody exits — while the
-    frame count per RPC stays inside the pinned budget."""
+class TestNativeDispatch:
+    """The native tier's one server dispatch chain (ServerBinding._process
+    → _execute → done) under the one sync client entry
+    (ChannelBinding.call_fused): responses, error codes, gate ordering
+    and custody exits, and the frame count per RPC inside its budget."""
 
-    def _server_channel(self, dev, fused, service=None, opts=None):
-        from brpc_tpu.butil import flags as fl
-        prev = fl.get_flag("ici_fused_dispatch")
-        fl.set_flag("ici_fused_dispatch", fused)
-        try:
-            server = rpc.Server(opts or rpc.ServerOptions(
-                usercode_inline=True))
-            server.add_service(service or EchoService())
-            assert server.start(f"ici://{dev}") == 0
-            ch = rpc.Channel()
-            ch.init(f"ici://{dev}",
-                    options=rpc.ChannelOptions(timeout_ms=10000,
-                                               max_retry=0,
-                                               ici_local_device=dev))
-        finally:
-            fl.set_flag("ici_fused_dispatch", prev)
+    def _server_channel(self, dev, service=None, opts=None):
+        server = rpc.Server(opts or rpc.ServerOptions(
+            usercode_inline=True))
+        server.add_service(service or EchoService())
+        assert server.start(f"ici://{dev}") == 0
+        ch = rpc.Channel()
+        ch.init(f"ici://{dev}",
+                options=rpc.ChannelOptions(timeout_ms=10000,
+                                           max_retry=0,
+                                           ici_local_device=dev))
         return server, ch
 
     def _echo(self, ch, mesh, msg="m", n=512):
@@ -1044,32 +1037,25 @@ class TestFusedDispatch:
                               EchoRequest(message=msg), EchoResponse)
         return cntl, resp
 
-    def test_fused_vs_legacy_byte_parity(self, mesh):
-        """The same echo (attachment + payload) through both dispatch
-        generations produces identical bytes; the route counters prove
-        which chain actually ran."""
-        results = {}
-        for fused in (True, False):
-            server, ch = self._server_channel(3, fused)
-            try:
-                cntl, resp = self._echo(ch, mesh, msg="parity")
-                assert not cntl.failed(), cntl.error_text
-                results[fused] = (resp.message,
-                                  cntl.response_attachment.to_bytes())
-                binding = server._native_ici
-                if fused:
-                    assert binding.fused_dispatched >= 1
-                    assert binding.legacy_dispatched == 0
-                else:
-                    assert binding.legacy_dispatched >= 1
-                    assert binding.fused_dispatched == 0
-            finally:
-                server.stop()
-        assert results[True] == results[False]
+    def test_inline_echo_returns_the_request_and_is_counted(self, mesh):
+        """An inline echo (attachment + payload) returns the request's
+        bytes and message, and the native listener counted it."""
+        server, ch = self._server_channel(3)
+        try:
+            binding = server._native_ici
+            before = binding.requests()
+            cntl, resp = self._echo(ch, mesh, msg="parity")
+            assert not cntl.failed(), cntl.error_text
+            assert resp.message == "parity"
+            assert cntl.response_attachment.to_bytes() == bytes(
+                np.arange(512, dtype=np.uint8))
+            assert binding.requests() == before + 1
+        finally:
+            server.stop()
 
-    def test_fused_error_paths_match_legacy(self, mesh):
-        """ENOMETHOD, handler exception, and parse failure return the
-        same codes through both chains."""
+    def test_error_paths(self, mesh):
+        """ENOMETHOD, handler exception, and parse failure return their
+        codes."""
         class Boom(rpc.Service):
             SERVICE_NAME = "EchoService"
 
@@ -1077,30 +1063,29 @@ class TestFusedDispatch:
             def Echo(self, cntl, request, response, done):
                 raise ValueError("kaboom")
 
-        for fused in (True, False):
-            server, ch = self._server_channel(3, fused, service=Boom())
-            try:
-                cntl = rpc.Controller()
-                ch.call_method("EchoService.Nope", cntl,
-                               EchoRequest(message="x"), EchoResponse)
-                assert cntl.error_code == rpc.errors.ENOMETHOD
-                cntl = rpc.Controller()
-                ch.call_method("EchoService.Echo", cntl,
-                               EchoRequest(message="x"), EchoResponse)
-                assert cntl.error_code == rpc.errors.EINTERNAL
-                assert "kaboom" in cntl.error_text
-                # parse failure: raw garbage bytes as the request
-                cntl = rpc.Controller()
-                ch.call_method("EchoService.Echo", cntl,
-                               b"\xff\xff\xff\xff\xff", None)
-                assert cntl.error_code == rpc.errors.EREQUEST, \
-                    cntl.error_text
-            finally:
-                server.stop()
+        server, ch = self._server_channel(3, service=Boom())
+        try:
+            cntl = rpc.Controller()
+            ch.call_method("EchoService.Nope", cntl,
+                           EchoRequest(message="x"), EchoResponse)
+            assert cntl.error_code == rpc.errors.ENOMETHOD
+            cntl = rpc.Controller()
+            ch.call_method("EchoService.Echo", cntl,
+                           EchoRequest(message="x"), EchoResponse)
+            assert cntl.error_code == rpc.errors.EINTERNAL
+            assert "kaboom" in cntl.error_text
+            # parse failure: raw garbage bytes as the request
+            cntl = rpc.Controller()
+            ch.call_method("EchoService.Echo", cntl,
+                           b"\xff\xff\xff\xff\xff", None)
+            assert cntl.error_code == rpc.errors.EREQUEST, \
+                cntl.error_text
+        finally:
+            server.stop()
 
-    def test_fused_async_handler_and_send_response(self, mesh):
+    def test_async_handler_and_send_response(self, mesh):
         """A handler that parks done() for a later thread, and one that
-        answers via cntl.send_response(), both complete under fusion."""
+        answers via cntl.send_response(), both complete."""
         import threading as _th
 
         class Async(rpc.Service):
@@ -1115,7 +1100,7 @@ class TestFusedDispatch:
                 response.message = "later"
                 _th.Timer(0.03, done).start()
 
-        server, ch = self._server_channel(3, True, service=Async())
+        server, ch = self._server_channel(3, service=Async())
         try:
             cntl = rpc.Controller()
             resp = ch.call_method("EchoService.Echo", cntl,
@@ -1131,24 +1116,24 @@ class TestFusedDispatch:
         finally:
             server.stop()
 
-    def test_fused_admission_delegates_to_legacy_chain(self, mesh):
+    def test_admission_serves_on_the_native_tier(self, mesh):
         """An admission-controlled server keeps the full shed/WFQ
-        decision tree: the fused entry resolves the method but the
-        request rides the legacy chain (counter proves it)."""
+        decision tree in front of the chain, and the native tier serves
+        the call."""
         opts = rpc.ServerOptions(usercode_inline=True, admission=True)
-        server, ch = self._server_channel(3, True, opts=opts)
+        server, ch = self._server_channel(3, opts=opts)
         try:
+            binding = server._native_ici
+            before = binding.requests()
             cntl, resp = self._echo(ch, mesh, msg="adm")
             assert not cntl.failed(), cntl.error_text
             assert resp.message == "adm"
-            binding = server._native_ici
-            assert binding.fused_dispatched == 0
-            assert binding.legacy_dispatched >= 1
+            assert binding.requests() == before + 1
         finally:
             server.stop()
 
-    def test_fused_draining_bounces_elogoff(self, mesh):
-        server, ch = self._server_channel(3, True)
+    def test_draining_bounces_elogoff(self, mesh):
+        server, ch = self._server_channel(3)
         try:
             cntl, resp = self._echo(ch, mesh)
             assert not cntl.failed()
@@ -1161,10 +1146,10 @@ class TestFusedDispatch:
             server._draining = False
             server.stop()
 
-    def test_fused_context_masking_for_nested_dispatch(self, mesh):
+    def test_context_masking_for_nested_dispatch(self, mesh):
         """A handler WITHOUT admission meta must not leak an outer
-        inline context into its own outbound calls: the fused path
-        masks exactly like _reqctx.scope."""
+        inline context into its own outbound calls: the handler runs
+        under _reqctx.scope of its own request."""
         from brpc_tpu.rpc import request_context as reqctx
         seen = {}
 
@@ -1178,30 +1163,57 @@ class TestFusedDispatch:
                 response.message = "ok"
                 done()
 
-        server, ch = self._server_channel(3, True, service=Svc())
+        server, ch = self._server_channel(3, service=Svc())
         try:
             cntl = rpc.Controller()
             ch.call_method("EchoService.Echo", cntl,
                            EchoRequest(message="x"), EchoResponse)
             assert not cntl.failed()
             # the channel stamps deadline_left from timeout_ms, so the
-            # handler sees a real inbound context with that budget (the
-            # legacy scope() behavior)
+            # handler sees a real inbound context with that budget
             assert seen["ctx"] is not None
             assert seen["ctx"].deadline_left_ms == seen["ddl"] > 0
         finally:
             server.stop()
 
+    def test_every_sync_call_enters_call_fused_the_first_included(
+            self, mesh, monkeypatch):
+        """A fresh channel's first sync call, and every one after it,
+        enters ChannelBinding.call_fused; none takes the async body
+        ``call`` (fault injection is off)."""
+        entered = {"call_fused": 0, "call": 0}
+        for name in entered:
+            orig = getattr(native_plane.ChannelBinding, name)
+
+            def wrapper(self, *a, _orig=orig, _name=name, **kw):
+                entered[_name] += 1
+                return _orig(self, *a, **kw)
+
+            monkeypatch.setattr(native_plane.ChannelBinding, name, wrapper)
+        server, ch = self._server_channel(3)
+        try:
+            assert ch._native_ici is None      # no binding before the call
+            for i in range(3):
+                cntl, resp = self._echo(ch, mesh, msg=f"c{i}")
+                assert not cntl.failed(), cntl.error_text
+                assert resp.message == f"c{i}"
+                assert entered == {"call_fused": i + 1, "call": 0}
+            assert ch._native_ici is not None
+        finally:
+            server.stop()
+
     def test_frame_budget(self, mesh):
-        """ISSUE 13 satellite: interpreter frames per RPC on the
-        native-ici echo path, measured with sys.setprofile around ONE
-        call_method.  The budget pins this PR's measured number (+
-        slack) so frame creep fails a named test instead of surfacing
-        as a bench surprise.  PR-12's equivalent-methodology count was
-        93 (the cProfile figure in ROADMAP, ~170, also counted C
-        calls); this PR measured ~40 fused."""
+        """Interpreter frames per RPC on the native-ici echo path,
+        measured with sys.setprofile around ONE call_method on the
+        caller's thread.  The server is not inline — the shape
+        local_compute_1m runs — so the count is the client path plus the
+        upcall's hand-off to a tasklet, not the handler chain (29-30
+        when this shape was pinned).  An inline server's frames are no
+        longer pinned: the fused server chain that earned that pin (55)
+        is deleted, and the one chain's count on an inline server (71
+        then) is what the handler tier costs on any server."""
         import sys as _sys
-        server, ch = self._server_channel(3, True)
+        server, ch = self._server_channel(3, opts=rpc.ServerOptions())
         try:
             # resident payload (the bench shape): a cross-device payload
             # would add jax's whole device_put stack to every call and
@@ -1236,11 +1248,10 @@ class TestFusedDispatch:
                 counts.append(n[0])
             counts.sort()
             frames = counts[len(counts) // 2]
-            BUDGET = 60          # measured ~40 + slack
+            BUDGET = 60
             assert frames <= BUDGET, (
-                f"frame creep: {frames} frames/RPC on the fused "
-                f"native-ici echo path (budget {BUDGET}; PR-12 "
-                f"same-methodology baseline was 93)")
+                f"frame creep: {frames} frames/RPC on the caller's "
+                f"thread of a native-ici echo (budget {BUDGET})")
         finally:
             server.stop()
 

@@ -2767,6 +2767,10 @@ scaler.start()
 stop_evt = threading.Event()
 stats = {"ok": 0, "shed": 0, "fail": 0, "mismatch": 0}
 slock = threading.Lock()
+# every completion, checked against the reference once the traffic is
+# over: a reference computed under slock during the traffic serialized
+# the clients behind it, and the decode workers then never looked loaded
+completions = []
 ch_opts = rpc.ChannelOptions(timeout_ms=30000)
 
 def client(wid, priority, pace_s, steps):
@@ -2792,22 +2796,21 @@ def client(wid, priority, pace_s, steps):
                                      %% (cntl.error_code_,
                                         cntl.error_text))
             else:
-                toks = json.loads(resp.message)["tokens"]
-                if toks == reference_generate(tokens, steps):
-                    stats["ok"] += 1
-                else:
-                    stats["mismatch"] += 1
+                completions.append(
+                    (tokens, steps, json.loads(resp.message)["tokens"]))
         if pace_s:
             time.sleep(pace_s)
     ch.close()
 
-# batch sessions are LONG (400 tokens): they live tens of steps in the
+# batch sessions are LONG (10,000 tokens): they live tens of ms in the
 # roster, so 6 concurrent batch clients genuinely saturate max_batch=4
 # and the load signal (roster + queue pressure) crosses the high-water
-# mark — the toy decode is otherwise too fast to ever look loaded
+# mark — the toy decode is otherwise too fast to ever look loaded (at
+# 400 tokens a session left the roster in ~20 ms, before the next one's
+# prefill ended, and only the first wave could cross the mark)
 threads = [threading.Thread(target=client, args=(w, 0, 0.05, 6))
            for w in range(2)]
-threads += [threading.Thread(target=client, args=(10 + w, 3, 0.0, 400))
+threads += [threading.Thread(target=client, args=(10 + w, 3, 0.0, 10000))
             for w in range(6)]
 for t in threads: t.start()
 
@@ -2854,6 +2857,11 @@ wait_for(lambda: scaler.scale_downs.get_value() >= 1, 20.0,
          "scale-down (load=%%s)" %% current_load())
 
 scaler.stop()
+for tokens, steps, toks in completions:
+    if toks == reference_generate(tokens, steps):
+        stats["ok"] += 1
+    else:
+        stats["mismatch"] += 1
 epoch1 = pod.epoch(refresh=True)
 desc = pod.describe()
 assert "autoscaler" in desc, "autoscaler missing from pod describe"

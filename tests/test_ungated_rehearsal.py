@@ -1,13 +1,11 @@
 """The streaming cell's traced rehearsal after PR 38: a chunk that is on the
 server's chip already meets no delivery gate, so no span of the device poller
 is left in the window and the four metrics that read those spans fall silent
-— the line leaves them out — while every other metric the cell lists is
-there, ``delivery_gate_ms_per_call`` among them.
+— and the cell no longer lists them — while every metric the cell lists
+is there, ``delivery_gate_ms_per_call`` among them.
 
-tests/benchmarks/test_stream_cell.py holds that NO listed metric is missing;
-the end of tests/conftest.py skips that one case and this file holds its
-other clauses (PERF.md §7 row 1c has what the next benchmark issue writes:
-a ``workloads`` list for the four).
+tests/benchmarks/test_stream_cell.py holds the same of the listed metrics;
+this file holds the route besides: no chunk met the gate.
 """
 import json
 import os
@@ -63,10 +61,10 @@ def test_the_streams_chunks_meet_no_gate_and_the_poller_metrics_fall_silent(
     # every chunk was on the server's chip: none waited for the poller
     assert counted["gated_arrays"] == 0
     assert counted["resident_refs_ungated"] >= 2 * CHUNKS * line["attempted"]
-    # ... so nothing is left for the poller's four metrics to read, and
-    # nothing else the cell lists is missing
-    assert POLLER_METRICS <= set(listed)
-    assert set(listed) - set(got) == POLLER_METRICS
+    # ... so the cell lists none of the poller's four metrics, and
+    # nothing it lists is missing
+    assert not POLLER_METRICS & set(listed)
+    assert set(listed) <= set(got)
     assert set(got) <= set(listed)
     # the gate's span is still recorded for every entry: about 0, not absent
     gate = got["delivery_gate_ms_per_call"]["value"]
